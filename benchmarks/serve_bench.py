@@ -89,6 +89,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.configs import get_config              # noqa: E402
+from repro.launch.compile_cache import enable_compile_cache  # noqa: E402
 from repro.models import param as pm              # noqa: E402
 from repro.models.model_zoo import Model          # noqa: E402
 from repro.serve.chaos import ChaosInjector       # noqa: E402
@@ -757,9 +758,9 @@ def prefill_kernel_timing(arch: str = "qwen2-0.5b", *, b: int = 4,
     d = cfg.resolved_head_dim
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, lq, hq, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((pages, page_size, hkv, d)),
+    k = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
                     jnp.float32)
-    v = jnp.asarray(rng.standard_normal((pages, page_size, hkv, d)),
+    v = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
                     jnp.float32)
     p_max = pages // b
     tbl = jnp.asarray(rng.permutation(pages)[:b * p_max]
@@ -842,9 +843,9 @@ def roofline_probe(arch: str = "qwen2-0.5b", *, b: int = 2, lq: int = 8,
     hq, hkv = cfg.n_heads, cfg.kv_heads
     d = cfg.resolved_head_dim
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.standard_normal((pages, page_size, hkv, d)),
+    kp = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
                      jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pages, page_size, hkv, d)),
+    vp = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
                      jnp.float32)
     p_max = pages // b
     tbl = jnp.asarray(rng.permutation(pages)[:b * p_max]
@@ -1040,6 +1041,7 @@ def main() -> None:
                     help="with --autotune-compare: also persist the "
                          "winners to this tuned-shape cache file")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.tuned_out and not args.autotune_compare:
         ap.error("--tuned-out requires --autotune-compare")
     if args.autotune_compare:

@@ -130,7 +130,10 @@ class TpuSpec:
     ici_link_gbps: float = 50.0           # per link
     ici_links: int = 4                    # 2D torus: 4 links/chip
     hbm_bytes: int = 16 * 1024**3
-    vmem_bytes: int = 128 * 1024**2
+    # VMEM one Pallas kernel may use: Mosaic's default scoped limit on
+    # v5e (the chip holds 128 MiB; a kernel sees 16 MiB unless its
+    # compiler params raise the limit)
+    vmem_bytes: int = 16 * 1024**2
     mxu_tile: int = 128                   # MXU systolic dim
     lane_tile: int = 128                  # last-dim register tiling
     sublane_tile: int = 8                 # fp32 second-minor tiling

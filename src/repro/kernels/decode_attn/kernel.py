@@ -32,11 +32,11 @@ from jax.experimental.pallas import tpu as pltpu
 BS = 512    # KV rows per block
 
 
-def _make_kernel(bs: int, scale: float):
+def _make_kernel(bs: int, hkv: int, d: int, scale: float):
     def kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref):
         bi = pl.program_id(0)
-        s = pl.program_id(2)
-        ns = pl.num_programs(2)
+        s = pl.program_id(1)
+        ns = pl.num_programs(1)
         ln = len_ref[bi]
 
         @pl.when(s == 0)
@@ -52,30 +52,34 @@ def _make_kernel(bs: int, scale: float):
         # compute at all — the accumulator simply carries through.
         @pl.when(base < ln)
         def _():
-            q = q_ref[0, 0]                  # [G, D]
-            k = k_ref[0, :, 0, :]            # [BS, D]
-            v = v_ref[0, :, 0, :]
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [G, BS]
+            k_all = k_ref[0]                 # [BS, Hkv * D]
+            v_all = v_ref[0]
             live = kpos < ln                 # [1, BS]
-            scores = jnp.where(live, scores, -1e30)
-            m_prev = m_ref[...]              # [G, 1]
-            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-            p = jnp.exp(scores - m_new)      # [G, BS]
-            corr = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * corr + p.sum(axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-                p.astype(jnp.float32), v.astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[...] = m_new
+            for h in range(hkv):             # static: heads share the DMA
+                q = q_ref[0, h]              # [G, D]
+                k = k_all[:, h * d:(h + 1) * d]
+                v = v_all[:, h * d:(h + 1) * d]
+                scores = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale  # [G, BS]
+                scores = jnp.where(live, scores, -1e30)
+                m_prev = m_ref[h]            # [G, 1]
+                m_new = jnp.maximum(m_prev,
+                                    scores.max(axis=-1, keepdims=True))
+                p = jnp.exp(scores - m_new)  # [G, BS]
+                corr = jnp.exp(m_prev - m_new)
+                l_ref[h] = l_ref[h] * corr + p.sum(axis=-1, keepdims=True)
+                acc_ref[h] = acc_ref[h] * corr + jax.lax.dot_general(
+                    p.astype(jnp.float32), v.astype(jnp.float32),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                m_ref[h] = m_new
 
         @pl.when(s == ns - 1)
         def _():
-            o_ref[0, 0] = (acc_ref[...]
-                           / jnp.maximum(l_ref[...], 1e-30)
-                           ).astype(o_ref.dtype)
+            o_ref[0] = (acc_ref[...]
+                        / jnp.maximum(l_ref[...], 1e-30)
+                        ).astype(o_ref.dtype)
     return kernel
 
 
@@ -87,22 +91,26 @@ def decode_attn_kernel(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     b, hkv, g, d = q.shape
     s = k.shape[1]
     bs = min(bs, s)
-    grid = (b, hkv, pl.cdiv(s, bs))
+    # merging the two minor axes is free (row-major) and makes one KV
+    # block a dense [bs, Hkv * D] tile: its minor dim is the array's full
+    # extent, which Mosaic accepts at any head count and head_dim
+    k = k.reshape(b, s, hkv * d)
+    v = v.reshape(b, s, hkv * d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=grid,
+        grid=(b, pl.cdiv(s, bs)),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda bi, h, si, ln: (bi, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda bi, h, si, ln: (bi, si, h, 0)),
-            pl.BlockSpec((1, bs, 1, d), lambda bi, h, si, ln: (bi, si, h, 0)),
+            pl.BlockSpec((1, hkv, g, d), lambda bi, si, ln: (bi, 0, 0, 0)),
+            pl.BlockSpec((1, bs, hkv * d), lambda bi, si, ln: (bi, si, 0)),
+            pl.BlockSpec((1, bs, hkv * d), lambda bi, si, ln: (bi, si, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bi, h, si, ln: (bi, h, 0, 0)),
-        scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, 1), jnp.float32),
-                        pltpu.VMEM((g, d), jnp.float32)],
+        out_specs=pl.BlockSpec((1, hkv, g, d),
+                               lambda bi, si, ln: (bi, 0, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, 1), jnp.float32),
+                        pltpu.VMEM((hkv, g, d), jnp.float32)],
     )
     return pl.pallas_call(
-        _make_kernel(bs, 1.0 / math.sqrt(d)), grid_spec=grid_spec,
+        _make_kernel(bs, hkv, d, 1.0 / math.sqrt(d)), grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret)(lengths, q, k, v)

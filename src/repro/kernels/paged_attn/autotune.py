@@ -61,6 +61,7 @@ import numpy as np
 
 from ...core.hwspec import DEFAULT_TPU, TpuSpec
 from .kernel import GRID_ORDERS
+from .prefill_kernel import default_block_rows, vmem_bytes
 
 SCHEMA = 1
 OPS = ("decode", "prefill", "verify")
@@ -97,8 +98,9 @@ class Geometry:
 
 @dataclasses.dataclass(frozen=True)
 class Candidate:
-    """One launch configuration.  ``block_rows=None`` means the default
-    single-block row fold (and is the only valid value for decode)."""
+    """One launch configuration.  ``block_rows=None`` means the kernel's
+    default row fold (:func:`prefill_kernel.default_block_rows`; the
+    only valid value for decode)."""
     grid_order: str = "bh"
     block_rows: int | None = None
 
@@ -109,7 +111,7 @@ class Candidate:
         return cfg
 
     def label(self) -> str:
-        br = "full" if self.block_rows is None else str(self.block_rows)
+        br = "default" if self.block_rows is None else str(self.block_rows)
         return f"{self.grid_order}/br={br}"
 
 
@@ -153,8 +155,8 @@ def make_workload(op: str, geom: Geometry, *, b: int = 2, lq: int = 8,
                          f"page_size > page_size + lq "
                          f"(pages={pages}, b={b}, ps={ps}, lq={lq})")
     rng = np.random.default_rng(seed)
-    kp = jnp.asarray(rng.standard_normal((pages, ps, hkv, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pages, ps, hkv, d)), jnp.float32)
+    kp = jnp.asarray(rng.standard_normal((pages, hkv, ps, d)), jnp.float32)
+    vp = jnp.asarray(rng.standard_normal((pages, hkv, ps, d)), jnp.float32)
     tbl = jnp.asarray(rng.permutation(pages)[:b * p_max]
                       .reshape(b, p_max).astype(np.int32))
     if op == "decode":
@@ -183,34 +185,52 @@ def enumerate_candidates(op: str, lg: int | None = None) -> list[Candidate]:
     return out
 
 
-def vmem_working_set(geom: Geometry, *, rows: int) -> int:
-    """fp32 bytes the kernel stages per grid step: q block + o block +
-    k/v page blocks + the (m, l, acc) flash scratch."""
-    d, ps = geom.d, geom.page_size
-    return 4 * (2 * rows * d + 2 * ps * d + rows * (d + 2))
+def vmem_working_set(geom: Geometry, *, rows: int, itemsize: int = 4) -> int:
+    """Scoped-VMEM bytes one prefill grid step stages for a ``rows``-row
+    block — the kernel's own lane-padded model
+    (:func:`prefill_kernel.vmem_bytes`), at the workloads' dtype."""
+    return vmem_bytes(rows, d=geom.d, ps=geom.page_size,
+                      q_itemsize=itemsize, kv_itemsize=itemsize)
 
 
 def feasible(cand: Candidate, *, op: str, lg: int, geom: Geometry,
              spec: TpuSpec = DEFAULT_TPU) -> tuple[bool, str]:
     """Static feasibility — infeasible tilings never run.  Rejects
     unknown grid orders, row tiling on decode (no row axis), non-divisor
-    ``block_rows``, and tilings whose per-step working set overflows
-    VMEM."""
+    ``block_rows``, row blocks Mosaic would refuse (neither a multiple of
+    the sublane tile nor the whole row axis), and tilings whose per-step
+    working set overflows the kernel's VMEM limit."""
     if cand.grid_order not in GRID_ORDERS:
         return False, f"unknown grid_order {cand.grid_order!r}"
-    rows = lg
-    if cand.block_rows is not None:
+    if cand.block_rows is None:
+        rows = default_block_rows(lg, d=geom.d, ps=geom.page_size,
+                                  q_itemsize=4, kv_itemsize=4,
+                                  limit=spec.vmem_bytes // 2)
+    else:
         if op == "decode":
             return False, "decode has no query-row axis to tile"
         if cand.block_rows <= 0 or lg % cand.block_rows:
             return False, (f"block_rows={cand.block_rows} does not divide "
                            f"the fused row count Lq*G={lg}")
+        if cand.block_rows % spec.sublane_tile and cand.block_rows != lg:
+            return False, (f"block_rows={cand.block_rows} is not a multiple "
+                           f"of the {spec.sublane_tile}-row sublane tile")
         rows = cand.block_rows
-    ws = vmem_working_set(geom, rows=rows)
-    if ws > spec.vmem_bytes:
-        return False, (f"VMEM working set {ws} B exceeds "
-                       f"{spec.vmem_bytes} B")
+    # the same half-of-scoped-VMEM budget the kernel's default rule keeps
+    # (the compiler may place operands in the other half)
+    ws, limit = vmem_working_set(geom, rows=rows), spec.vmem_bytes // 2
+    if ws > limit:
+        return False, f"VMEM working set {ws} B exceeds {limit} B"
     return True, "ok"
+
+
+def _block_rows(wl: Workload, block_rows: int | None) -> int:
+    """The row block a candidate launches with (None: the default)."""
+    if block_rows is not None:
+        return block_rows
+    item = jnp.dtype(wl.q.dtype).itemsize
+    return default_block_rows(wl.lg, d=wl.geom.d, ps=wl.geom.page_size,
+                              q_itemsize=item, kv_itemsize=item)
 
 
 def _page_fetches(wl: Workload, block_rows: int | None) -> int:
@@ -224,11 +244,10 @@ def _page_fetches(wl: Workload, block_rows: int | None) -> int:
         end = np.clip(ln, 0, p_max * ps)
         return int(np.sum((end + ps - 1) // ps))
     off = np.asarray(wl.q_offset, np.int64)
-    lg = wl.lg
-    br = lg if block_rows is None else block_rows
+    br = _block_rows(wl, block_rows)
     g = wl.geom.g
     total = 0
-    for r in range(lg // br):
+    for r in range(-(-wl.lg // br)):
         top = off + (r * br + br - 1) // g        # deepest qpos in block
         end = np.clip(np.minimum(ln, top + 1), 0, p_max * ps)
         total += int(np.sum((end + ps - 1) // ps))
@@ -259,14 +278,14 @@ def score(cand: Candidate, wl: Workload,
     and compute time — compute derated by sublane occupancy of the row
     block — plus a dispatch charge per grid step."""
     mem, flops, _onchip = candidate_traffic(wl, cand)
-    rows = wl.lg if cand.block_rows is None else cand.block_rows
+    rows = _block_rows(wl, cand.block_rows)
     sublane_eff = min(1.0, rows / spec.sublane_tile)
     mem_t = mem / spec.hbm_gbps
     comp_t = flops / (spec.peak_flops_per_ns * sublane_eff)
     b, p_max = int(wl.table.shape[0]), int(wl.table.shape[1])
     steps = b * wl.geom.hkv * p_max
     if wl.op != "decode":
-        steps *= wl.lg // rows
+        steps *= -(-wl.lg // rows)
     return max(mem_t, comp_t) + steps * DISPATCH_NS
 
 

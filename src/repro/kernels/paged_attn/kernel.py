@@ -3,8 +3,8 @@
 Same regime as :mod:`repro.kernels.decode_attn` — one new token against a
 deep KV cache, memory-bound, accumulator staged in VMEM across the KV walk
 — but the cache is no longer a per-slot stripe: K/V pages live in one
-pooled ``[n_pages, page_size, Hkv, D]`` allocation and each slot names its
-pages through a ``[B, max_pages]`` table.  The indirection happens in the
+pooled head-major ``[n_pages, Hkv, page_size, D]`` allocation and each
+slot names its pages through a ``[B, max_pages]`` table.  The indirection happens in the
 BlockSpec index maps: the page table and per-slot lengths are
 scalar-prefetched, so the DMA for grid step ``(b, h, p)`` fetches physical
 page ``table[b, p]`` — the gather costs nothing extra, it just redirects
@@ -21,8 +21,13 @@ Command skipping (§5.1.2) lands at page granularity and at two levels:
   the engine's page-count bucketing) — pages past *every* slot's length
   are never launched.
 
-The page dimension sits where decode_attn's KV-block dimension sat, so
-block shapes keep D on the 128-lane axis and the page rows on sublanes.
+Pages are stored head-major so that one grid step's K/V block,
+``(1, 1, page_size, D)``, has the page's token rows on sublanes and D on
+lanes as its two minor dimensions.  Mosaic requires those two to be
+multiples of (8, 128) or the array's full extent; both are the full
+extent here, so the block is legal at any page size and head_dim (a
+token-major ``[n_pages, page_size, Hkv, D]`` page would put a 1-wide
+head slice on sublanes, which the TPU compiler refuses).
 
 Tunable launch geometry (see :mod:`autotune`): ``grid_order`` picks which
 of the two outer grid axes is major — ``"bh"`` walks slots outermost
@@ -78,8 +83,8 @@ def _make_kernel(ps: int, scale: float, b_axis: int):
         @pl.when(base < ln)
         def _():
             q = q_ref[0, 0]                  # [G, D]
-            k = k_ref[0, :, 0, :]            # [ps, D]
-            v = v_ref[0, :, 0, :]
+            k = k_ref[0, 0]                  # [ps, D]
+            v = v_ref[0, 0]
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # [G, ps]
@@ -109,13 +114,13 @@ def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
                       lengths: jnp.ndarray, *,
                       interpret: bool = True,
                       grid_order: str = "bh") -> jnp.ndarray:
-    """q: [B, Hkv, G, D]; k_pages/v_pages: [N, ps, Hkv, D] pooled pages;
+    """q: [B, Hkv, G, D]; k_pages/v_pages: [N, Hkv, ps, D] pooled pages;
     table: [B, P] int32 physical page per (slot, logical page) — every
     entry must be < N (callers clamp sentinels); lengths: [B] int32.
     ``grid_order`` picks the outer grid majorness (see module docstring);
     the page axis is always innermost."""
     b, hkv, g, d = q.shape
-    n, ps = k_pages.shape[0], k_pages.shape[1]
+    ps = k_pages.shape[2]
     p_max = table.shape[1]
     b_axis, h_axis = _axes(grid_order)
     grid = [0, 0, p_max]
@@ -127,7 +132,7 @@ def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         # dead pages re-fetch the slot's first page (always resident for a
         # live slot) instead of pulling a fresh line that will be skipped
         pg = jnp.where(p * ps < ln[bi], tbl[bi, p], tbl[bi, 0])
-        return (pg, 0, h, 0)
+        return (pg, h, 0, 0)
 
     def q_map(i0, i1, p, tbl, ln):
         bi, h = (i0, i1)[b_axis], (i0, i1)[h_axis]
@@ -138,8 +143,8 @@ def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, g, d), q_map),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
+            pl.BlockSpec((1, 1, ps, d), kv_map),
+            pl.BlockSpec((1, 1, ps, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), q_map),
         scratch_shapes=[pltpu.VMEM((g, 1), jnp.float32),

@@ -209,7 +209,7 @@ def _traffic(q, k_pages, table, lengths, q_offset=None) -> tuple:
     lq = int(q.shape[1]) if q.ndim == 4 else 1
     hq = int(q.shape[-2])
     d = int(q.shape[-1])
-    ps, hkv = int(k_pages.shape[1]), int(k_pages.shape[2])
+    hkv, ps = int(k_pages.shape[1]), int(k_pages.shape[2])
     p = int(table.shape[-1])
     item = jnp.dtype(k_pages.dtype).itemsize
     qitem = jnp.dtype(q.dtype).itemsize
@@ -276,8 +276,8 @@ def _tuned_launch(op: str, q, k_pages, *, lg: int) -> dict:
     under trace, so resolution works at trace time."""
     from ..decode_attn import active_policy
     return active_policy().tuned_config(
-        op, hq=int(q.shape[-2]), hkv=int(k_pages.shape[2]),
-        d=int(q.shape[-1]), page_size=int(k_pages.shape[1]), lg=lg) or {}
+        op, hq=int(q.shape[-2]), hkv=int(k_pages.shape[1]),
+        d=int(q.shape[-1]), page_size=int(k_pages.shape[2]), lg=lg) or {}
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "grid_order"))
@@ -287,7 +287,7 @@ def _paged_attn_jit(q: jnp.ndarray, k_pages: jnp.ndarray,
                     interpret: bool = True,
                     grid_order: str = "bh") -> jnp.ndarray:
     b, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
     tbl = _clamp_table(table, k_pages.shape[0])
@@ -301,7 +301,7 @@ def paged_attn(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                table: jnp.ndarray, lengths: jnp.ndarray, *,
                interpret: bool = True,
                grid_order: str | None = None) -> jnp.ndarray:
-    """q: [B, Hq, D] one-token queries; k_pages/v_pages: [N, ps, Hkv, D]
+    """q: [B, Hq, D] one-token queries; k_pages/v_pages: [N, Hkv, ps, D]
     pooled pages; table: [B, P] int32; slot b attends over the first
     ``lengths[b]`` tokens of its pages in table order.  ``grid_order``
     None resolves through the active policy's tuned-shape cache
@@ -309,7 +309,7 @@ def paged_attn(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
     if grid_order is None:
         grid_order = _tuned_launch(
             "decode", q, k_pages,
-            lg=int(q.shape[-2]) // int(k_pages.shape[2])
+            lg=int(q.shape[-2]) // int(k_pages.shape[1])
         ).get("grid_order", "bh")
     if not _TELEMETRY.enabled:
         return _paged_attn_jit(q, k_pages, v_pages, table, lengths,
@@ -354,7 +354,7 @@ def paged_prefill_attn_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
     pass straight to the kernel's launch geometry — tuned-shape
     resolution happens in :func:`paged_prefill_attn`, not here."""
     b, lq, hq, d = q.shape
-    hkv = k_pages.shape[2]
+    hkv = k_pages.shape[1]
     g = hq // hkv
     qf = q.reshape(b, lq, hkv, g, d).transpose(0, 2, 1, 3, 4)
     qf = qf.reshape(b, hkv, lq * g, d)
@@ -407,7 +407,7 @@ def paged_prefill_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
     op = _op or ("decode" if q.shape[1] == 1 else "prefill")
     if pol.kernel_wanted():
         if grid_order is None or block_rows is None:
-            g = int(q.shape[2]) // int(k_pages.shape[2])
+            g = int(q.shape[2]) // int(k_pages.shape[1])
             cfg = _tuned_launch(op, q, k_pages, lg=int(q.shape[1]) * g)
             if grid_order is None:
                 grid_order = cfg.get("grid_order", "bh")

@@ -5,7 +5,7 @@ top of it): a block of ``Lq`` new prompt tokens per slot, sitting at a
 per-slot absolute depth ``q_offset[b]`` (its resident cached-prefix /
 already-prefilled length), attends causally over everything below it —
 shared prefix pages, earlier chunks and the block's own K/V, all resident
-in the pooled ``[n_pages, page_size, Hkv, D]`` allocation and named by the
+in the pooled head-major ``[n_pages, Hkv, page_size, D]`` allocation and named by the
 ``[B, max_pages]`` table.  Unlike :mod:`kernel` (one query row, pure
 memory-bound), the query block here re-uses every fetched page across
 ``Lq * G`` rows, so the kernel is the compute-bound sibling: same page
@@ -43,13 +43,19 @@ Tunable launch geometry (see :mod:`autotune`):
   Smaller row blocks shrink the VMEM working set and let the causal
   top-skip fire per row block (a deep row block never pays for pages
   only the shallow rows need), at the cost of re-walking the pages once
-  per block.  ``block_rows`` must divide ``Lq * G``; per query row the
-  accumulation sequence over pages is unchanged, so outputs are
-  numerically equivalent — but not guaranteed bit-identical on every
-  backend, because XLA may lower the block matmuls differently by
-  shape (CPU interpret does, by ulps).  The autotuner parity-gates
+  per block.  An explicit ``block_rows`` must divide ``Lq * G``; per
+  query row the accumulation sequence over pages is unchanged, so
+  outputs are numerically equivalent — but not guaranteed bit-identical
+  on every backend, because XLA may lower the block matmuls differently
+  by shape (CPU interpret does, by ulps).  The autotuner parity-gates
   candidates against the default shape and discards non-exact ones, so
   *tuned* configs are always bit-exact on the backend that tuned them.
+  The default (:func:`default_block_rows`) keeps every row in one block
+  while that block's scoped-VMEM footprint (:func:`vmem_bytes`) fits
+  half the chip's limit; past it, it takes the fewest row blocks that
+  fit, each a multiple of ``ROW_TILE``, and the grid rounds up — the
+  tail block's rows past ``Lq * G`` are computed (rows are independent)
+  and their writes dropped.
 * ``grid_order`` picks the outer-axis majorness exactly as in the decode
   kernel (``"bh"`` slot-major, ``"hb"`` head-major).  The row-block and
   page axes always stay innermost, pages last — the accumulator scratch
@@ -71,7 +77,60 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ...core.hwspec import DEFAULT_TPU
 from .kernel import GRID_ORDERS, _axes
+
+# default row blocks past one block are multiples of the bf16 sublane tile
+# (16 rows; the f32 tile of 8 divides it), as Mosaic requires of a block
+# that does not span the whole row axis
+ROW_TILE = 16
+
+
+def _lanes(n: int) -> int:
+    return -(-n // 128) * 128
+
+
+def vmem_bytes(rows: int, *, d: int, ps: int, q_itemsize: int,
+               kv_itemsize: int) -> int:
+    """Scoped-VMEM bytes one grid step of a ``rows``-row block stages,
+    counting Mosaic's padding of every minor dimension to 128 lanes: the
+    q and o blocks and the two K/V page blocks (each double buffered);
+    the (m, l) scratches — ``(rows, 1)`` f32, so 128 lanes each — and the
+    f32 accumulator; and the 32-bit temporaries of one page step: four
+    ``(rows, 1)`` columns (query positions, new running max, its
+    correction, row sums), the scores and probabilities ``[rows, ps]``,
+    and the PV product and rescaled accumulator ``[rows, D]``.
+
+    Checked against the v5e compiler at D=64, ps=16, bf16: a launch of
+    two 2560-row blocks compiled, one of six was refused, and 2816-row
+    blocks were refused — the compiler may also place the kernel's
+    operands or result in VMEM, which this model cannot see.  So
+    :func:`default_block_rows` budgets half the scoped limit (at most
+    1248 rows there), which compiled at every Lq tried from 5 to 2048."""
+    blocks = 2 * (2 * rows * _lanes(d) * q_itemsize
+                  + 2 * ps * _lanes(d) * kv_itemsize)
+    scratch = 4 * rows * (2 * 128 + _lanes(d))
+    temps = 4 * rows * (4 * 128 + 2 * _lanes(ps) + 2 * _lanes(d))
+    return blocks + scratch + temps
+
+
+def default_block_rows(lg: int, *, d: int, ps: int, q_itemsize: int,
+                       kv_itemsize: int,
+                       limit: int = DEFAULT_TPU.vmem_bytes // 2) -> int:
+    """The default row block for ``lg`` fused rows: all of them when one
+    block fits ``limit`` (a block spanning the whole axis is legal at
+    any row count), else the fewest blocks that fit, each rounded up to
+    a multiple of ``ROW_TILE``; the last block may then overhang the row
+    axis, and its rows past the end are computed and never written."""
+    kw = dict(d=d, ps=ps, q_itemsize=q_itemsize, kv_itemsize=kv_itemsize)
+    fixed = vmem_bytes(0, **kw)
+    per_row = vmem_bytes(1, **kw) - fixed
+    max_rows = max(ROW_TILE,
+                   (limit - fixed) // per_row // ROW_TILE * ROW_TILE)
+    if lg <= max_rows:
+        return lg
+    n_blocks = -(-lg // max_rows)
+    return -(-lg // (n_blocks * ROW_TILE)) * ROW_TILE
 
 
 def _make_kernel(ps: int, g: int, scale: float, b_axis: int):
@@ -107,8 +166,8 @@ def _make_kernel(ps: int, g: int, scale: float, b_axis: int):
         @pl.when((base < ln) & (base <= off + (row0 + br - 1) // g))
         def _():
             q = q_ref[0, 0]                  # [br, D]
-            k = k_ref[0, :, 0, :]            # [ps, D]
-            v = v_ref[0, :, 0, :]
+            k = k_ref[0, 0]                  # [ps, D]
+            v = v_ref[0, 0]
             scores = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale   # [br, ps]
@@ -142,23 +201,28 @@ def paged_prefill_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
                               block_rows: int | None = None,
                               grid_order: str = "bh") -> jnp.ndarray:
     """q: [B, Hkv, Lq * G, D] fused query rows (row ``r`` = token ``r // g``
-    of group member ``r % g``); k_pages/v_pages: [N, ps, Hkv, D] pooled
+    of group member ``r % g``); k_pages/v_pages: [N, Hkv, ps, D] pooled
     pages; table: [B, P] int32, every entry < N (callers clamp sentinels);
     q_offset/kv_len: [B] int32 per-slot depth of the query block and total
     live KV length (``q_offset + Lq`` for a suffix prefill).
-    ``block_rows`` (must divide ``Lq * G``; default: all rows in one
-    block) and ``grid_order`` tune the launch geometry — outputs are
+    ``block_rows`` (must divide ``Lq * G``; default:
+    :func:`default_block_rows`, whose tail block may overhang) and ``grid_order`` tune the launch geometry — outputs are
     numerically equivalent across valid settings; bit-exactness per
     backend is verified by the autotuner (see module docstring)."""
     b, hkv, lg, d = q.shape
-    ps = k_pages.shape[1]
+    ps = k_pages.shape[2]
     p_max = table.shape[1]
-    br = lg if block_rows is None else int(block_rows)
-    if br <= 0 or lg % br:
-        raise ValueError(f"block_rows={block_rows} must divide the fused "
-                         f"query-row count Lq*G={lg}")
+    if block_rows is None:
+        br = default_block_rows(
+            lg, d=d, ps=ps, q_itemsize=q.dtype.itemsize,
+            kv_itemsize=k_pages.dtype.itemsize)
+    else:
+        br = int(block_rows)
+        if br <= 0 or lg % br:
+            raise ValueError(f"block_rows={block_rows} must divide the "
+                             f"fused query-row count Lq*G={lg}")
     b_axis, h_axis = _axes(grid_order)
-    grid = [0, 0, lg // br, p_max]
+    grid = [0, 0, pl.cdiv(lg, br), p_max]
     grid[b_axis], grid[h_axis] = b, hkv
     grid = tuple(grid)
 
@@ -169,7 +233,7 @@ def paged_prefill_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         base = p * ps
         dead = (base >= ln[bi]) | (base > off[bi] + (r * br + br - 1) // g)
         pg = jnp.where(dead, tbl[bi, 0], tbl[bi, p])
-        return (pg, 0, h, 0)
+        return (pg, h, 0, 0)
 
     def q_map(i0, i1, r, p, tbl, off, ln):
         bi, h = (i0, i1)[b_axis], (i0, i1)[h_axis]
@@ -180,8 +244,8 @@ def paged_prefill_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, br, d), q_map),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
-            pl.BlockSpec((1, ps, 1, d), kv_map),
+            pl.BlockSpec((1, 1, ps, d), kv_map),
+            pl.BlockSpec((1, 1, ps, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, br, d), q_map),
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32),

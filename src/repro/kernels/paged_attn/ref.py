@@ -9,19 +9,23 @@ from ..decode_attn.ref import decode_attn_ref
 
 
 def gather_pages(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-    """pool: [N, ps, ...]; table: [B, P] int32 page ids (entries >= N are
+    """pool: head-major K/V pages [N, Hkv, ps, D], or latent pages
+    [N, ps, R] (MLA, no head axis) — either way the token axis is the
+    second-minor one; table: [B, P] int32 page ids (entries >= N are
     unallocated and clamp to the last page — callers mask by length).
-    Returns the contiguous view [B, P * ps, ...]."""
-    n, ps = pool.shape[:2]
-    gathered = pool[jnp.minimum(table, n - 1)]        # [B, P, ps, ...]
-    return gathered.reshape((table.shape[0], table.shape[1] * ps)
-                            + pool.shape[2:])
+    Returns the token-major contiguous view [B, P * ps, Hkv, D] (or
+    [B, P * ps, R])."""
+    gathered = pool[jnp.minimum(table, pool.shape[0] - 1)]
+    if pool.ndim == 4:                          # [B, P, Hkv, ps, D]
+        gathered = gathered.swapaxes(2, 3)      # [B, P, ps, Hkv, D]
+    b, p, ps = gathered.shape[:3]
+    return gathered.reshape((b, p * ps) + gathered.shape[3:])
 
 
 def paged_attn_ref(q: jnp.ndarray, k_pages: jnp.ndarray,
                    v_pages: jnp.ndarray, table: jnp.ndarray,
                    lengths: jnp.ndarray) -> jnp.ndarray:
-    """q: [B, Hq, D]; k_pages/v_pages: [N, ps, Hkv, D]; table: [B, P];
+    """q: [B, Hq, D]; k_pages/v_pages: [N, Hkv, ps, D]; table: [B, P];
     lengths: [B] int32 — slot b attends over its first lengths[b] tokens
     in page-table order."""
     k = gather_pages(k_pages, table)

@@ -1,5 +1,8 @@
 import os
+# a CPU tool with described devices: pin the platform so neither this
+# process nor its --fork children reach for an accelerator
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 

@@ -13,6 +13,7 @@ import jax
 import numpy as np
 
 from ..configs import get_config
+from .compile_cache import enable_compile_cache
 from ..models import param as pm
 from ..models.model_zoo import Model
 from ..serve.engine import ServeConfig
@@ -36,12 +37,16 @@ def run(arch: str, *, reduced: bool = True, requests: int = 4,
         overload: bool = False,
         deadline_s: float | None = None,
         timeout_s: float | None = None,
-        watchdog_rounds: int = 100_000) -> dict:
+        watchdog_rounds: int = 100_000,
+        prompt_lens: list[int] | None = None) -> dict:
+    """Serve random prompts (tokens drawn from ``seed``) through the
+    continuous batcher.  ``prompt_lens`` gives each request's prompt
+    length and replaces ``requests`` and the default 4-11 token draw."""
     cfg = get_config(arch)
     if reduced:
         cfg = cfg.reduced()
     model = Model(cfg)
-    params = pm.unwrap(model.init(jax.random.key(seed)))
+    params = pm.unwrap(jax.jit(model.init)(jax.random.key(seed)))
     scfg = ServeConfig(max_len=max_len, batch=batch, sync_every=sync_every,
                        temperature=temperature, attn_mode=attn_mode,
                        paged=paged, page_size=page_size,
@@ -59,10 +64,16 @@ def run(arch: str, *, reduced: bool = True, requests: int = 4,
     b = Batcher(model, params, scfg, eos_id=eos_id, seed=seed)
     rng = np.random.default_rng(seed)
     system = rng.integers(0, cfg.vocab, size=shared_prefix).tolist()
-    for rid in range(requests):
-        prompt = system + rng.integers(0, cfg.vocab,
-                                       size=int(rng.integers(4, 12))).tolist()
-        b.submit(rid, prompt, deadline_s=deadline_s, timeout_s=timeout_s)
+    if prompt_lens is None:
+        prompt_lens = [None] * requests
+    prompts = {}
+    for rid, plen in enumerate(prompt_lens):
+        if plen is None:
+            plen = int(rng.integers(4, 12))
+        prompts[rid] = system + rng.integers(0, cfg.vocab,
+                                             size=plen).tolist()
+        b.submit(rid, prompts[rid], deadline_s=deadline_s,
+                 timeout_s=timeout_s)
     t0 = time.perf_counter()
     results = b.run(max_new=max_new)
     dt = time.perf_counter() - t0
@@ -131,7 +142,8 @@ def run(arch: str, *, reduced: bool = True, requests: int = 4,
         print(f"[serve] wrote Perfetto trace -> {trace_out} "
               f"({len(b.telemetry.events)} events; open at "
               "ui.perfetto.dev)")
-    return {"results": results, "tok_per_s": toks / dt, "kv_util": util,
+    return {"results": results, "prompts": prompts, "seconds": dt,
+            "join": b.join_stats(), "tok_per_s": toks / dt, "kv_util": util,
             "prefix": pstats, "spec": sstats, "latency": lat,
             "preempt": kstats, "slo": slo, "overload": ostats,
             "attribution": attribution}
@@ -228,6 +240,7 @@ def main() -> None:
                          "progress before the scheduler dumps a flight "
                          "bundle and force-sheds the blocking request")
     args = ap.parse_args()
+    enable_compile_cache()
     run(args.arch, reduced=args.reduced, requests=args.requests,
         max_new=args.max_new, batch=args.batch, max_len=args.max_len,
         sync_every=args.sync_every, temperature=args.temperature,

@@ -27,6 +27,7 @@ from ..models.model_zoo import Model
 from ..train import optimizer as opt
 from ..train.train_loop import (TrainConfig, make_train_state,
                                 make_train_step, split_microbatches)
+from .compile_cache import enable_compile_cache
 
 
 def run(arch: str, *, steps: int = 20, batch: int = 8, seq: int = 128,
@@ -116,6 +117,7 @@ def main() -> None:
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     ap.add_argument("--compress-grads", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
     out = run(args.arch, steps=args.steps, batch=args.batch, seq=args.seq,
               reduced=args.reduced, ckpt_dir=args.ckpt_dir,
               ckpt_every=args.ckpt_every, accum=args.accum, lr=args.lr,

@@ -39,9 +39,12 @@ class KVCache(NamedTuple):
 class PagedKVCache(NamedTuple):
     """Paged layout: K/V pages live in one pooled allocation shared by all
     slots; ``table`` names each slot's pages in order (entries >= n_pages
-    are unallocated — scatters through them drop, reads clamp + mask)."""
-    k: jnp.ndarray           # [n_pages, page_size, Hkv, D] (latent for MLA)
-    v: jnp.ndarray           # [n_pages, page_size, Hkv, D] (rope-key MLA)
+    are unallocated — scatters through them drop, reads clamp + mask).
+    GQA pages are head-major, so a (page, head) block is a contiguous
+    ``[page_size, D]`` tile for the paged kernels; MLA's latent pages have
+    no head axis.  Either way the token axis is the second-minor one."""
+    k: jnp.ndarray           # [n_pages, Hkv, page_size, D] (MLA: [n, ps, r])
+    v: jnp.ndarray           # [n_pages, Hkv, page_size, D] (MLA: rope key)
     table: jnp.ndarray       # [B, P] int32 page ids
     length: jnp.ndarray      # [B] int32: tokens filled per slot
 
@@ -153,8 +156,9 @@ def _cache_insert(buf: jnp.ndarray, vals: jnp.ndarray, length) -> jnp.ndarray:
 
 def _paged_insert(pool: jnp.ndarray, vals: jnp.ndarray,
                   table: jnp.ndarray, length: jnp.ndarray) -> jnp.ndarray:
-    """Scatter ``vals`` [B, L, ...] into the page pool [N, ps, ...]:
-    row b's token at sequence position ``length[b] + t`` lands in page
+    """Scatter ``vals`` [B, L, Hkv, D] into the head-major page pool
+    [N, Hkv, ps, D] (or latent ``vals`` [B, L, R] into [N, ps, R]): row
+    b's token at sequence position ``length[b] + t`` lands in page
     ``table[b, (length[b] + t) // ps]`` at offset ``% ps``.  Positions
     whose logical page is unallocated (sentinel id >= N) or beyond the
     table width drop — exactly the dense path's out-of-range semantics,
@@ -170,7 +174,7 @@ def _paged_insert(pool: jnp.ndarray, vals: jnp.ndarray,
     overhang at admission so these writes never land past the slot's
     pages (a dropped write would make a *accepted* draft read garbage)."""
     vals = vals.astype(pool.dtype)
-    n, ps = pool.shape[0], pool.shape[1]
+    n, ps = pool.shape[0], pool.shape[-2]
     b, l = vals.shape[:2]
     p_max = table.shape[1]
     pos = jnp.asarray(length, jnp.int32)[:, None] + jnp.arange(l)[None, :]
@@ -179,8 +183,10 @@ def _paged_insert(pool: jnp.ndarray, vals: jnp.ndarray,
     page = jnp.where(logical < p_max,
                      table[bidx, jnp.minimum(logical, p_max - 1)], n)
     flat_vals = vals.reshape((b * l,) + vals.shape[2:])
-    return pool.at[page.reshape(-1), (pos % ps).reshape(-1)].set(
-        flat_vals, mode="drop")
+    page, off = page.reshape(-1), (pos % ps).reshape(-1)
+    if pool.ndim == 4:
+        return pool.at[page, :, off].set(flat_vals, mode="drop")
+    return pool.at[page, off].set(flat_vals, mode="drop")
 
 
 def _paged_gather(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:

@@ -137,13 +137,18 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int,
 def init_paged_caches(cfg: ArchConfig, batch: int, n_pages: int,
                       page_size: int, dtype=jnp.bfloat16) -> list:
     """Paged variant of :func:`init_caches`: attention segments hold one
-    pooled ``[layers, n_pages, page_size, ...]`` allocation shared by every
-    slot through the page table (see repro.serve.kvpool); SSM segments keep
-    their per-slot recurrent state — it is O(1) in sequence length, there
-    is nothing to page (which is also why prefix sharing is
-    attention-only: a recurrent state cannot resume from a cached page)."""
+    pooled ``[layers, n_pages, kv_heads, page_size, head_dim]`` allocation
+    (head-major, see PagedKVCache; MLA latents ``[layers, n_pages,
+    page_size, r]``) shared by every slot through the page table (see
+    repro.serve.kvpool); SSM segments keep their per-slot recurrent state
+    — it is O(1) in sequence length, there is nothing to page (which is
+    also why prefix sharing is attention-only: a recurrent state cannot
+    resume from a cached page)."""
     caches = []
     kshape, vshape = _attn_cache_shape(cfg, n_pages, page_size)
+    if cfg.attn is not AttnKind.MLA:
+        kshape = vshape = (n_pages, cfg.kv_heads, page_size,
+                           cfg.resolved_head_dim)
     for seg in cfg.resolved_segments():
         n = seg.count
         if seg.kind is BlockKind.SSM:

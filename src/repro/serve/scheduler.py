@@ -1205,6 +1205,7 @@ class ContinuousBatcher:
         # bounded by the chunk size
         width = _pow2_bucket(max(len(piece) for _, _, piece, _, _ in take),
                              lo=8, hi=self.cfg.max_len)
+        self.metrics.observe("join.width", width)
         join_mask = np.zeros((b,), bool)
         commit_mask = np.zeros((b,), bool)
         prompts = np.zeros((b, width), np.int32)
@@ -1600,10 +1601,13 @@ class ContinuousBatcher:
         chunked prefill exists to bound.  ``chunk_joins`` counts the
         continuation pieces (0 when unchunked); ``budget_deferrals``
         counts prefill pieces pushed to a later round by the
-        decode-priority ``prefill_round_tokens`` cap (0 when uncapped)."""
+        decode-priority ``prefill_round_tokens`` cap (0 when uncapped);
+        ``widths`` lists the padded prefill widths (jit buckets) the
+        joins ran at."""
         m = self.metrics
         n = m.count("join.seconds")
         return {"joins": n,
+                "widths": sorted({int(w) for w in m.samples("join.width")}),
                 "chunk_joins": int(m.value("join.chunk_continuations")),
                 "budget_deferrals": int(m.value("join.budget_deferrals")),
                 "max_join_s": max(m.samples("join.seconds"), default=0.0),

@@ -15,8 +15,8 @@ from repro.kernels.paged_attn import (gather_pages, paged_attn,
 
 def _mk(rng, b, hq, hkv, d, n, ps, p_max, lengths, dtype=jnp.float32):
     q = jnp.asarray(rng.standard_normal((b, hq, d)), dtype)
-    k = jnp.asarray(rng.standard_normal((n, ps, hkv, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((n, ps, hkv, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((n, hkv, ps, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((n, hkv, ps, d)), dtype)
     # each slot maps ceil(len/ps) random distinct pages; the tail of each
     # row is the pool's sentinel id (== n)
     tbl = np.full((b, p_max), n, np.int32)
@@ -73,8 +73,8 @@ def test_paged_attn_matches_dense_decode_attn():
     vd = jnp.asarray(rng.standard_normal((b, p_max * ps, hkv, d)),
                      jnp.float32)
     # identity layout: slot i's pages are i*p_max .. i*p_max+p_max-1
-    kp = kd.reshape(n, ps, hkv, d)
-    vp = vd.reshape(n, ps, hkv, d)
+    kp = kd.reshape(n, ps, hkv, d).swapaxes(1, 2)
+    vp = vd.reshape(n, ps, hkv, d).swapaxes(1, 2)
     tbl = jnp.arange(n, dtype=jnp.int32).reshape(b, p_max)
     ln = jnp.asarray(lengths, jnp.int32)
     paged = paged_attn(q, kp, vp, tbl, ln)
@@ -83,13 +83,20 @@ def test_paged_attn_matches_dense_decode_attn():
 
 
 def test_gather_pages_layout():
-    """gather_pages reassembles table order and clamps sentinels."""
-    pool = jnp.arange(4 * 2 * 1 * 1, dtype=jnp.float32).reshape(4, 2, 1, 1)
+    """gather_pages reassembles table order, clamps sentinels, and turns
+    head-major pages [N, Hkv, ps, D] into a token-major view."""
+    pool = jnp.arange(4 * 2 * 2, dtype=jnp.float32).reshape(4, 2, 2, 1)
     tbl = jnp.asarray([[2, 0, 4]], jnp.int32)      # 4 == sentinel, clamps
     out = gather_pages(pool, tbl)
-    assert out.shape == (1, 6, 1, 1)
-    got = np.asarray(out)[0, :, 0, 0]
-    np.testing.assert_array_equal(got[:4], [4.0, 5.0, 0.0, 1.0])
+    assert out.shape == (1, 6, 2, 1)
+    got = np.asarray(out)[0, :, :, 0]              # [token, head]
+    np.testing.assert_array_equal(got[:4], [[8, 10], [9, 11], [0, 2], [1, 3]])
+    # latent pages (MLA) have no head axis: [N, ps, R]
+    lat = jnp.arange(4 * 2 * 3, dtype=jnp.float32).reshape(4, 2, 3)
+    out = gather_pages(lat, tbl)
+    assert out.shape == (1, 6, 3)
+    np.testing.assert_array_equal(np.asarray(out)[0, :4],
+                                  np.asarray(lat)[[2, 2, 0, 0], [0, 1, 0, 1]])
 
 
 def test_paged_attn_xla_matches_kernel():
@@ -109,8 +116,8 @@ def _mk_prefill(rng, b, hq, hkv, d, n, ps, p_max, offsets, lq,
     """Random pooled pages + per-slot tables sized for offset + lq tokens;
     table tails hold the sentinel id (== n)."""
     q = jnp.asarray(rng.standard_normal((b, lq, hq, d)), dtype)
-    k = jnp.asarray(rng.standard_normal((n, ps, hkv, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((n, ps, hkv, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((n, hkv, ps, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((n, hkv, ps, d)), dtype)
     tbl = np.full((b, p_max), n, np.int32)
     perm = list(rng.permutation(n))
     for i, off in enumerate(offsets):
@@ -208,3 +215,23 @@ def test_paged_prefill_dead_pages_skipped():
     v2 = v.at[jnp.asarray(dead)].set(jnp.nan)
     out2 = paged_prefill_attn_pallas(q, k2, v2, tbl, offs, ln)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+def test_paged_prefill_default_row_blocks_overhang():
+    """At qwen2-0.5b geometry (14 query heads, 2 KV heads, head_dim 64,
+    page 16), a 200-token block fuses 1400 rows: more than one row block
+    fits the VMEM budget, so the default splits them into multiple-of-16
+    blocks whose last one overhangs the row axis — and still matches the
+    oracle."""
+    from repro.kernels.paged_attn.prefill_kernel import (ROW_TILE,
+                                                         default_block_rows)
+    hq, hkv, d, ps, lq = 14, 2, 64, 16, 200
+    lg = lq * hq // hkv
+    br = default_block_rows(lg, d=d, ps=ps, q_itemsize=4, kv_itemsize=4)
+    assert br < lg and br % ROW_TILE == 0 and lg % br
+    rng = np.random.default_rng(5)
+    q, k, v, tbl, off, ln = _mk_prefill(rng, 1, hq, hkv, d, 20, ps, 16,
+                                        [37], lq)
+    out = paged_prefill_attn_pallas(q, k, v, tbl, off, ln)
+    ref = paged_prefill_attn_ref(q, k, v, tbl, off, ln)
+    np.testing.assert_allclose(out, ref, rtol=3e-4, atol=3e-4)

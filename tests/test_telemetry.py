@@ -484,7 +484,7 @@ def test_kernel_hooks_off_record_nothing():
     tel.disable()
     tel.reset()
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.normal(size=(4, 4, 2, 8)), jnp.float32)
+    kp = jnp.asarray(rng.normal(size=(4, 2, 4, 8)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(2, 4, 8)), jnp.float32)
     tbl = jnp.zeros((2, 2), jnp.int32)
     ln = jnp.asarray([3, 5], jnp.int32)
@@ -502,7 +502,7 @@ def test_kernel_hooks_record_ops_routes():
     tel.enable()
     try:
         rng = np.random.default_rng(0)
-        kp = jnp.asarray(rng.normal(size=(6, 4, 2, 8)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(6, 2, 4, 8)), jnp.float32)
         tbl = jnp.asarray(rng.integers(0, 6, size=(2, 3)), jnp.int32)
         ln = jnp.asarray([5, 9], jnp.int32)
         q1 = jnp.asarray(rng.normal(size=(2, 4, 8)), jnp.float32)
@@ -532,7 +532,7 @@ def test_kernel_hooks_traced_counted_not_timed():
     tel.enable()
     try:
         rng = np.random.default_rng(0)
-        kp = jnp.asarray(rng.normal(size=(6, 4, 2, 8)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(6, 2, 4, 8)), jnp.float32)
         tbl = jnp.asarray(rng.integers(0, 6, size=(2, 3)), jnp.int32)
         ln = jnp.asarray([5, 9], jnp.int32)
         q3 = jnp.asarray(rng.normal(size=(2, 3, 4, 8)), jnp.float32)
@@ -567,7 +567,7 @@ def test_kernel_roofline_all_ops_on_kernel_route():
     tel.enable()
     try:
         rng = np.random.default_rng(0)
-        kp = jnp.asarray(rng.normal(size=(6, 4, 2, 8)), jnp.float32)
+        kp = jnp.asarray(rng.normal(size=(6, 2, 4, 8)), jnp.float32)
         tbl = jnp.asarray(rng.integers(0, 6, size=(2, 3)), jnp.int32)
         ln = jnp.asarray([5, 9], jnp.int32)
         q1 = jnp.asarray(rng.normal(size=(2, 4, 8)), jnp.float32)
