@@ -1,0 +1,118 @@
+"""Every configuration, traffic mix and metric loads by name, agrees with
+BENCHMARK.json, and a new one of each kind is found without an edit to
+any file that is already there."""
+import json
+import os
+import re
+import shutil
+
+import pytest
+
+from bench.harness import spec
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+BENCH = spec.benchmark()
+
+
+def test_benchmark_file_follows_its_rules():
+    assert set(BENCH) == {"command", "paths", "run_seconds", "configs",
+                          "workloads", "end_to_end", "per_layer"}
+    e2e = {m["name"]: m for m in BENCH["end_to_end"]}
+    assert "setup_s" in e2e
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"])
+        assert m["better"] in ("lower", "higher")
+    for m in BENCH["end_to_end"]:
+        assert 0.01 <= m["bound"] <= 0.25
+        assert m["source"] in ("host_clock", "device_trace")
+    cells = {w["name"]: w for w in BENCH["workloads"]}
+    for m in BENCH["per_layer"]:
+        assert m["moves"] in e2e
+        for w in m.get("workloads", cells):
+            moved = e2e[m["moves"]]
+            assert "workloads" not in moved or w in moved["workloads"]
+    for w in cells:
+        c = spec.cell(w)
+        names = {m["name"] for m in c.end_to_end}
+        assert "setup_s" in names and len(names) >= 2 and c.per_layer
+    assert {w["config"] for w in cells.values()} == \
+        {c["name"] for c in BENCH["configs"]}
+
+
+@pytest.mark.parametrize("cfg", BENCH["configs"], ids=lambda c: c["name"])
+def test_config_file_loads_by_name(cfg):
+    assert cfg["file"] == f"bench/configs/{cfg['name']}.json"
+    data = spec.config(cfg["name"])
+    assert data["name"] == cfg["name"] and data["source"] == cfg["source"]
+    for key in cfg["reduced"]:
+        assert key in data and NAME.match(key)
+
+
+@pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
+def test_traffic_file_loads_by_name(w):
+    mix = spec.traffic(w["traffic"])
+    assert mix["arrivals"]["kind"] in ("closed", "poisson")
+    assert spec.cell(w["name"]).traffic == mix
+
+
+@pytest.mark.parametrize("m", BENCH["per_layer"], ids=lambda m: m["name"])
+def test_metric_file_loads_by_name(m):
+    mod = spec.metric_module(m["name"])
+    assert (mod.LAYER, mod.UNIT, mod.MOVES) == (m["layer"], m["unit"],
+                                                m["moves"])
+    assert callable(mod.read)
+
+
+def test_peak_table_names_its_source_and_refuses_others():
+    pk = spec.peaks("TPU v5 lite")
+    assert pk["bf16_flops_per_s"] == 197e12 and pk["hbm_bytes_per_s"] == 819e9
+    assert "cloud.google.com" in pk["source"]
+    with pytest.raises(KeyError):
+        spec.peaks("TPU v4")
+
+
+def test_new_files_need_no_edit(tmp_path, monkeypatch):
+    bench_dir = tmp_path / "bench"
+    shutil.copytree(spec.BENCH_DIR, bench_dir,
+                    ignore=shutil.ignore_patterns("__pycache__", "tests"))
+    before = {p: open(os.path.join(r, p), "rb").read()
+              for r, _, fs in os.walk(bench_dir) for p in fs}
+    cfg = spec.config("qwen2-0.5b")
+    cfg["name"] = "qwen2-0.5b-b64"
+    cfg["serve"] = dict(cfg["serve"], batch=64)
+    (bench_dir / "configs" / "qwen2-0.5b-b64.json").write_text(
+        json.dumps(cfg))
+    mix = dict(spec.traffic("code_completion"), max_new=32)
+    (bench_dir / "traffic" / "short_code.json").write_text(json.dumps(mix))
+    (bench_dir / "metrics" / "join_share.py").write_text(
+        'LAYER = "model step (serve/engine.py join and decode loop)"\n'
+        'UNIT = "%"\nMOVES = "ttft_p95_s"\n'
+        "def read(record, trace):\n    return 1.0\n")
+    bench = dict(BENCH)
+    bench["configs"] = BENCH["configs"] + [dict(
+        BENCH["configs"][0], name="qwen2-0.5b-b64",
+        file="bench/configs/qwen2-0.5b-b64.json")]
+    bench["workloads"] = BENCH["workloads"] + [{
+        "name": "qwen2-0.5b-b64.short_code", "config": "qwen2-0.5b-b64",
+        "traffic": "short_code", "chips": 1, "why": "test"}]
+    bench["end_to_end"] = [
+        dict(m, workloads=m["workloads"] + ["qwen2-0.5b-b64.short_code"])
+        if m["name"] == "ttft_p95_s" else m for m in BENCH["end_to_end"]]
+    bench["per_layer"] = BENCH["per_layer"] + [{
+        "name": "join_share", "unit": "%", "better": "lower",
+        "source": "device_trace", "moves": "ttft_p95_s",
+        "layer": "model step (serve/engine.py join and decode loop)",
+        "workloads": ["qwen2-0.5b-b64.short_code"]}]
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+    monkeypatch.setattr(spec, "BENCH_DIR", str(bench_dir))
+    monkeypatch.setattr(spec, "ROOT", str(tmp_path))
+    c = spec.cell("qwen2-0.5b-b64.short_code")
+    assert c.config["serve"]["batch"] == 64 and c.traffic["max_new"] == 32
+    assert [m["name"] for m in c.per_layer] == ["join_share"]
+    assert spec.metric_module("join_share").read({}, None) == 1.0
+    assert spec.cell("qwen2-0.5b.offline_long_output").config["serve"][
+        "batch"] == 128
+    after = {p: open(os.path.join(r, p), "rb").read()
+             for r, _, fs in os.walk(bench_dir) for p in fs if p in before}
+    assert after == before
