@@ -110,9 +110,9 @@ class ServeConfig:
     # unified telemetry (repro.serve.telemetry): when True the batcher
     # builds a Tracer recording per-request lifecycle events, per-round
     # scheduler spans and pool-partition gauges (exportable as Perfetto
-    # trace_event JSON).  Off by default — the off path adds zero work
-    # to the jitted closures (all instrumentation sits at host-sync /
-    # scheduling-round boundaries, never inside lax.scan).
+    # trace_event JSON).  Off by default; on or off, the jitted closures
+    # and the host-device syncs are the same (all instrumentation sits
+    # at scheduling-round boundaries, never inside lax.scan).
     telemetry: bool = False
     # SLO monitor (repro.serve.scheduler.slo_stats): per-request latency
     # targets.  None disables the check for that metric (attainment is

@@ -213,7 +213,7 @@ from .overload import (CANCEL_REASONS, HEALTHY, RETRY_AFTER, STATES,
 from .prefixcache import PrefixCache
 # _pct moved to telemetry (the registry owns percentile math) but stays
 # importable from here — it has always been this module's public helper
-from .telemetry import MetricsRegistry, Tracer, _pct  # noqa: F401
+from .telemetry import MetricsRegistry, Tracer, _pct, phase  # noqa: F401
 from ..models.model_zoo import Model
 
 
@@ -1140,6 +1140,28 @@ class ContinuousBatcher:
 
     # ------------------------------------------------------------------
     def _refill(self, max_new: int) -> None:
+        """One round's prefill: the admission scan (PREFILLING slots'
+        next chunks, then new admissions into free slots), then one join
+        of every piece taken."""
+        with phase("admit", self.telemetry, self.round):
+            take = self._admit_pieces(max_new)
+        if not take:
+            return
+        with phase("join", self.telemetry, self.round) as span:
+            # the join prefills only each row's uncached suffix piece, so
+            # the padded width (and the jit bucket) shrinks with hit depth
+            # and is bounded by the chunk size
+            width = _pow2_bucket(
+                max(len(piece) for _, _, piece, _, _ in take),
+                lo=8, hi=self.cfg.max_len)
+            span.set_metadata(
+                rows_computed=self.cfg.batch, width=width,
+                tokens=sum(len(piece) for _, _, piece, _, _ in take))
+            self._join_pieces(take, width, max_new)
+
+    def _admit_pieces(self, max_new: int) -> list:
+        """The pieces this round's join prefills, as (slot, rid, piece
+        tokens, depth before this piece, commits?)."""
         chunk = self._effective_chunk()
         round_cap = self.cfg.prefill_round_tokens
         round_used = 0
@@ -1196,15 +1218,13 @@ class ContinuousBatcher:
                 # kept warm even while the controller sheds speculation,
                 # so a re-enabled drafter reads a correct corpus
                 self.history[slot, :len(p)] = p
-        if not take:
-            return
+        return take
+
+    def _join_pieces(self, take: list, width: int, max_new: int) -> None:
+        """Prefill ``take`` at ``width`` in one join of every row and
+        commit each completed prompt's first token."""
         t0 = time.perf_counter()
         b = self.cfg.batch
-        # the join prefills only each row's uncached suffix piece, so the
-        # padded width (and the jit bucket) shrinks with hit depth and is
-        # bounded by the chunk size
-        width = _pow2_bucket(max(len(piece) for _, _, piece, _, _ in take),
-                             lo=8, hi=self.cfg.max_len)
         self.metrics.observe("join.width", width)
         join_mask = np.zeros((b,), bool)
         commit_mask = np.zeros((b,), bool)
@@ -1302,8 +1322,6 @@ class ContinuousBatcher:
                 self.slot_budget[slot] = max_new
         t1 = time.perf_counter()
         self.metrics.observe("join.seconds", t1 - t0)
-        if self.telemetry is not None:
-            self.telemetry.add_span("join", self.round, t0, t1)
 
     # ------------------------------------------------------------------
     def _collect(self, emitted: np.ndarray) -> None:
@@ -1437,85 +1455,9 @@ class ContinuousBatcher:
         try:
             while self.queue or any(r is not None for r in self.slot_rid):
                 self.round += 1
-                if self.chaos is not None:
-                    if tr is not None:
-                        with tr.span("chaos", self.round):
-                            self.chaos.on_round(self)
-                    else:
-                        self.chaos.on_round(self)
-                if self.overload is not None:
-                    self._overload_round()
-                self._cancel_sweep(max_new)
-                # progress watchdog (replaces the old idle-spin counter +
-                # RuntimeError): *any* kind of stall — admission spin,
-                # livelock, chaos stall — trips it after watchdog_rounds
-                # rounds with unchanged progress counters, dumps the
-                # flight bundle, and sheds the blocking head so the run
-                # finishes instead of raising
-                self._watchdog_tick()
-                if self.round < self._stall_until:
-                    continue                      # chaos stall: dead round
-                self._refill(max_new)
-                if not any(r is not None and not self.slot_pending[i]
-                           for i, r in enumerate(self.slot_rid)):
-                    # nothing is decoding: if slots are still PREFILLING
-                    # (or the queue is waiting on pages) the next refill
-                    # round advances their chunks — a decode segment
-                    # would only burn a scan on all-done rows
-                    if self.queue or any(r is not None
-                                         for r in self.slot_rid):
-                        continue
-                    break
-                # optimistic admission: make every decoding slot's page
-                # table cover this segment's worst-case advance,
-                # preempting on pressure — may evict every decoding slot
-                # (chaos holds), in which case the next refill round
-                # re-admits from the queue
-                self._ensure_decode_pages(steps)
-                if not any(r is not None and not self.slot_pending[i]
-                           for i, r in enumerate(self.slot_rid)):
-                    continue
-                self._sample_kv()
-                seg_t0 = time.perf_counter() if tr is not None else 0.0
-                if self._spec_live():
-                    cap = self._page_cap()
-                    loop = self._loop(steps, cap)
-                    pages = jnp.asarray(self.pool.table[:, :cap])
-                    hist = jnp.asarray(self.history)
-                    ((self.tok, self.caches, self.lengths, self.done,
-                      self.remaining, self.key, hist), emitted) = loop(
-                        self.params, self.tok, self.caches, self.lengths,
-                        self.done, self.remaining, self.key, hist, pages)
-                    # np.array (not asarray): the device export is
-                    # read-only and the next join writes prompts into
-                    # this mirror
-                    self.history = np.array(hist)
-                elif self.pool is not None:
-                    cap = self._page_cap()
-                    loop = self._loop(steps, cap)
-                    pages = jnp.asarray(self.pool.table[:, :cap])
-                    ((self.tok, self.caches, self.lengths, self.done,
-                      self.remaining, self.key), emitted) = loop(
-                        self.params, self.tok, self.caches, self.lengths,
-                        self.done, self.remaining, self.key, pages)
-                else:
-                    loop = self._loop(steps, self._kv_cap(steps))
-                    ((self.tok, self.caches, self.lengths, self.done,
-                      self.remaining, self.key), emitted) = loop(
-                        self.params, self.tok, self.caches, self.lengths,
-                        self.done, self.remaining, self.key)
-                if tr is not None:
-                    # block so the segment span measures device wall
-                    # time, not dispatch — a tracing-on-only sync (the
-                    # off path's sync stays where it always was:
-                    # np.asarray below)
-                    jax.block_until_ready(emitted)
-                    tr.add_span("decode-segment", self.round, seg_t0,
-                                time.perf_counter())
-                    with tr.span("collect", self.round):
-                        self._collect(np.asarray(emitted))
-                else:
-                    self._collect(np.asarray(emitted))
+                with phase("round", tr, self.round):
+                    if not self._round(max_new, steps):
+                        break
         except PageError as err:
             # postmortem before the crash propagates: the flight
             # recorder's ring holds the last N lifecycle events leading
@@ -1525,6 +1467,83 @@ class ContinuousBatcher:
             self._dump_flight(err)
             raise
         return self.results
+
+    def _round(self, max_new: int, steps: int) -> bool:
+        """One scheduling round; False once nothing is left to decode."""
+        tr = self.telemetry
+        if self.chaos is not None:
+            with phase("chaos", tr, self.round):
+                self.chaos.on_round(self)
+        with phase("sweep", tr, self.round):
+            if self.overload is not None:
+                self._overload_round()
+            self._cancel_sweep(max_new)
+            # progress watchdog (replaces the old idle-spin counter +
+            # RuntimeError): *any* kind of stall — admission spin,
+            # livelock, chaos stall — trips it after watchdog_rounds
+            # rounds with unchanged progress counters, dumps the
+            # flight bundle, and sheds the blocking head so the run
+            # finishes instead of raising
+            self._watchdog_tick()
+        if self.round < self._stall_until:
+            return True                           # chaos stall: dead round
+        self._refill(max_new)
+        if not self._decoding_rows():
+            # nothing is decoding: if slots are still PREFILLING (or the
+            # queue is waiting on pages) the next refill round advances
+            # their chunks — a decode segment would only burn a scan on
+            # all-done rows
+            return bool(self.queue
+                        or any(r is not None for r in self.slot_rid))
+        with phase("pages", tr, self.round) as span:
+            # optimistic admission: make every decoding slot's page
+            # table cover this segment's worst-case advance, preempting
+            # on pressure — may evict every decoding slot (chaos holds),
+            # in which case the next refill round re-admits from the
+            # queue
+            self._ensure_decode_pages(steps)
+            rows = self._decoding_rows()
+            if rows:
+                live, mapped, _ = self._sample_kv()
+                span.set_metadata(live_tokens=live, mapped_tokens=mapped)
+                if self.pool is not None:
+                    cap = self._page_cap()
+                    pages = jnp.asarray(self.pool.table[:, :cap])
+                else:
+                    cap = self._kv_cap(steps)
+        if not rows:
+            return True
+        with phase("decode-segment", tr, self.round):
+            loop = self._loop(steps, cap)
+            if self._spec_live():
+                hist = jnp.asarray(self.history)
+                ((self.tok, self.caches, self.lengths, self.done,
+                  self.remaining, self.key, hist), emitted) = loop(
+                    self.params, self.tok, self.caches, self.lengths,
+                    self.done, self.remaining, self.key, hist, pages)
+                # np.array (not asarray): the device export is
+                # read-only and the next join writes prompts into this
+                # mirror
+                self.history = np.array(hist)
+            elif self.pool is not None:
+                ((self.tok, self.caches, self.lengths, self.done,
+                  self.remaining, self.key), emitted) = loop(
+                    self.params, self.tok, self.caches, self.lengths,
+                    self.done, self.remaining, self.key, pages)
+            else:
+                ((self.tok, self.caches, self.lengths, self.done,
+                  self.remaining, self.key), emitted) = loop(
+                    self.params, self.tok, self.caches, self.lengths,
+                    self.done, self.remaining, self.key)
+            emitted = np.asarray(emitted)
+        with phase("collect", tr, self.round):
+            self._collect(emitted)
+        return True
+
+    def _decoding_rows(self) -> int:
+        """Slots holding a request past its prefill."""
+        return sum(1 for i, r in enumerate(self.slot_rid)
+                   if r is not None and not self.slot_pending[i])
 
     # ------------------------------------------------------------------
     # flight recorder
@@ -1571,17 +1590,20 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------
     # KV memory accounting
     # ------------------------------------------------------------------
-    def _sample_kv(self) -> None:
-        """Record (live tokens, allocated token capacity, live slots) at a
-        segment boundary.  Dense allocates ``batch * max_len`` whether or
-        not slots are live; paged allocates only the mapped pages."""
+    def _sample_kv(self) -> tuple[int, int, int]:
+        """Record and return (live tokens, allocated token capacity, live
+        slots) at a segment boundary.  Dense allocates ``batch * max_len``
+        whether or not slots are live; paged allocates only the mapped
+        pages."""
         live = [i for i, r in enumerate(self.slot_rid) if r is not None]
         live_tokens = sum(self.slot_len[i] for i in live)
         if self.pool is not None:
             alloc = self.pool.used_pages * self.pool.page_size
         else:
             alloc = self.cfg.batch * self.cfg.max_len
-        self.kv_samples.append((live_tokens, alloc, len(live)))
+        sample = (live_tokens, alloc, len(live))
+        self.kv_samples.append(sample)
+        return sample
 
     def kv_utilization(self) -> dict:
         """Aggregate the per-segment samples: mean/peak KV utilization

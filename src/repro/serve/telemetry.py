@@ -17,8 +17,8 @@ event-level instrumentation; this module is that layer, in two parts:
 
 each stamped with the scheduling round, slot id, pages held by that slot
 and the pool's free-page count at the instant of the event, plus
-per-round scheduler **spans** (chaos / join / decode-segment / collect)
-and a pool-partition gauge sampled after every allocator mutation
+per-round scheduler **spans** (see *Phases* below) and a pool-partition
+gauge sampled after every allocator mutation
 (:attr:`repro.serve.kvpool.KVPool.gauge_cb`).  Chaos faults land in the
 same stream (``CHAOS_*`` kinds).  Two export shapes:
 
@@ -30,6 +30,15 @@ same stream (``CHAOS_*`` kinds).  Two export shapes:
   top), one async track for queue residency (SUBMIT/PREEMPT opens,
   ADMIT closes — requests overlap there, slots never do), one track of
   scheduler spans, and counter tracks for the pool partitions.
+
+**Phases** — :func:`phase` marks every part of a scheduling round
+(``round`` around ``chaos``, ``sweep``, ``admit``, ``join``, ``pages``,
+``decode-segment`` and ``collect``) as a ``jax.profiler.TraceAnnotation``
+named ``serve.<phase>``; the join's and the pages' carry as arguments
+the counts the host has there (rows, width, tokens; live and mapped KV
+tokens).  Under ``jax.profiler.trace`` they sit on one timeline with the
+device's operations; with a Tracer attached the same intervals also land
+in :attr:`Tracer.spans`.
 
 **MetricsRegistry** — counters, gauges and fixed-bucket histograms; the
 single store every ``*_stats()`` view and the ``BENCH_serve.json`` row
@@ -45,12 +54,14 @@ Naming convention: ``<subsystem>.<metric>[_<unit>]`` — e.g.
 ``pool.free_pages`` (gauge).  Keys are flat strings; ``snapshot()``
 returns one flat dict for row writers.
 
-Zero-overhead-off contract: the scheduler only calls into the tracer
-behind ``if tracer is not None`` guards at host-sync / scheduling-round
-boundaries — never inside ``lax.scan`` or any jitted closure — and the
-registry's counter increments are plain dict ops on the host path that
-already existed.  Telemetry off (the default) adds no device work and no
-per-token host work.
+Cost when off: the scheduler calls into the tracer only behind
+``if tracer is not None`` guards at scheduling-round boundaries — never
+inside ``lax.scan`` or any jitted closure — and the registry's counter
+increments are plain dict ops on the host path that already existed.
+The phase annotations are always opened: about eight per round, a few
+µs each on the host, recording nothing unless a profiler trace is
+running.  Neither adds device work or a host-device sync, so a run makes
+the same syncs with telemetry on and off.
 """
 from __future__ import annotations
 
@@ -59,6 +70,7 @@ import time
 from collections import deque
 from contextlib import contextmanager
 
+import jax
 import numpy as np
 
 
@@ -334,9 +346,6 @@ class Tracer:
         return sorted((dict(e) for e in self.events if e["rid"] == rid),
                       key=lambda e: e["t"])
 
-    def timelines(self) -> dict[int, list[dict]]:
-        return {rid: self.timeline(rid) for rid in self.rids()}
-
     # -- Perfetto export -----------------------------------------------
     def _us(self, t: float) -> float:
         return max(0.0, (t - self.t0) * 1e6)
@@ -347,9 +356,10 @@ class Tracer:
 
         Track layout (one process, pid 1):
 
-        * tid 0 ``scheduler`` — per-round spans (``ph:"X"``: chaos /
-          join / decode-segment / collect, strictly sequential) plus
-          chaos fault instants;
+        * tid 0 ``scheduler`` — the phase spans (``ph:"X"``: a
+          ``round`` span around its chaos / sweep / admit / join /
+          pages / decode-segment / collect spans, which follow one
+          another) plus chaos fault instants;
         * tid 1 ``queue`` — async spans (``ph:"b"``/``"e"``, id = rid)
           from SUBMIT (or PREEMPT) to ADMIT — queue residency overlaps
           across requests, which is what the async phase exists for;
@@ -445,3 +455,20 @@ class Tracer:
                 json.dump(data, f)
                 f.write("\n")
         return data
+
+
+@contextmanager
+def phase(name: str, tracer: Tracer | None, round: int, /, **counts):
+    """One phase of a scheduling round: a ``jax.profiler.TraceAnnotation``
+    named ``serve.<name>`` with ``counts`` as its arguments, and — when a
+    Tracer is attached — the same interval in ``tracer.spans`` under the
+    bare ``name``.  Yields the annotation; counts known only at the end
+    of the phase go on with ``set_metadata(**counts)``.  The annotation
+    records nothing unless a profiler trace is running."""
+    with jax.profiler.TraceAnnotation(f"serve.{name}", **counts) as ann:
+        t0 = tracer.now() if tracer is not None else 0.0
+        try:
+            yield ann
+        finally:
+            if tracer is not None:
+                tracer.add_span(name, round, t0, tracer.now())

@@ -5,21 +5,27 @@ page 16, bf16), with interpret mode off.
 Interpret mode does not enforce the TPU's block tiling or VMEM limits;
 the chip's compiler does, and it is installed here: it compiles for a
 chip that is described, not attached.  Nothing runs, so these tests say
-nothing about results or times — only that Mosaic accepts each kernel.
+nothing about results or times — only that Mosaic accepts each kernel,
+and that the paged kernels keep the custom-call names the roofline
+metrics of ``bench/metrics`` match in a profiler trace.
 
 The topology is described inside a fixture (never at import): only one
 process may load the TPU library, and every test worker imports this
 file.
 """
 import functools
+import importlib.util
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels.decode_attn import decode_attn_policy
 from repro.kernels.decode_attn.kernel import decode_attn_kernel
+from repro.kernels.paged_attn import paged_attn, paged_prefill_attn
 from repro.kernels.paged_attn.kernel import GRID_ORDERS, paged_attn_kernel
 from repro.kernels.paged_attn.prefill_kernel import paged_prefill_attn_kernel
 
@@ -90,3 +96,40 @@ def test_dense_decode_compiles(one_chip):
     _compile(functools.partial(decode_attn_kernel, interpret=False),
              one_chip, ((B, HKV, G, D), jnp.bfloat16), cache, cache,
              ((B,), jnp.int32))
+
+
+def _op_pattern(metric: str) -> str:
+    """The ``OP`` pattern of ``bench/metrics/<metric>.py``."""
+    path = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench", "metrics", f"{metric}.py")
+    spec = importlib.util.spec_from_file_location(f"pinned_{metric}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.OP
+
+
+def _custom_calls(fn, sharding, *shapes) -> list[str]:
+    """The compiled program's custom-call instructions, named as the
+    trace's ``XLA Ops`` line names them."""
+    with decode_attn_policy(mode="kernel", interpret=False):
+        text = _compile(fn, sharding, *shapes)
+    lines = (ln.strip().removeprefix("ROOT ") for ln in text.splitlines())
+    return [ln for ln in lines if "custom-call(" in ln]
+
+
+def test_decode_kernel_op_name(one_chip):
+    calls = _custom_calls(
+        lambda q, k, v, t, n: paged_attn(q, k, v, t, n, interpret=False),
+        one_chip, ((B, HQ, D), jnp.bfloat16), _pool(), _pool(),
+        ((B, MAX_LEN // PS), jnp.int32), ((B,), jnp.int32))
+    op = _op_pattern("paged_decode_attn_roofline")
+    assert any(re.search(op, c) for c in calls), calls
+
+
+def test_prefill_kernel_op_name(one_chip):
+    calls = _custom_calls(
+        paged_prefill_attn, one_chip, ((B, 32, HQ, D), jnp.bfloat16),
+        _pool(), _pool(), ((B, MAX_LEN // PS), jnp.int32),
+        ((B,), jnp.int32), ((B,), jnp.int32))
+    op = _op_pattern("paged_prefill_attn_roofline")
+    assert any(re.search(op, c) for c in calls), calls
