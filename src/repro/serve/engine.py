@@ -30,6 +30,8 @@ from ..kernels.decode_attn import decode_attn_policy
 from ..models.model_zoo import Model
 
 PAD_TOKEN = -1    # emitted-slot sentinel: "slot was already retired"
+# rows per prefill group of the paged join (make_paged_join)
+JOIN_GROUP_ROWS = 4
 
 
 @dataclasses.dataclass(frozen=True)
@@ -498,17 +500,29 @@ def jit_join(model: Model, cfg: ServeConfig, *, eos_id: int | None):
     return jax.jit(join, donate_argnums=(1, 2, 3, 4, 5))
 
 
+def paged_join_rows(joining: int) -> int:
+    """Rows the paged join program computes for ``joining`` joining
+    slots: whole groups of :data:`JOIN_GROUP_ROWS`."""
+    return -(-joining // JOIN_GROUP_ROWS) * JOIN_GROUP_ROWS
+
+
 def make_paged_join(model: Model, cfg: ServeConfig, *, eos_id: int | None):
-    """Paged slot refill with a suffix-only prefill path.  For *attention*
-    segments there is nothing to select afterwards: the batch prefill
-    *writes through the page table*, and rows outside ``join_mask`` get an
-    all-sentinel table so their scatters drop — occupied slots' pages stay
-    bit-for-bit intact inside one shared pooled allocation.  SSM segments
-    have per-slot recurrent state, not pages (init_paged_caches keeps them
-    dense), so the prefill's recompute of every row must still be masked
-    back with the dense join's batch-axis select — only joining rows take
-    the fresh state.  ``pages`` is the full-width device page table; only
-    its masked copy is handed to the prefill.
+    """Paged slot refill with a suffix-only prefill path that computes only
+    the joining rows.  The slots in ``join_mask`` are gathered, in groups
+    of :data:`JOIN_GROUP_ROWS` rows, into one ``lax.while_loop`` of
+    ``[R, width]`` prefills; each group's results are scattered back to
+    its slots.  Every other slot's state, and every page outside the
+    joiners' tables, is bit-for-bit untouched; with no joiner no group
+    runs and the state comes back as it went in.  The argument shapes are
+    the full batch's, so one program per width serves any joining count.
+    A group's padding rows (the last group's tail) get an all-sentinel
+    page table and ``plens`` 1, so their scatters drop.  For *attention*
+    segments the prefill *writes through the page table* into the one
+    shared pooled allocation; SSM segments have per-slot recurrent state,
+    not pages (init_paged_caches keeps them dense), so a group gathers
+    its slots' state rows and scatters the fresh ones back.  ``pages`` is
+    the full-width device page table.  The returned ``first`` is [B]:
+    joining rows' sampled tokens, ``PAD_TOKEN`` elsewhere.
 
     Prefix sharing (repro.serve.prefixcache): ``prompts`` carries only
     each joining row's *uncached suffix* and ``prefix_lens`` [B] its
@@ -519,10 +533,15 @@ def make_paged_join(model: Model, cfg: ServeConfig, *, eos_id: int | None):
     strictly below every write), RoPE continues at the absolute position,
     and the suffix queries attend *over the already-resident prefix pages*
     through the table gather — the prefix is neither recomputed nor
-    restored.  Rows hitting a shared prefix in the same join as the row
-    that first prefills it are still exact: per layer the pooled scatter
-    precedes the gather, so the writer row's pages are visible to every
-    reader row of the same call.
+    restored.  A row may read a page that another row of the same join
+    writes (a queue-mate matching pages a chunk or an admission of this
+    round covers).  The writer writes at positions at or past its own
+    ``prefix_len`` and the reader reads below its own, so the writer's
+    ``prefix_len`` is strictly the smaller: the groups take the joining
+    slots shallowest cached prefix first (ties in slot order), which puts
+    every writer in the same group as its readers or an earlier one.
+    Within a group per layer the pooled scatter precedes the gather, so
+    every reader sees its writer's pages.
 
     Chunked prefill adds ``commit_mask`` [B]: the subset of joining rows
     whose prompt *completes* with this call.  Commit rows sample their
@@ -539,40 +558,69 @@ def make_paged_join(model: Model, cfg: ServeConfig, *, eos_id: int | None):
     temp = cfg.temperature
     sentinel = cfg.pool_pages      # OOB page id (see kvpool.KVPool)
     seg_kinds = [s.kind for s in model.cfg.resolved_segments()]
+    r = JOIN_GROUP_ROWS
 
     def join(params, caches, tok, lengths, done, remaining,
              join_mask, prompts, plens, budgets, key, pages, prefix_lens,
              commit_mask):
-        write_tbl = jnp.where(join_mask[:, None], pages, sentinel)
-        with decode_attn_policy(mode=cfg.attn_mode,
-                                interpret=cfg.attn_interpret):
-            logits, new_caches = model.prefill_paged(
-                params, {"tokens": prompts}, caches, write_tbl,
-                dtype=cfg.dtype, last_pos=plens - 1,
-                cache_len=prefix_lens)
+        b = join_mask.shape[0]
+        count = join_mask.sum(dtype=jnp.int32)
+        # joining slots first, shallowest cached prefix first (writers
+        # before their readers), then the padding index b, whose
+        # scatters drop; padded to whole groups
+        depth = jnp.where(join_mask, prefix_lens, jnp.iinfo(jnp.int32).max)
+        order = jnp.argsort(depth, stable=True).astype(jnp.int32)
+        slots = jnp.where(jnp.arange(b) < count, order, b)
+        slots = jnp.pad(slots, (0, -b % r), constant_values=b)
 
-        def select(new, old):
-            # leaves are [layers, B, ...]: mask on the batch axis
-            m = join_mask.reshape((1, join_mask.shape[0])
-                                  + (1,) * (new.ndim - 2))
-            return jnp.where(m, new.astype(old.dtype), old)
+        def rows(a, idx, fill=0, axis=0):
+            return jnp.take(a, idx, axis=axis, mode="fill", fill_value=fill)
 
-        caches = [jax.tree_util.tree_map(select, nc, oc)
-                  if kind is BlockKind.SSM else nc
-                  for kind, nc, oc in zip(seg_kinds, new_caches, caches)]
-        key, sub = jax.random.split(key)
-        first = sample_tokens(logits[:, -1], sub, temp)
-        if eos_id is None:
-            is_eos = jnp.zeros_like(join_mask)
-        else:
-            is_eos = first == eos_id
-        rem_new = budgets - 1
-        tok = jnp.where(commit_mask[:, None], first[:, None], tok)
-        lengths = jnp.where(join_mask, prefix_lens + plens, lengths)
-        remaining = jnp.where(commit_mask, rem_new,
-                              jnp.where(join_mask, 0, remaining))
-        done = jnp.where(commit_mask, is_eos | (rem_new <= 0),
-                         jnp.where(join_mask, True, done))
+        def body(carry):
+            g, caches, tok, lengths, done, remaining, key, first = carry
+            idx = jax.lax.dynamic_slice(slots, (g * r,), (r,))
+            cache_len = rows(prefix_lens, idx)
+            plen = rows(plens, idx, 1)
+            # SSM leaves are [layers, B, ...]: their rows are on axis 1
+            in_caches = [
+                jax.tree_util.tree_map(lambda c: rows(c, idx, axis=1), oc)
+                if kind is BlockKind.SSM else oc
+                for kind, oc in zip(seg_kinds, caches)]
+            with decode_attn_policy(mode=cfg.attn_mode,
+                                    interpret=cfg.attn_interpret):
+                logits, new_caches = model.prefill_paged(
+                    params, {"tokens": rows(prompts, idx)}, in_caches,
+                    rows(pages, idx, sentinel), dtype=cfg.dtype,
+                    last_pos=plen - 1, cache_len=cache_len)
+            caches = [
+                jax.tree_util.tree_map(
+                    lambda n, o: o.at[:, idx].set(n.astype(o.dtype),
+                                                  mode="drop"), nc, oc)
+                if kind is BlockKind.SSM else nc
+                for kind, nc, oc in zip(seg_kinds, new_caches, caches)]
+            key, sub = jax.random.split(key)
+            f = sample_tokens(logits[:, -1], sub, temp)
+            commit = rows(commit_mask, idx)      # False on padding rows
+            is_eos = (jnp.zeros_like(commit) if eos_id is None
+                      else f == eos_id)
+            rem_new = rows(budgets, idx) - 1
+            tok = tok.at[idx, 0].set(
+                jnp.where(commit, f, rows(tok, idx)[:, 0]), mode="drop")
+            lengths = lengths.at[idx].set(cache_len + plen, mode="drop")
+            remaining = remaining.at[idx].set(
+                jnp.where(commit, rem_new, 0), mode="drop")
+            done = done.at[idx].set(
+                jnp.where(commit, is_eos | (rem_new <= 0), True),
+                mode="drop")
+            first = first.at[idx].set(f, mode="drop")
+            return g + 1, caches, tok, lengths, done, remaining, key, first
+
+        groups = -(-count // r)
+        first = jnp.full((b,), PAD_TOKEN, jnp.int32)
+        carry = (jnp.int32(0), caches, tok, lengths, done, remaining, key,
+                 first)
+        (_, caches, tok, lengths, done, remaining, key,
+         first) = jax.lax.while_loop(lambda c: c[0] < groups, body, carry)
         return caches, tok, lengths, done, remaining, key, first
     return join
 

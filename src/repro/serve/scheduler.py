@@ -91,8 +91,9 @@ page-aligned, so a frozen slot's placeholder decode writes (overwritten
 by the next chunk) can never land in a shared prefix page, and prompt
 pages are registered in the radix tree *as chunks cover them* — a
 queue-mate can match and gather a page in the same join that writes it
-(scatters precede gathers per layer), but never one the writer has not
-reached.
+(the join prefills its rows shallowest cached prefix first, so the writer
+runs in the reader's group or an earlier one, and per layer scatters
+precede gathers), but never one the writer has not reached.
 
 Decode-priority chunk budget (``cfg.prefill_round_tokens``): by default a
 refill round takes one chunk from *every* PREFILLING slot plus the first
@@ -205,7 +206,7 @@ import numpy as np
 
 from .engine import (PAD_TOKEN, ServeConfig, jit_decode_loop, jit_join,
                      jit_paged_decode_loop, jit_paged_join,
-                     jit_spec_decode_loop)
+                     jit_spec_decode_loop, paged_join_rows)
 from .kvpool import KVPool, PageError
 from .overload import (CANCEL_REASONS, HEALTHY, RETRY_AFTER, STATES,
                        DegradationController, Watchdog, WatchdogStall,
@@ -1154,8 +1155,11 @@ class ContinuousBatcher:
             width = _pow2_bucket(
                 max(len(piece) for _, _, piece, _, _ in take),
                 lo=8, hi=self.cfg.max_len)
+            rows = (paged_join_rows(len(take)) if self.pool is not None
+                    else self.cfg.batch)
+            self.metrics.inc("join.rows_computed", rows)
             span.set_metadata(
-                rows_computed=self.cfg.batch, width=width,
+                rows_computed=rows, width=width,
                 tokens=sum(len(piece) for _, _, piece, _, _ in take))
             self._join_pieces(take, width, max_new)
 
@@ -1170,7 +1174,8 @@ class ContinuousBatcher:
         # 1. PREFILLING slots first: their next chunk rides this join, and
         #    its about-to-be-covered pages are registered *before* the
         #    admission scan so queue-mates can match them (their KV is
-        #    written by this very join; scatters precede gathers)
+        #    written by this very join, whose writer rows run no later
+        #    than their readers: see make_paged_join)
         for slot, rid in enumerate(self.slot_rid):
             if rid is None or not self.slot_pending[slot]:
                 continue
@@ -1221,7 +1226,8 @@ class ContinuousBatcher:
         return take
 
     def _join_pieces(self, take: list, width: int, max_new: int) -> None:
-        """Prefill ``take`` at ``width`` in one join of every row and
+        """Prefill ``take`` at ``width`` in one join call (full-batch
+        arrays; the paged join computes only the rows in ``take``) and
         commit each completed prompt's first token."""
         t0 = time.perf_counter()
         b = self.cfg.batch

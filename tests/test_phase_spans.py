@@ -11,6 +11,7 @@ nor adds a host-device sync.
 """
 import glob
 import os
+from collections import Counter
 
 import jax
 import jax.numpy as jnp
@@ -22,7 +23,7 @@ from repro.configs import get_config
 from repro.models import param as pm
 from repro.models.model_zoo import Model
 from repro.serve.chaos import ChaosInjector
-from repro.serve.engine import ServeConfig
+from repro.serve.engine import JOIN_GROUP_ROWS, ServeConfig
 from repro.serve.scheduler import Batcher
 from repro.serve.telemetry import Tracer
 
@@ -112,13 +113,20 @@ def test_every_phase_nests_in_its_round_in_order(traced):
 
 
 def test_span_counts_agree_with_the_batcher(traced):
-    b, results, spans, _ = traced
+    b, results, spans, tr = traced
     args = {p: [sp[3] for sp in spans if sp[0] == p]
             for p in ("round", *PHASES)}
     joins = args["join"]
     assert sum(a["tokens"] for a in joins) == \
         b.metrics.value("prefill.computed_tokens")
-    assert all(a["rows_computed"] == SERVE["batch"] for a in joins)
+    # the join computes whole groups of JOIN_GROUP_ROWS over its pieces
+    pieces = Counter(e["round"] for e in tr.events
+                     if e["kind"] == "PREFILL_CHUNK")
+    r = JOIN_GROUP_ROWS
+    assert [a["rows_computed"] for a in joins] == \
+        [-(-pieces[n] // r) * r for n in sorted(pieces)]
+    assert sum(a["rows_computed"] for a in joins) == \
+        b.metrics.value("join.rows_computed")
     widths = [a["width"] for a in joins]
     assert all(w >= 8 and w & (w - 1) == 0 for w in widths)
     assert sorted(widths) == sorted(b.metrics.samples("join.width"))
