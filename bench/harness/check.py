@@ -17,8 +17,6 @@ the token it would put first, measured by the float32 reference.
 """
 from __future__ import annotations
 
-import importlib
-
 import jax.numpy as jnp
 import numpy as np
 
@@ -34,11 +32,6 @@ def sample(finished: list[tuple[int, int]], k: int, seed: int) -> list[int]:
     rng = np.random.default_rng([seed, 99])
     pick = rng.permutation(len(rest))[:max(0, k - 1)]
     return [longest] + sorted(rest[i] for i in pick)
-
-
-def reference(cfg: dict):
-    return importlib.import_module(
-        f"bench.reference.{cfg['reference']['module']}")
 
 
 def _gaps(ref_logits, chosen) -> np.ndarray:

@@ -5,7 +5,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import gc
-import importlib
 import math
 import os
 import shutil
@@ -89,7 +88,6 @@ class Overrides:
     (the command line never sets them)."""
     device: dict | None = None          # skip the look for a chip
     arch: object = None                 # ArchConfig in place of the file's
-    dims: dict | None = None            # reference sizes to match ``arch``
     serve: dict | None = None           # ServeConfig fields to replace
     # calibration (bench/calibrate.py): programs shared between the runs
     # of one process, and the control's reading beside the program's
@@ -214,10 +212,8 @@ def run(workload: str, seed: int, seconds: int, trace: bool, t_start: float,
     from repro.serve.scheduler import ContinuousBatcher
 
     cfg, mix = cell.config, cell.traffic
-    ref = check.reference(cfg)
-    fam = importlib.import_module(
-        f"bench.harness.families.{cfg['reference']['module']}")
-    m = ov.dims or ref.dims(cfg)
+    ref, fam = spec.reference_module(cfg), spec.family_module(cfg)
+    m = ref.dims(cfg)
     arch = ov.arch or fam.arch_config(cfg, m)
     serve = dict(cfg["serve"], **(ov.serve or {}))
     scfg = ServeConfig(dtype=jnp.bfloat16, **serve)

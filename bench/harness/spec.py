@@ -3,8 +3,12 @@
 ``BENCHMARK.json`` names each cell's configuration and traffic mix; the
 configuration is ``bench/configs/<config>.json``, the mix
 ``bench/traffic/<traffic>.json`` and each per-layer metric
-``bench/metrics/<metric>.py``.  Adding a file of any of these kinds and
-an entry for it in ``BENCHMARK.json`` needs no edit to any other file.
+``bench/metrics/<metric>.py``.  A configuration names its plain
+reference, ``bench/reference/<module>.py``, and the module that hands
+that reference's weights to the program,
+``bench/harness/families/<module>.py``, under ``reference.module``.
+Adding a file of any of these kinds and an entry for it in
+``BENCHMARK.json`` needs no edit to any other file.
 """
 from __future__ import annotations
 
@@ -34,15 +38,37 @@ def traffic(name: str) -> dict:
     return _json(os.path.join(BENCH_DIR, "traffic", f"{name}.json"))
 
 
+_LOADED: dict = {}
+
+
+def _module(kind: str, name: str):
+    """``bench/<kind>/<name>.py``, loaded once per path."""
+    path = os.path.join(BENCH_DIR, kind, f"{name}.py")
+    if path not in _LOADED:
+        spec = importlib.util.spec_from_file_location(
+            f"bench_{kind.replace('/', '_')}_{name.replace('.', '_')}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        _LOADED[path] = mod
+    return _LOADED[path]
+
+
 def metric_module(name: str):
     """The reader of a per-layer metric: a module with ``LAYER``, ``UNIT``,
     ``MOVES`` and ``read(record, trace) -> float | None``."""
-    path = os.path.join(BENCH_DIR, "metrics", f"{name}.py")
-    spec = importlib.util.spec_from_file_location(
-        f"bench_metric_{name.replace('.', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    return _module("metrics", name)
+
+
+def reference_module(cfg: dict):
+    """The configuration's plain reference: ``dims(cfg)``,
+    ``make_weights(m, seed)`` and ``logits_at(w, m, tokens, read, quant=)``."""
+    return _module("reference", cfg["reference"]["module"])
+
+
+def family_module(cfg: dict):
+    """What hands the reference's weights to the program:
+    ``arch_config(cfg, m)`` and ``to_program(w)``."""
+    return _module("harness/families", cfg["reference"]["module"])
 
 
 def peaks(device_kind: str) -> dict:

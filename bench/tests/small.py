@@ -3,10 +3,8 @@ configuration's own family at ``reduced()`` widths, a few slots, short
 prompts and outputs, and the device check skipped.  For the tests only;
 the command line never runs a cell this way."""
 import dataclasses
-import os
 
 from bench.harness import runner, spec
-from bench.reference import dense_gqa
 from repro.configs import get_config
 
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
@@ -16,26 +14,10 @@ CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 TEST_LIMIT = 0.01
 
 
-def _cell(name: str) -> spec.Cell:
-    """The cell as BENCHMARK.json lists it; a cell kept as files alone
-    (its configuration and traffic mix) runs with every metric file."""
-    bench = spec.benchmark()
-    if any(w["name"] == name for w in bench["workloads"]):
-        return spec.cell(name, bench)
-    conf, mix = name.rsplit(".", 1)
-    names = sorted(f[:-3] for f in os.listdir(os.path.join(
-        spec.BENCH_DIR, "metrics")) if f.endswith(".py"))
-    per = tuple({"name": n, "unit": spec.metric_module(n).UNIT}
-                for n in names)
-    e2e = ({"name": "ttft_p95_s", "unit": "s"},
-           {"name": "tpot_p95_ms", "unit": "ms"},
-           {"name": "setup_s", "unit": "s"})
-    return spec.Cell(name, 1, spec.config(conf), spec.traffic(mix), e2e,
-                     per, bench["run_seconds"])
-
-
 def cell(name: str, *, sample: int = 4):
-    c = _cell(name)
+    """The cell as BENCHMARK.json lists it, cut to the test size, and the
+    overrides that run it there."""
+    c = spec.cell(name)
     cfg = dict(c.config)
     arch = get_config(cfg["program"]["arch"]).reduced()
     arch = dataclasses.replace(arch, **cfg["program"].get("arch_overrides",
@@ -60,7 +42,9 @@ def cell(name: str, *, sample: int = 4):
                    prompt_tokens={"dist": "lognormal", "median": 40,
                                   "sigma": 0.6, "min": 8, "max": 100},
                    output_tokens={"dist": "fixed", "value": 12}, max_new=12)
-    ov = runner.Overrides(device=CPU, arch=arch, dims=dense_gqa.dims(cfg),
+    # the sizes the reference runs come from the configuration's own
+    # reference module, ``dims`` of the cut configuration
+    ov = runner.Overrides(device=CPU, arch=arch,
                           serve={"batch": 4, "max_len": 256,
                                  "prefill_chunk": 32})
     return dataclasses.replace(c, config=cfg, traffic=mix), ov

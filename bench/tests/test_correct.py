@@ -7,20 +7,22 @@ are in PERF.md."""
 import time
 
 import numpy as np
+import pytest
 
 import repro.models.attention as attention
 import repro.serve.engine as engine
-from bench.harness import check, runner
+from bench.harness import check, runner, spec
 from bench.reference import dense_gqa
 from bench.tests import small
 
-CELL = "qwen2-0.5b.offline_long_output"
+CELLS = [w["name"] for w in spec.benchmark()["workloads"]]
 
 
-def test_control_fails_where_the_program_passes():
-    c, ov = small.cell(CELL)
+@pytest.mark.parametrize("cell", CELLS)
+def test_control_fails_where_the_program_passes(cell):
+    c, ov = small.cell(cell)
     ov.control = True
-    res = runner.run(CELL, 2**31 + 303, 3, False, time.perf_counter(),
+    res = runner.run(cell, 2**31 + 303, 3, False, time.perf_counter(),
                      cell=c, ov=ov)
     prog = res["compared"]["max_logit_gap"]["value"]
     ctrl = res["compared"]["control_max_logit_gap"]["value"]
@@ -29,7 +31,8 @@ def test_control_fails_where_the_program_passes():
     assert ctrl >= 3 * max(prog, 1e-3)
 
 
-def test_altered_token_fails(monkeypatch):
+@pytest.mark.parametrize("cell", CELLS)
+def test_altered_token_fails(monkeypatch, cell):
     real = engine.sample_tokens
 
     def altered(logits, key, temperature):
@@ -37,14 +40,15 @@ def test_altered_token_fails(monkeypatch):
     # the decode loop and the join look the sampler up when they are
     # traced, which happens inside the run
     monkeypatch.setattr(engine, "sample_tokens", altered)
-    c, ov = small.cell(CELL)
-    res = runner.run(CELL, 2**31 + 304, 3, False, time.perf_counter(),
+    c, ov = small.cell(cell)
+    res = runner.run(cell, 2**31 + 304, 3, False, time.perf_counter(),
                      cell=c, ov=ov)
     assert res["correct"] is False
     assert res["compared"]["max_logit_gap"]["value"] > 10 * small.TEST_LIMIT
 
 
-def test_decode_cache_write_left_out_fails(monkeypatch):
+@pytest.mark.parametrize("cell", CELLS)
+def test_decode_cache_write_left_out_fails(monkeypatch, cell):
     # the decode step returns the paged cache unchanged: the token it
     # feeds back is never written, and later steps read a stale page
     real = attention._paged_insert
@@ -52,8 +56,8 @@ def test_decode_cache_write_left_out_fails(monkeypatch):
     def unchanged(pool, vals, table, length):
         return pool if vals.shape[1] == 1 else real(pool, vals, table, length)
     monkeypatch.setattr(attention, "_paged_insert", unchanged)
-    c, ov = small.cell(CELL)
-    res = runner.run(CELL, 2**31 + 305, 3, False, time.perf_counter(),
+    c, ov = small.cell(cell)
+    res = runner.run(cell, 2**31 + 305, 3, False, time.perf_counter(),
                      cell=c, ov=ov)
     assert res["correct"] is False
     assert res["compared"]["max_logit_gap"]["value"] > 10 * small.TEST_LIMIT

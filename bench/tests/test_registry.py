@@ -1,14 +1,17 @@
-"""Every configuration, traffic mix and metric loads by name, agrees with
-BENCHMARK.json, and a new one of each kind is found without an edit to
-any file that is already there."""
+"""Every configuration (with its reference and family modules), traffic
+mix and metric loads by name, agrees with BENCHMARK.json, and a new one
+of each kind is found without an edit to any file that is already
+there."""
 import json
 import os
 import re
 import shutil
+import time
 
 import pytest
 
-from bench.harness import spec
+from bench.harness import runner, spec
+from bench.tests import small
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -47,6 +50,9 @@ def test_config_file_loads_by_name(cfg):
     assert data["name"] == cfg["name"] and data["source"] == cfg["source"]
     for key in cfg["reduced"]:
         assert key in data and NAME.match(key)
+    # the program's registry entry agrees with the sizes the reference runs
+    m = spec.reference_module(data).dims(data)
+    spec.family_module(data).arch_config(data, m)
 
 
 @pytest.mark.parametrize("w", BENCH["workloads"], ids=lambda w: w["name"])
@@ -81,6 +87,19 @@ def test_new_files_need_no_edit(tmp_path, monkeypatch):
     cfg = spec.config("qwen2-0.5b")
     cfg["name"] = "qwen2-0.5b-b64"
     cfg["serve"] = dict(cfg["serve"], batch=64)
+    # its own reference and family modules, copies of dense_gqa that
+    # record being called
+    cfg["reference"] = dict(cfg["reference"], module="plain_decoder")
+    ref_src = open(bench_dir / "reference" / "dense_gqa.py").read()
+    (bench_dir / "reference" / "plain_decoder.py").write_text(
+        ref_src + "\n\nMADE = []\n_make = make_weights\n\n\n"
+        "def make_weights(m, seed, dtype=jnp.bfloat16):\n"
+        "    MADE.append(seed)\n    return _make(m, seed, dtype)\n")
+    fam_src = open(bench_dir / "harness" / "families" / "dense_gqa.py").read()
+    (bench_dir / "harness" / "families" / "plain_decoder.py").write_text(
+        fam_src + "\n\nHANDED = []\n_to = to_program\n\n\n"
+        "def to_program(w):\n    HANDED.append(len(w))\n"
+        "    return _to(w)\n")
     (bench_dir / "configs" / "qwen2-0.5b-b64.json").write_text(
         json.dumps(cfg))
     mix = dict(spec.traffic("code_completion"), max_new=32)
@@ -113,6 +132,17 @@ def test_new_files_need_no_edit(tmp_path, monkeypatch):
     assert spec.metric_module("join_share").read({}, None) == 1.0
     assert spec.cell("qwen2-0.5b.offline_long_output").config["serve"][
         "batch"] == 128
+    ref, fam = spec.reference_module(c.config), spec.family_module(c.config)
+    assert ref.__file__ == str(bench_dir / "reference" / "plain_decoder.py")
+    assert fam.__file__ == str(
+        bench_dir / "harness" / "families" / "plain_decoder.py")
+    # the harness runs the new cell through them (at the test size)
+    seed = 2**31 + 909
+    sc, ov = small.cell(c.name)
+    res = runner.run(c.name, seed, 2, False, time.perf_counter(), cell=sc,
+                     ov=ov)
+    assert res["correct"] is True and res["attempted"] > 0
+    assert ref.MADE == [seed, seed] and len(fam.HANDED) == 1
     after = {p: open(os.path.join(r, p), "rb").read()
              for r, _, fs in os.walk(bench_dir) for p in fs if p in before}
     assert after == before

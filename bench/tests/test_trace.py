@@ -1,6 +1,6 @@
 """The reduction from a profiler trace to busy time, idle share, kernel
 and program time and labelled idle gaps: on a hand-made trace whose
-answers are known, and on a small trace recorded on the chip."""
+answers are known, and on small traces recorded on the chip, one per cell."""
 import gzip
 import json
 import os
@@ -91,11 +91,12 @@ def test_readers_find_nothing_in_an_empty_trace():
         assert spec.metric_module(m["name"]).read(rec, r) is None
 
 
-RECORDED = os.path.join(DATA, "qwen2_offline_trace.json.gz")
+RECORDED = ["qwen2_offline_trace.json.gz", "starcoder2_code_trace.json.gz"]
 
 
-def test_recorded_chip_trace():
-    with gzip.open(RECORDED, "rt") as f:
+@pytest.mark.parametrize("name", RECORDED)
+def test_recorded_chip_trace(name):
+    with gzip.open(os.path.join(DATA, name), "rt") as f:
         t = json.load(f)
     r = Reduced(t)
     assert 0 < r.busy_s <= r.window_s

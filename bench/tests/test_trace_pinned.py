@@ -1,16 +1,17 @@
 """The numbers the accepted per-layer metrics read on the committed chip
-trace, pinned to the bit: a change to the trace reduction
+traces, one per cell, pinned to the bit: a change to the trace reduction
 (``bench/harness/trace.py``) that keeps more of the trace must leave
 them exactly as they are."""
 import gzip
 import json
 import os
 
+import pytest
+
 from bench.harness import spec
 from bench.harness.trace import Reduced
 
-RECORDED = os.path.join(os.path.dirname(__file__), "data",
-                        "qwen2_offline_trace.json.gz")
+DATA = os.path.join(os.path.dirname(__file__), "data")
 LEDGER = {"prefill_flops": 1e12, "decode_flops": 2e12,
           "prefill_attn_flops": 3e10, "prefill_attn_bytes": 4e9,
           "decode_attn_flops": 5e9, "decode_attn_bytes": 6e9}
@@ -18,13 +19,26 @@ PINNED = {"decode_mfu": 0.9440885449280972,
           "paged_decode_attn_roofline": 1.17696162577125,
           "device_idle_share": 0.12926823394466824,
           "serve_mfu": 0.26848429036009674}
+# one join (one group of rows) and the decode segment after it, with 1 ms
+# on either side, cut from a traced run of starcoder2-3b.code_completion
+PINNED_CODE = {"prefill_mfu": 0.7092067028797032,
+               "paged_prefill_attn_roofline": 0.8481940199444469,
+               "decode_mfu": 0.8845576384206261,
+               "paged_decode_attn_roofline": 0.9606047780695242,
+               "device_idle_share": 1.212555245896385}
+RECORDED = {
+    "qwen2_offline_trace.json.gz": (PINNED, (5.664666956, 5.671999049)),
+    "starcoder2_code_trace.json.gz": (PINNED_CODE, (1.86347233, 1.886345309)),
+}
 
 
-def test_accepted_metrics_read_the_pinned_values():
-    with gzip.open(RECORDED, "rt") as f:
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_accepted_metrics_read_the_pinned_values(name):
+    pinned, busy_window = RECORDED[name]
+    with gzip.open(os.path.join(DATA, name), "rt") as f:
         r = Reduced(json.load(f))
     rec = {"ledger": LEDGER, "peaks": spec.peaks("TPU v5 lite"),
            "queue_waits": []}
-    got = {name: spec.metric_module(name).read(rec, r) for name in PINNED}
-    assert got == PINNED
-    assert (r.busy_s, r.window_s) == (5.664666956, 5.671999049)
+    got = {n: spec.metric_module(n).read(rec, r) for n in pinned}
+    assert got == pinned
+    assert (r.busy_s, r.window_s) == busy_window
