@@ -13,18 +13,18 @@ gone idle) :meth:`Client.pump`
 * submits the requests that are due (open loop) or tops the queue up to
   its backlog (closed);
 * while the traced stretch is open, adds up the needed work of the
-  tokens that became visible, per layer of the program.
+  prompt pieces and tokens that became visible, as the configuration's
+  family (``bench/harness/families``) names and counts it.
 
 ``pick_victim`` returns None, so the hook never changes the scheduler's
 own preemption policy.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import math
 import time
-
-from . import work
 
 
 @dataclasses.dataclass
@@ -43,9 +43,10 @@ class Rec:
 
 
 class Client:
-    def __init__(self, stream, mix: dict, batch: int, dims: dict,
+    def __init__(self, stream, mix: dict, batch: int, dims: dict, family,
                  clock=time.perf_counter):
         self.stream, self.mix, self.m, self.clock = stream, mix, dims, clock
+        self.family = family                # prefill_work, decode_work
         arr = mix["arrivals"]
         self.closed = arr["kind"] == "closed"
         self.backlog = (math.ceil(arr.get("queued_per_slot", 1.0) * batch)
@@ -64,14 +65,8 @@ class Client:
         self.on_tick = None                 # called after each pump:
         self.on_phase = None                # the tracer, then ``drive``
         self.account = False                # add up needed work
-        self.ledger = self._empty_ledger()
-
-    @staticmethod
-    def _empty_ledger() -> dict:
-        return {"prefill_flops": 0, "decode_flops": 0,
-                "prefill_attn_flops": 0, "prefill_attn_bytes": 0,
-                "decode_attn_flops": 0, "decode_attn_bytes": 0,
-                "prefill_tokens": 0, "decode_tokens": 0}
+        # needed work by name; a name no piece or token gave reads 0
+        self.ledger: collections.Counter = collections.Counter()
 
     # the batcher's chaos hook -------------------------------------------
     def on_round(self, batcher) -> None:
@@ -104,7 +99,7 @@ class Client:
     def _observe(self, b, now: float) -> None:
         in_window = self.counting
         acct = self.account
-        led = self.ledger
+        led, fam, m = self.ledger, self.family, self.m
         for slot, rid in enumerate(b.slot_rid):
             rec = self.live.get(rid)
             if rec is None:
@@ -112,12 +107,8 @@ class Client:
             filled = b.slot_filled[slot]
             if filled > rec.filled:
                 if acct:
-                    commit = filled == rec.prompt_len
-                    led["prefill_flops"] += work.prefill_flops(
-                        self.m, rec.filled, filled, commit)
-                    f, by = work.prefill_attn_work(self.m, rec.filled, filled)
-                    led["prefill_attn_flops"] += f
-                    led["prefill_attn_bytes"] += by
+                    led.update(fam.prefill_work(m, rec.filled, filled,
+                                                filled == rec.prompt_len))
                     led["prefill_tokens"] += filled - rec.filled
                 rec.filled = filled
         outputs = b.outputs
@@ -136,11 +127,7 @@ class Client:
                     # output token i >= 1 comes from the decode step of the
                     # token at position prompt_len + i - 1
                     for i in range(max(rec.seen, 1), n):
-                        pos = rec.prompt_len + i - 1
-                        led["decode_flops"] += work.decode_flops(self.m, pos)
-                        f, by = work.decode_attn_work(self.m, pos)
-                        led["decode_attn_flops"] += f
-                        led["decode_attn_bytes"] += by
+                        led.update(fam.decode_work(m, rec.prompt_len + i - 1))
                     led["decode_tokens"] += n - max(rec.seen, 1)
                 rec.seen = n
             if len(out) >= rec.out_len:

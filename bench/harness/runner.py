@@ -87,7 +87,8 @@ class Overrides:
     """Hooks the tests use to run the harness on the CPU at a small size
     (the command line never sets them)."""
     device: dict | None = None          # skip the look for a chip
-    arch: object = None                 # ArchConfig in place of the file's
+    arch: object = None                 # ArchConfig in place of the file's,
+                                        # checked against dims all the same
     serve: dict | None = None           # ServeConfig fields to replace
     # calibration (bench/calibrate.py): programs shared between the runs
     # of one process, and the control's reading beside the program's
@@ -214,7 +215,8 @@ def run(workload: str, seed: int, seconds: int, trace: bool, t_start: float,
     cfg, mix = cell.config, cell.traffic
     ref, fam = spec.reference_module(cfg), spec.family_module(cfg)
     m = ref.dims(cfg)
-    arch = ov.arch or fam.arch_config(cfg, m)
+    arch = ov.arch or fam.arch_config(cfg)
+    fam.check(arch, m)
     serve = dict(cfg["serve"], **(ov.serve or {}))
     scfg = ServeConfig(dtype=jnp.bfloat16, **serve)
     max_new = int(mix["max_new"])
@@ -222,7 +224,7 @@ def run(workload: str, seed: int, seconds: int, trace: bool, t_start: float,
     w = ref.make_weights(m, seed)
     jax.block_until_ready(w)
     stream = RequestStream(mix, seed, m["vocab"], scfg.batch)
-    client = Client(stream, mix, scfg.batch, m)
+    client = Client(stream, mix, scfg.batch, m, fam)
     b = ContinuousBatcher(build_model(arch), fam.to_program(w), scfg,
                           seed=seed, chaos=client)
     if ov.share:
@@ -387,7 +389,7 @@ class _Tracer:
             jax.profiler.start_trace(self.dir, profiler_options=opts)
             self.span = jax.profiler.TraceAnnotation("bench.traced")
             self.span.__enter__()
-            self.client.ledger = self.client._empty_ledger()
+            self.client.ledger = collections.Counter()
             self.client.account = True
             self.t0, self.state = now, "on"
         elif self.state == "on" and now >= self.t0 + self.length:
@@ -414,6 +416,6 @@ class _Tracer:
             with gzip.open(keep, "wt") as f:
                 json.dump(t, f)
         kind = jax.devices()[0].device_kind
-        return {"trace": tr.Reduced(t), "ledger": dict(self.client.ledger),
+        return {"trace": tr.Reduced(t), "ledger": self.client.ledger,
                 "peaks": spec.peaks(kind) if jax.devices()[0].platform
                 == "tpu" else None}

@@ -3,10 +3,12 @@
 ``BENCHMARK.json`` names each cell's configuration and traffic mix; the
 configuration is ``bench/configs/<config>.json``, the mix
 ``bench/traffic/<traffic>.json`` and each per-layer metric
-``bench/metrics/<metric>.py``.  A configuration names its plain
-reference, ``bench/reference/<module>.py``, and the module that hands
-that reference's weights to the program,
-``bench/harness/families/<module>.py``, under ``reference.module``.
+``bench/metrics/<metric>.py``.  A configuration names its family under
+``reference.module``: its plain reference,
+``bench/reference/<module>.py``, and the module that owns what depends
+on the model's structure (the program's arch and its check, the weights
+handed to the program, the cut for the CPU tests and the needed work),
+``bench/harness/families/<module>.py``.
 Adding a file of any of these kinds and an entry for it in
 ``BENCHMARK.json`` needs no edit to any other file.
 """
@@ -66,8 +68,11 @@ def reference_module(cfg: dict):
 
 
 def family_module(cfg: dict):
-    """What hands the reference's weights to the program:
-    ``arch_config(cfg, m)`` and ``to_program(w)``."""
+    """Everything in the harness that depends on the model's structure:
+    ``arch_config(cfg)``, ``check(arch, m)``, ``to_program(w)``,
+    ``small_cut(cfg)``, ``prefill_work(m, start, end, commit)`` and
+    ``decode_work(m, pos)`` (``families/dense_gqa.py`` says what each
+    does)."""
     return _module("harness/families", cfg["reference"]["module"])
 
 

@@ -3,16 +3,22 @@
 ``load_xplane`` keeps what the reduction needs from the ``.xplane.pb``
 that ``jax.profiler`` writes: for every device plane the operations
 (line ``XLA Ops``) and the programs (line ``XLA Modules``), each as
-``[name, start_ns, duration_ns]``, and the harness's own host spans
-(``bench.*``).  :class:`Reduced` works on that plain form, which the
-tests also feed with a small recorded trace.
+``[name, start_ns, duration_ns]``, the harness's own host spans
+(``bench.*``, same form) and the program's (``serve.*``, under
+``program_spans``, each with its profiler arguments as a fourth item,
+``{arg: value}``).  :class:`Reduced` works on that plain form, which the
+tests also feed with small recorded traces (the older ones hold no
+``program_spans``).
 
 * busy time: the union of the operation intervals inside the traced
   stretch (the host span ``bench.traced``), averaged over the devices;
 * kernel or program time: the summed durations of the events whose name
   matches a pattern (each metric file holds its own pattern);
+* program spans: the program's spans that lie inside the stretch, with
+  their arguments, for the readers of the program's own counts;
 * idle gaps: the stretches of the traced window in which no operation
-  ran, each labelled by the innermost harness span open at its middle.
+  ran, each labelled by the innermost span, the harness's or the
+  program's, open at its middle.
 """
 from __future__ import annotations
 
@@ -27,6 +33,7 @@ DEVICE_PREFIX = "/device:"
 CONTAINERS = ("while", "conditional", "call")
 OPS, MODULES = "XLA Ops", "XLA Modules"
 WINDOW_SPAN = "bench.traced"
+HOST_PREFIX, PROGRAM_PREFIX = "bench.", "serve."
 
 
 def load_xplane(directory: str) -> dict:
@@ -36,7 +43,7 @@ def load_xplane(directory: str) -> dict:
     if not files:
         raise FileNotFoundError(f"no .xplane.pb under {directory}")
     data = ProfileData.from_file(sorted(files)[-1])
-    out = {"devices": [], "host_spans": []}
+    out = {"devices": [], "host_spans": [], "program_spans": []}
     for plane in data.planes:
         if plane.name.startswith(DEVICE_PREFIX):
             dev = {"name": plane.name, "ops": [], "modules": []}
@@ -50,9 +57,11 @@ def load_xplane(directory: str) -> dict:
         else:
             for line in plane.lines:
                 for e in line.events:
-                    if e.name.startswith("bench."):
-                        out["host_spans"].append(
-                            [e.name, int(e.start_ns), int(e.duration_ns)])
+                    span = [e.name, int(e.start_ns), int(e.duration_ns)]
+                    if e.name.startswith(HOST_PREFIX):
+                        out["host_spans"].append(span)
+                    elif e.name.startswith(PROGRAM_PREFIX):
+                        out["program_spans"].append(span + [dict(e.stats)])
     return out
 
 
@@ -116,22 +125,32 @@ class Reduced:
         """Device seconds of the programs whose name matches."""
         return self._sum("modules", pattern)
 
+    def program_spans(self, name: str) -> list:
+        """The program's spans called ``name`` that lie inside the traced
+        stretch, as ``[name, start_ns, duration_ns, {arg: value}]``; none
+        for a trace that kept no program spans."""
+        return [s for s in self.t.get("program_spans", [])
+                if s[0] == name and self.lo <= s[1]
+                and s[1] + s[2] <= self.hi]
+
     def idle_gaps(self) -> list[tuple[str, float]]:
         """Every idle stretch of the first device, longest first, labelled
-        by the innermost harness span open at its middle."""
+        by the innermost span (the harness's or the program's) open at its
+        middle."""
         busy = self._busy[0] if self._busy else []
         gaps, at = [], self.lo
         for s, e in busy + [(self.hi, self.hi)]:
             if s > at:
                 gaps.append((at, s))
             at = max(at, e)
-        spans = [(n, s, s + d) for n, s, d in self.t["host_spans"]
+        spans = [(n, s, s + d) for n, s, d, *_ in
+                 self.t["host_spans"] + self.t.get("program_spans", [])
                  if n != WINDOW_SPAN]
         out = []
         for a, b in gaps:
             mid = (a + b) // 2
             inner = [(s, n) for n, s, e in spans if s <= mid < e]
-            label = max(inner)[1] if inner else "outside any bench span"
+            label = max(inner)[1] if inner else "outside any span"
             out.append((label, (b - a) / 1e9))
         return sorted(out, key=lambda g: -g[1])
 

@@ -1,11 +1,10 @@
 """A cell of the benchmark cut to a size the CPU runs in seconds: the
-configuration's own family at ``reduced()`` widths, a few slots, short
+configuration cut by its own family (``small_cut``), a few slots, short
 prompts and outputs, and the device check skipped.  For the tests only;
 the command line never runs a cell this way."""
 import dataclasses
 
 from bench.harness import runner, spec
-from repro.configs import get_config
 
 CPU = {"platform": "cpu", "kind": "cpu", "count": 1}
 # the widest gap the test size allows: sound runs read under 1e-3 there,
@@ -18,14 +17,7 @@ def cell(name: str, *, sample: int = 4):
     """The cell as BENCHMARK.json lists it, cut to the test size, and the
     overrides that run it there."""
     c = spec.cell(name)
-    cfg = dict(c.config)
-    arch = get_config(cfg["program"]["arch"]).reduced()
-    arch = dataclasses.replace(arch, **cfg["program"].get("arch_overrides",
-                                                          {}))
-    cfg.update(hidden_size=arch.d_model, num_attention_heads=arch.n_heads,
-               num_key_value_heads=arch.kv_heads, head_dim=arch.head_dim,
-               intermediate_size=arch.d_ff, vocab_size=arch.vocab,
-               num_hidden_layers=arch.n_layers)
+    cfg, arch = spec.family_module(c.config).small_cut(c.config)
     cfg["correct"] = dict(cfg["correct"], sample_requests=sample,
                           max_logit_gap=TEST_LIMIT)
     mix = dict(c.traffic, ramp_s=1.0, ramp_rounds=3, strata=8,
@@ -42,8 +34,8 @@ def cell(name: str, *, sample: int = 4):
                    prompt_tokens={"dist": "lognormal", "median": 40,
                                   "sigma": 0.6, "min": 8, "max": 100},
                    output_tokens={"dist": "fixed", "value": 12}, max_new=12)
-    # the sizes the reference runs come from the configuration's own
-    # reference module, ``dims`` of the cut configuration
+    # the runner checks the cut arch against ``dims`` of the cut
+    # configuration, as it checks the file's at full size
     ov = runner.Overrides(device=CPU, arch=arch,
                           serve={"batch": 4, "max_len": 256,
                                  "prefill_chunk": 32})

@@ -1,4 +1,6 @@
 """The whole run of a cell, on the CPU at a small size (closed backlog)."""
+import gzip
+import json
 import time
 
 from bench.harness import runner
@@ -20,3 +22,25 @@ def test_closed_backlog_run_end_to_end():
                                           "backend_compiles": 0}
     gap = res["compared"]["max_logit_gap"]
     assert gap["value"] <= gap["limit"] == small.TEST_LIMIT
+
+
+def test_traced_run_keeps_the_program_spans(tmp_path):
+    c, ov = small.cell(CELL)
+    ov.keep_trace = str(tmp_path / "trace.json.gz")
+    res = runner.run(CELL, 2**31 + 102, 3, True, time.perf_counter(),
+                     cell=c, ov=ov)
+    assert res["correct"] is True
+    with gzip.open(ov.keep_trace, "rt") as f:
+        spans = json.load(f)["program_spans"]
+    args = {}
+    for name, _, dur, a in spans:
+        assert name.startswith("serve.") and dur >= 0
+        args.setdefault(name, []).append(a)
+    assert {"serve.round", "serve.join", "serve.pages"} <= set(args)
+    assert all(set(a) == {"rows_computed", "width", "tokens"}
+               for a in args["serve.join"])
+    assert all(set(a) == {"live_tokens", "mapped_tokens"}
+               for a in args["serve.pages"] if a)
+    # the readers of the program's spans need no device
+    for name in ("join_token_use_share", "kv_page_use_share"):
+        assert 0 < res["metrics"][name]["value"] <= 100

@@ -80,6 +80,43 @@ def test_metric_files_read_the_hand_trace():
     assert read("queue_wait_p95_s") == pytest.approx(2.9)
 
 
+def test_program_spans_inside_the_stretch_label_idle_gaps():
+    t = hand_trace()
+    t["program_spans"] = [
+        # a round that opened before the stretch: out of the list, but
+        # still a label where nothing inner is open
+        ["serve.round", -5 * MS, 50 * MS, {}],
+        ["serve.join", 2 * MS, 38 * MS,
+         {"rows_computed": 4, "width": 64, "tokens": 100}],
+        ["serve.pages", 45 * MS, 10 * MS,
+         {"live_tokens": 300, "mapped_tokens": 512}],
+        ["serve.pages", 60 * MS, 2 * MS,
+         {"live_tokens": 100, "mapped_tokens": 128}],
+        # runs past the stretch's end
+        ["serve.join", 96 * MS, 10 * MS,
+         {"rows_computed": 8, "width": 8, "tokens": 8}]]
+    r = Reduced(t)
+    assert r.program_spans("serve.join") == [t["program_spans"][1]]
+    assert r.program_spans("serve.pages") == t["program_spans"][2:4]
+    assert r.program_spans("serve.round") == []
+    # 0-10 mid 5: serve.join (from 2 ms) inside bench.refill (from 0);
+    # 40-60 mid 50: serve.pages inside bench.collect; 70-95: bench.client
+    assert r.idle_gaps() == [("bench.client", pytest.approx(0.025)),
+                             ("serve.pages", pytest.approx(0.020)),
+                             ("serve.join", pytest.approx(0.010))]
+    # the numbers that do not read spans stay as they were
+    plain = Reduced(hand_trace())
+    assert (r.busy_s, r.window_s) == (plain.busy_s, plain.window_s)
+    rec = {"ledger": {}, "peaks": spec.peaks("TPU v5 lite"),
+           "queue_waits": []}
+    read = lambda n: spec.metric_module(n).read(rec, r)
+    assert read("join_token_use_share") == pytest.approx(100 * 100 / 256)
+    assert read("kv_page_use_share") == pytest.approx(100 * 400 / 640)
+    # a trace that kept no program spans gives none, and no reading
+    assert plain.program_spans("serve.join") == []
+    assert spec.metric_module("kv_page_use_share").read(rec, plain) is None
+
+
 def test_readers_find_nothing_in_an_empty_trace():
     r = Reduced({"devices": [], "host_spans": [["bench.traced", 0, MS]]})
     led = dict.fromkeys(("prefill_flops", "decode_flops",
@@ -91,7 +128,8 @@ def test_readers_find_nothing_in_an_empty_trace():
         assert spec.metric_module(m["name"]).read(rec, r) is None
 
 
-RECORDED = ["qwen2_offline_trace.json.gz", "starcoder2_code_trace.json.gz"]
+RECORDED = ["qwen2_offline_trace.json.gz", "starcoder2_code_trace.json.gz",
+            "qwen2_offline_spans_trace.json.gz"]
 
 
 @pytest.mark.parametrize("name", RECORDED)

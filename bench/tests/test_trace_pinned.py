@@ -1,7 +1,9 @@
 """The numbers the accepted per-layer metrics read on the committed chip
-traces, one per cell, pinned to the bit: a change to the trace reduction
+traces, pinned to the bit: a change to the trace reduction
 (``bench/harness/trace.py``) that keeps more of the trace must leave
-them exactly as they are."""
+them exactly as they are.  One trace per cell, and a qwen2 stretch that
+also holds the program's ``serve.*`` spans, which the span readers
+read."""
 import gzip
 import json
 import os
@@ -26,9 +28,20 @@ PINNED_CODE = {"prefill_mfu": 0.7092067028797032,
                "decode_mfu": 0.8845576384206261,
                "paged_decode_attn_roofline": 0.9606047780695242,
                "device_idle_share": 1.212555245896385}
+# one round (a join of one group, its pages, its decode segment) with
+# 1 ms on either side, cut from a traced run of
+# qwen2-0.5b.offline_long_output that kept the program's spans
+PINNED_SPANS = {"decode_mfu": 0.9598788824058277,
+                "paged_decode_attn_roofline": 1.2113643251729778,
+                "device_idle_share": 2.228942544841228,
+                "serve_mfu": 1.2214019373607348,
+                "join_token_use_share": 25.5859375,
+                "kv_page_use_share": 22.372293462266136}
 RECORDED = {
     "qwen2_offline_trace.json.gz": (PINNED, (5.664666956, 5.671999049)),
     "starcoder2_code_trace.json.gz": (PINNED_CODE, (1.86347233, 1.886345309)),
+    "qwen2_offline_spans_trace.json.gz": (PINNED_SPANS,
+                                          (1.219008507, 1.246798939)),
 }
 
 
@@ -42,3 +55,15 @@ def test_accepted_metrics_read_the_pinned_values(name):
     got = {n: spec.metric_module(n).read(rec, r) for n in pinned}
     assert got == pinned
     assert (r.busy_s, r.window_s) == busy_window
+
+
+def test_span_readers_read_the_spans_arguments():
+    with gzip.open(os.path.join(DATA, "qwen2_offline_spans_trace.json.gz"),
+                   "rt") as f:
+        r = Reduced(json.load(f))
+    (join,) = r.program_spans("serve.join")
+    (pages,) = r.program_spans("serve.pages")
+    assert join[3] == {"rows_computed": 4, "width": 256, "tokens": 262}
+    assert pages[3] == {"live_tokens": 34056, "mapped_tokens": 152224}
+    assert 100 * 262 / (4 * 256) == PINNED_SPANS["join_token_use_share"]
+    assert 100 * 34056 / 152224 == PINNED_SPANS["kv_page_use_share"]

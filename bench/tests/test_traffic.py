@@ -2,8 +2,9 @@
 import collections
 import math
 
-from bench.harness import spec
+from bench.harness import spec, work
 from bench.harness.client import Client
+from bench.harness.families import dense_gqa
 from bench.harness.traffic import RequestStream, length_range, quantile
 
 BIG_SEED = 2**31 + 12345
@@ -142,7 +143,8 @@ def test_closed_backlog_and_client_stop():
     mix = spec.traffic("offline_long_output")
     dims = {"layers": 1, "d": 8, "heads": 2, "kv_heads": 1, "head_dim": 4,
             "ffn": 16, "vocab": 1000, "gated": True}
-    c = Client(stream("offline_long_output"), mix, batch=4, dims=dims)
+    c = Client(stream("offline_long_output"), mix, batch=4, dims=dims,
+               family=dense_gqa)
     b = FakeBatcher(4, per_step=200)
     c.start(0.0)
     c.pump(b)
@@ -151,8 +153,21 @@ def test_closed_backlog_and_client_stop():
     c.pump(b)
     assert len(b.queue) == 4          # refilled behind the admitted four
     b.step()
+    seen = {rid: rec.seen for rid, rec in c.recs.items()}
+    filled = {rid: rec.filled for rid, rec in c.recs.items()}
     c.account = True
     c.pump(b)
+    # the needed work of the tokens seen while accounting: output token
+    # i >= 1 comes from the decode step at position prompt_len + i - 1
+    pos = [rec.prompt_len + i - 1 for rid, rec in c.recs.items()
+           for i in range(max(seen.get(rid, 0), 1), rec.seen)]
+    assert c.ledger["decode_tokens"] == len(pos)
+    assert c.ledger["decode_flops"] == sum(work.decode_flops(dims, p)
+                                           for p in pos)
+    # and of the prompts that became resident (each in one piece)
+    assert c.ledger["prefill_flops"] == sum(
+        work.prefill_flops(dims, filled.get(rid, 0), rec.filled, True)
+        for rid, rec in c.recs.items() if rec.filled > filled.get(rid, 0))
     # every request got at least 32 tokens: each one stopped at its own
     # drawn length, seen tokens capped there, and cancelled by the client
     for rid, rec in c.recs.items():
@@ -164,3 +179,36 @@ def test_closed_backlog_and_client_stop():
     assert stopped == {rid for rid, rec in c.recs.items()
                        if rec.done_t is not None}
     assert all(c.recs[rid].seen == c.recs[rid].out_len for rid in stopped)
+
+
+class Pieces:
+    """A family whose needed work is counted in keys of its own."""
+
+    @staticmethod
+    def prefill_work(m, start, end, commit):
+        return {"piece_tokens": end - start, "commits": int(commit)}
+
+    @staticmethod
+    def decode_work(m, pos):
+        return {"expert_rows": m["top_k"], "decode_flops": pos}
+
+
+def test_client_adds_the_family_work_by_name():
+    mix = spec.traffic("offline_long_output")
+    c = Client(stream("offline_long_output"), mix, batch=2,
+               dims={"top_k": 6}, family=Pieces)
+    b = FakeBatcher(2, per_step=3)
+    c.start(0.0)
+    c.pump(b)
+    c.account = True
+    b.step()              # admits two, prompts resident, 3 tokens each
+    c.pump(b)
+    recs = [c.recs[r] for r in b.slot_rid]
+    # tokens 1 and 2 of each request come from decode steps at positions
+    # prompt_len and prompt_len + 1; token 0 from the join
+    assert dict(c.ledger) == {
+        "piece_tokens": sum(r.prompt_len for r in recs), "commits": 2,
+        "prefill_tokens": sum(r.prompt_len for r in recs),
+        "expert_rows": 6 * 4, "decode_tokens": 4,
+        "decode_flops": sum(2 * r.prompt_len + 1 for r in recs)}
+    assert c.ledger["decode_attn_bytes"] == 0     # a name it never gave

@@ -1,8 +1,10 @@
 """Needed operations and bytes against hand counts at published widths."""
+import collections
 import json
 import os
 
 from bench.harness import work
+from bench.harness.families import dense_gqa as family
 from bench.reference import dense_gqa
 
 CONFIGS = os.path.join(os.path.dirname(os.path.dirname(__file__)), "configs")
@@ -65,3 +67,29 @@ def test_decode_tokens_equal_a_prefill_of_the_same_positions():
     dec = sum(work.decode_flops(m, p) for p in range(40, 48))
     pre = work.prefill_flops(m, 40, 48, False) + 8 * work.head_flops(m)
     assert dec == pre
+
+
+def test_dense_family_ledger_equals_the_formulas():
+    # the pieces and tokens of two requests: one prompt of 1300 tokens in
+    # three chunks, one of 40 in one, then a few decode steps of each
+    m = dims("starcoder2-3b")
+    pieces = [(0, 512, False), (0, 40, True), (512, 1024, False),
+              (1024, 1300, True)]
+    tokens = [40, 1300, 41, 1301, 42]
+    led = collections.Counter()
+    for start, end, commit in pieces:
+        led.update(family.prefill_work(m, start, end, commit))
+    for pos in tokens:
+        led.update(family.decode_work(m, pos))
+    attn_pre = [work.prefill_attn_work(m, s, e) for s, e, _ in pieces]
+    attn_dec = [work.decode_attn_work(m, p) for p in tokens]
+    assert dict(led) == {
+        "prefill_flops": sum(work.prefill_flops(m, *p) for p in pieces),
+        "prefill_attn_flops": sum(f for f, _ in attn_pre),
+        "prefill_attn_bytes": sum(b for _, b in attn_pre),
+        "decode_flops": sum(work.decode_flops(m, p) for p in tokens),
+        "decode_attn_flops": sum(f for f, _ in attn_dec),
+        "decode_attn_bytes": sum(b for _, b in attn_dec)}
+    # and the whole prompts, as one piece each, need the same
+    assert led["prefill_flops"] == work.prefill_flops(m, 0, 1300, True) \
+        + work.prefill_flops(m, 0, 40, True)
