@@ -323,6 +323,10 @@ def check_tuned(tuned_path: str,
         if br is not None and (not isinstance(br, int)
                                or isinstance(br, bool) or br <= 0):
             failures.append(f"{key}: bad block_rows {br!r}")
+        ppb = cfg.get("pages_per_block")
+        if ppb is not None and (not isinstance(ppb, int)
+                                or isinstance(ppb, bool) or ppb <= 0):
+            failures.append(f"{key}: bad pages_per_block {ppb!r}")
     if failures:
         print(f"[check_bench] tuned-cache gate {tuned_path}: "
               f"{len(failures)} failure(s):")

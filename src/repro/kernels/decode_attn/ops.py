@@ -85,7 +85,8 @@ class DecodeAttnPolicy:
         None on a cache miss.  ``block_rows`` is sanitized against the
         caller's fused row count ``lg`` (entries are keyed without Lq,
         so a tuned row tiling is dropped when it does not divide this
-        call's rows); a malformed entry degrades field-by-field."""
+        call's rows), ``pages_per_block`` to a positive int; a malformed
+        entry degrades field-by-field."""
         ent = self._tuned.get(f"{jax.default_backend()}|{op}|"
                               f"hq{hq}.hkv{hkv}.d{d}.ps{page_size}")
         if not isinstance(ent, dict):
@@ -97,6 +98,9 @@ class DecodeAttnPolicy:
         if br is not None and (not isinstance(br, int) or br <= 0
                                or lg is None or lg % br):
             cfg.pop("block_rows", None)
+        ppb = cfg.get("pages_per_block")
+        if ppb is not None and (type(ppb) is not int or ppb <= 0):
+            cfg.pop("pages_per_block", None)
         return cfg or None
 
     def kernel_wanted(self) -> bool:

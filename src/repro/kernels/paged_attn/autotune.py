@@ -1,15 +1,18 @@
 """Autotuning harness for the paged-attention kernel family.
 
 The decode / prefill / verify kernels carry tunable launch geometry
-(``grid_order`` on both, ``block_rows`` on the prefill/verify row fold —
-see :mod:`kernel` and :mod:`prefill_kernel`) that until now ran on
+(``grid_order`` on both, ``pages_per_block`` on the decode block walk,
+``block_rows`` on the prefill/verify row fold — see :mod:`kernel` and
+:mod:`prefill_kernel`) that until now ran on
 hand-picked defaults validated only under CPU interpret.  This module
 mechanizes the PrIM-style sweep the paper argues every primitive needs
 before "fast as the hardware allows" claims mean anything:
 
 1. **Enumerate** candidate configs per (backend, op, geometry):
-   ``grid_order`` in ``("bh", "hb")`` for every op, plus every divisor of
-   the fused ``Lq * G`` row count as ``block_rows`` for prefill/verify.
+   ``grid_order`` in ``("bh", "hb")`` for every op, plus every power of
+   two up to the table width as ``pages_per_block`` for decode and every
+   divisor of the fused ``Lq * G`` row count as ``block_rows`` for
+   prefill/verify.
    Page size is a *geometry* axis, not a candidate axis — it changes the
    pool layout, so the CLI sweeps it as separate geometries.
 2. **Prune** with an analytic score that reuses PR 8's
@@ -17,7 +20,8 @@ before "fast as the hardware allows" claims mean anything:
    per-candidate physical HBM traffic (row blocks re-walk the page list,
    the causal top-skip refunds pages above each block), a sublane-
    occupancy derate on compute, and a per-grid-step dispatch charge.
-   Infeasible tilings (non-divisor ``block_rows``, VMEM overflow) never
+   Infeasible tilings (non-divisor ``block_rows``, a page block on
+   prefill, VMEM overflow) never
    run; the feasible set is ranked and cut to ``budget``.
 3. **Benchmark** survivors through the existing kernel-timing hooks
    (:func:`repro.kernels.paged_attn.ops.attn_telemetry`): one untimed
@@ -60,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.hwspec import DEFAULT_TPU, TpuSpec
-from .kernel import GRID_ORDERS
+from .kernel import GRID_ORDERS, default_pages_per_block
 from .prefill_kernel import default_block_rows, vmem_bytes
 
 SCHEMA = 1
@@ -100,18 +104,25 @@ class Geometry:
 class Candidate:
     """One launch configuration.  ``block_rows=None`` means the kernel's
     default row fold (:func:`prefill_kernel.default_block_rows`; the
-    only valid value for decode)."""
+    only valid value for decode); ``pages_per_block=None`` the decode
+    kernel's shape default (:func:`kernel.default_pages_per_block`; the
+    only valid value for prefill/verify)."""
     grid_order: str = "bh"
     block_rows: int | None = None
+    pages_per_block: int | None = None
 
     def as_dict(self) -> dict:
         cfg = {"grid_order": self.grid_order}
         if self.block_rows is not None:
             cfg["block_rows"] = self.block_rows
+        if self.pages_per_block is not None:
+            cfg["pages_per_block"] = self.pages_per_block
         return cfg
 
     def label(self) -> str:
         br = "default" if self.block_rows is None else str(self.block_rows)
+        if self.pages_per_block is not None:
+            return f"{self.grid_order}/br={br}/ppb={self.pages_per_block}"
         return f"{self.grid_order}/br={br}"
 
 
@@ -170,12 +181,19 @@ def make_workload(op: str, geom: Geometry, *, b: int = 2, lq: int = 8,
     return Workload(op, geom, q, kp, vp, tbl, off + lq, off, lq)
 
 
-def enumerate_candidates(op: str, lg: int | None = None) -> list[Candidate]:
+def enumerate_candidates(op: str, lg: int | None = None,
+                         pages: int | None = None) -> list[Candidate]:
     """Every launch config the kernels accept for ``op``: both grid
-    orders, and (prefill/verify) every divisor of the fused row count as
-    ``block_rows``.  The default ``Candidate()`` is always first — the
-    pruner keeps it and the benchmark parity-gates against it."""
-    if op == "decode" or lg is None:
+    orders, (decode) every power of two up to the ``pages`` of the
+    sliced table as ``pages_per_block``, and (prefill/verify) every
+    divisor of the fused row count as ``block_rows``.  The default
+    ``Candidate()`` is always first — the pruner keeps it and the
+    benchmark parity-gates against it."""
+    if op == "decode":
+        blocks = [1 << i for i in range(pages.bit_length())] if pages else []
+        return [Candidate(o, pages_per_block=p)
+                for p in [None] + blocks for o in GRID_ORDERS]
+    if lg is None:
         return [Candidate(o) for o in GRID_ORDERS]
     divisors = [r for r in range(1, lg) if lg % r == 0]
     out = []
@@ -202,6 +220,12 @@ def feasible(cand: Candidate, *, op: str, lg: int, geom: Geometry,
     working set overflows the kernel's VMEM limit."""
     if cand.grid_order not in GRID_ORDERS:
         return False, f"unknown grid_order {cand.grid_order!r}"
+    if cand.pages_per_block is not None:
+        if op != "decode":
+            return False, f"{op} walks one page per step, not a page block"
+        if cand.pages_per_block <= 0:
+            return False, (f"pages_per_block={cand.pages_per_block} is "
+                           f"not positive")
     if cand.block_rows is None:
         rows = default_block_rows(lg, d=geom.d, ps=geom.page_size,
                                   q_itemsize=4, kv_itemsize=4,
@@ -283,9 +307,12 @@ def score(cand: Candidate, wl: Workload,
     mem_t = mem / spec.hbm_gbps
     comp_t = flops / (spec.peak_flops_per_ns * sublane_eff)
     b, p_max = int(wl.table.shape[0]), int(wl.table.shape[1])
-    steps = b * wl.geom.hkv * p_max
-    if wl.op != "decode":
-        steps *= -(-wl.lg // rows)
+    if wl.op == "decode":
+        ppb = cand.pages_per_block or default_pages_per_block(
+            wl.geom.page_size, p_max)
+        steps = b * wl.geom.hkv * -(-p_max // min(ppb, p_max))
+    else:
+        steps = b * wl.geom.hkv * p_max * -(-wl.lg // rows)
     return max(mem_t, comp_t) + steps * DISPATCH_NS
 
 
@@ -298,7 +325,8 @@ def prune(wl: Workload, candidates: list[Candidate] | None = None, *,
     always first.  ``pruned`` pairs each rejected candidate with its
     reason."""
     if candidates is None:
-        candidates = enumerate_candidates(wl.op, wl.lg)
+        candidates = enumerate_candidates(wl.op, wl.lg,
+                                          int(wl.table.shape[1]))
     kept, pruned = [], []
     for c in candidates:
         ok, why = feasible(c, op=wl.op, lg=wl.lg, geom=wl.geom, spec=spec)
@@ -343,7 +371,8 @@ def benchmark(wl: Workload, candidates: list[Candidate], *, reps: int = 3,
         if wl.op == "decode":
             return _ops.paged_attn(wl.q, wl.k_pages, wl.v_pages, wl.table,
                                    wl.lengths, interpret=interpret,
-                                   grid_order=c.grid_order)
+                                   grid_order=c.grid_order,
+                                   pages_per_block=c.pages_per_block)
         fn = (_ops.paged_verify_attn if wl.op == "verify"
               else _ops.paged_prefill_attn)
         return fn(wl.q, wl.k_pages, wl.v_pages, wl.table, wl.q_offset,

@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import paged_attn_kernel
+from .kernel import default_pages_per_block, paged_attn_kernel
 from .prefill_kernel import paged_prefill_attn_kernel
 from .ref import gather_pages
 
@@ -280,12 +280,38 @@ def _tuned_launch(op: str, q, k_pages, *, lg: int) -> dict:
         d=int(q.shape[-1]), page_size=int(k_pages.shape[2]), lg=lg) or {}
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "grid_order"))
+def decode_launch(*, hq: int, hkv: int, d: int, page_size: int, p_cap: int,
+                  grid_order: str | None = None,
+                  pages_per_block: int | None = None) -> tuple[str, int]:
+    """The decode kernel's ``(grid_order, pages_per_block)`` for a call
+    of this shape under a table sliced to ``p_cap`` pages: an explicit
+    value wins, then the active policy's tuned entry (:mod:`autotune`),
+    then the defaults (``"bh"``, :func:`kernel.default_pages_per_block`).
+    The block never exceeds the table, so each page-cap bucket compiles
+    one program.  Shapes are static even under trace, so this resolves
+    at trace time — and on the host, where the scheduler counts the
+    pages the kernel walks."""
+    if grid_order is None or pages_per_block is None:
+        from ..decode_attn import active_policy
+        cfg = active_policy().tuned_config(
+            "decode", hq=hq, hkv=hkv, d=d, page_size=page_size,
+            lg=hq // hkv) or {}
+        if grid_order is None:
+            grid_order = cfg.get("grid_order", "bh")
+        if pages_per_block is None:
+            pages_per_block = (cfg.get("pages_per_block")
+                               or default_pages_per_block(page_size, p_cap))
+    return grid_order, min(pages_per_block, p_cap)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "grid_order",
+                                             "pages_per_block"))
 def _paged_attn_jit(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, table: jnp.ndarray,
                     lengths: jnp.ndarray, *,
                     interpret: bool = True,
-                    grid_order: str = "bh") -> jnp.ndarray:
+                    grid_order: str = "bh",
+                    pages_per_block: int | None = None) -> jnp.ndarray:
     b, hq, d = q.shape
     hkv = k_pages.shape[1]
     g = hq // hkv
@@ -293,31 +319,31 @@ def _paged_attn_jit(q: jnp.ndarray, k_pages: jnp.ndarray,
     tbl = _clamp_table(table, k_pages.shape[0])
     ln = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
     out = paged_attn_kernel(qg, k_pages, v_pages, tbl, ln,
-                            interpret=interpret, grid_order=grid_order)
+                            interpret=interpret, grid_order=grid_order,
+                            pages_per_block=pages_per_block)
     return out.reshape(b, hq, d)
 
 
 def paged_attn(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
                table: jnp.ndarray, lengths: jnp.ndarray, *,
                interpret: bool = True,
-               grid_order: str | None = None) -> jnp.ndarray:
+               grid_order: str | None = None,
+               pages_per_block: int | None = None) -> jnp.ndarray:
     """q: [B, Hq, D] one-token queries; k_pages/v_pages: [N, Hkv, ps, D]
     pooled pages; table: [B, P] int32; slot b attends over the first
-    ``lengths[b]`` tokens of its pages in table order.  ``grid_order``
-    None resolves through the active policy's tuned-shape cache
-    (:mod:`autotune`), falling back to the ``"bh"`` default."""
-    if grid_order is None:
-        grid_order = _tuned_launch(
-            "decode", q, k_pages,
-            lg=int(q.shape[-2]) // int(k_pages.shape[1])
-        ).get("grid_order", "bh")
+    ``lengths[b]`` tokens of its pages in table order.  ``grid_order`` /
+    ``pages_per_block`` left None resolve by :func:`decode_launch`."""
+    grid_order, pages_per_block = decode_launch(
+        hq=int(q.shape[-2]), hkv=int(k_pages.shape[1]), d=int(q.shape[-1]),
+        page_size=int(k_pages.shape[2]), p_cap=int(table.shape[-1]),
+        grid_order=grid_order, pages_per_block=pages_per_block)
+    kw = dict(interpret=interpret, grid_order=grid_order,
+              pages_per_block=pages_per_block)
     if not _TELEMETRY.enabled:
-        return _paged_attn_jit(q, k_pages, v_pages, table, lengths,
-                               interpret=interpret, grid_order=grid_order)
+        return _paged_attn_jit(q, k_pages, v_pages, table, lengths, **kw)
     return _recorded("decode", "kernel", q, _paged_attn_jit,
                      q, k_pages, v_pages, table, lengths,
-                     traffic=_traffic(q, k_pages, table, lengths),
-                     interpret=interpret, grid_order=grid_order)
+                     traffic=_traffic(q, k_pages, table, lengths), **kw)
 
 
 def paged_attn_xla(q: jnp.ndarray, k_pages: jnp.ndarray,

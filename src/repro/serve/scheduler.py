@@ -215,6 +215,7 @@ from .prefixcache import PrefixCache
 # _pct moved to telemetry (the registry owns percentile math) but stays
 # importable from here — it has always been this module's public helper
 from .telemetry import MetricsRegistry, Tracer, _pct, phase  # noqa: F401
+from ..kernels.paged_attn.ops import decode_launch
 from ..models.model_zoo import Model
 
 
@@ -1509,17 +1510,19 @@ class ContinuousBatcher:
             # queue
             self._ensure_decode_pages(steps)
             rows = self._decoding_rows()
+            walk = {}
             if rows:
                 live, mapped, _ = self._sample_kv()
                 span.set_metadata(live_tokens=live, mapped_tokens=mapped)
                 if self.pool is not None:
                     cap = self._page_cap()
+                    walk = self._decode_pages(cap)
                     pages = jnp.asarray(self.pool.table[:, :cap])
                 else:
                     cap = self._kv_cap(steps)
         if not rows:
             return True
-        with phase("decode-segment", tr, self.round):
+        with phase("decode-segment", tr, self.round, **walk):
             loop = self._loop(steps, cap)
             if self._spec_live():
                 hist = jnp.asarray(self.history)
@@ -1550,6 +1553,26 @@ class ContinuousBatcher:
         """Slots holding a request past its prefill."""
         return sum(1 for i, r in enumerate(self.slot_rid)
                    if r is not None and not self.slot_pending[i])
+
+    def _decode_pages(self, cap: int) -> dict:
+        """The pages the paged decode kernel reads for the decoding slots
+        at this segment's first step (``pages_live``: ceil(len / ps) per
+        slot) and the pages its block walk spans (``pages_walked``: each
+        slot's count rounded up to whole blocks of the kernel's
+        ``pages_per_block`` under a table sliced to ``cap`` pages)."""
+        arch = self.model.cfg
+        ps = self.pool.page_size
+        _, ppb = decode_launch(hq=arch.n_heads, hkv=arch.kv_heads,
+                               d=arch.resolved_head_dim, page_size=ps,
+                               p_cap=cap)
+        lens = np.array([self.slot_len[i]
+                         for i, r in enumerate(self.slot_rid)
+                         if r is not None and not self.slot_pending[i]],
+                        np.int64)
+        live = -(-lens // ps)
+        walked = np.minimum(-(-live // ppb) * ppb, cap)
+        return {"pages_live": int(live.sum()),
+                "pages_walked": int(walked.sum())}
 
     # ------------------------------------------------------------------
     # flight recorder

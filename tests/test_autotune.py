@@ -13,6 +13,8 @@ import pytest
 from repro.core.hwspec import DEFAULT_TPU
 from repro.kernels.decode_attn import decode_attn_policy
 from repro.kernels.paged_attn import autotune as at
+from repro.kernels.paged_attn.kernel import default_pages_per_block
+from repro.kernels.paged_attn.ops import decode_launch
 from repro.kernels.paged_attn import (paged_attn, paged_prefill_attn,
                                       paged_prefill_attn_ref,
                                       paged_verify_attn)
@@ -49,6 +51,18 @@ def test_feasible_rejects_vmem_overflow():
     assert not ok and "VMEM" in why
     # sanity: the same tiling fits a real VMEM
     assert at.feasible(at.Candidate(), op="prefill", lg=16, geom=GEOM)[0]
+
+
+def test_decode_candidates_walk_page_blocks():
+    """Decode sweeps every power-of-two page block up to the table; a
+    page block is no launch option of the prefill kernel."""
+    cands = at.enumerate_candidates("decode", GEOM.g, pages=8)
+    assert cands[0] == at.Candidate()
+    assert {c.pages_per_block for c in cands} == {None, 1, 2, 4, 8}
+    assert {c.grid_order for c in cands} == {"bh", "hb"}
+    ok, why = at.feasible(at.Candidate(pages_per_block=2), op="prefill",
+                          lg=16, geom=GEOM)
+    assert not ok and "page block" in why
 
 
 def test_prune_drops_infeasible_and_keeps_default_first():
@@ -162,6 +176,25 @@ def test_policy_sanitizes_block_rows_against_lg(tmp_path, monkeypatch):
                                 lg=16) == {"grid_order": "hb"}
         assert pol.tuned_config("prefill", hq=4, hkv=2, d=8, page_size=8,
                                 lg=None) == {"grid_order": "hb"}
+
+
+@pytest.mark.parametrize("config,want", [
+    ({"grid_order": "hb"}, ("hb", None)),         # no block: shape default
+    ({"grid_order": "hb", "pages_per_block": 2}, ("hb", 2)),
+    ({"pages_per_block": 0}, ("bh", None)),       # malformed: dropped
+    ({"pages_per_block": 64}, ("bh", 8)),         # held to the table
+])
+def test_decode_launch_resolves_pages_per_block(config, want, tmp_path,
+                                                monkeypatch):
+    """The decode kernel's page block: an explicit value wins, then the
+    tuned entry's, then the shape default."""
+    _no_env(monkeypatch)
+    path = _cache_file(tmp_path, config)
+    kw = dict(hq=4, hkv=2, d=8, page_size=8, p_cap=8)
+    with decode_attn_policy(use_tuned=True, tuned_path=path):
+        got = decode_launch(**kw)
+        assert decode_launch(**kw, pages_per_block=4) == (want[0], 4)
+    assert got == (want[0], want[1] or default_pages_per_block(8, 8))
 
 
 def test_env_var_disables_and_redirects(tmp_path, monkeypatch):

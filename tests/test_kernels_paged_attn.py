@@ -59,6 +59,33 @@ def test_paged_attn_page_boundaries(ln):
     np.testing.assert_allclose(out, ref, rtol=3e-4, atol=3e-4)
 
 
+@pytest.mark.parametrize("ppb", [1, 2, 4, None])
+@pytest.mark.parametrize("d", [64, 128])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attn_block_walk(ppb, d, dtype):
+    """The block walk at every block size: slots at lengths on the block
+    edges (empty, one token, a block less one, a block, a block and one,
+    the whole table) under a table of 7 pages, which no block size above
+    one divides, over permuted pages with sentinel tails; outputs match
+    the oracle, an empty slot reads zeros, and both grid orders give
+    bit-identical outputs."""
+    from repro.kernels.paged_attn.kernel import default_pages_per_block
+    rng = np.random.default_rng(d + (ppb or 0))
+    b, hq, hkv, n, ps, p_max = 6, 8, 2, 48, 8, 7
+    edge = ps * (ppb or default_pages_per_block(ps, p_max))
+    lengths = [min(ln, p_max * ps)
+               for ln in (0, 1, edge - 1, edge, edge + 1, p_max * ps)]
+    q, k, v, tbl, ln = _mk(rng, b, hq, hkv, d, n, ps, p_max, lengths, dtype)
+    outs = [np.asarray(paged_attn(q, k, v, tbl, ln, grid_order=order,
+                                  pages_per_block=ppb), np.float32)
+            for order in ("bh", "hb")]
+    np.testing.assert_array_equal(outs[0], outs[1])
+    ref = np.asarray(paged_attn_ref(q, k, v, tbl, ln), np.float32)
+    tol = 3e-4 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(outs[0][1:], ref[1:], rtol=tol, atol=tol)
+    np.testing.assert_array_equal(outs[0][0], 0.0)
+
+
 def test_paged_attn_matches_dense_decode_attn():
     """A paged cache whose table is the identity permutation is exactly a
     dense cache: paged_attn == decode_attn == dense oracle."""

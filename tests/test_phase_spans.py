@@ -4,12 +4,14 @@ A few requests are served at a reduced size under ``jax.profiler.trace``
 and the ``serve.*`` annotations are read back from the ``.xplane.pb``
 with ``ProfileData``: every phase of a round appears, nested in its
 ``serve.round`` span and one after another; the counts the join and
-pages spans carry agree with the registry and the batcher's own records,
-and no other span carries any; a Tracer records
+pages spans carry agree with the registry and the batcher's own records;
+the decode segment's span counts the decode kernel's block walk, and no
+other span carries any; a Tracer records
 the same intervals; and attaching a Tracer neither changes the tokens
 nor adds a host-device sync.
 """
 import glob
+import json
 import os
 from collections import Counter
 
@@ -20,6 +22,8 @@ import pytest
 from jax.profiler import ProfileData
 
 from repro.configs import get_config
+from repro.kernels.decode_attn import decode_attn_policy
+from repro.kernels.paged_attn.autotune import SCHEMA, Geometry, entry_key
 from repro.models import param as pm
 from repro.models.model_zoo import Model
 from repro.serve.chaos import ChaosInjector
@@ -137,12 +141,43 @@ def test_span_counts_agree_with_the_batcher(traced):
     assert pages == [(lt, cap) for lt, cap, _ in b.kv_samples]
     assert all(0 < lt <= cap for lt, cap in pages)
     # the run took its preemption and its cancellation, and no phase
-    # but the join and the pages carries arguments
+    # but the join, the pages and the decode segment carries arguments
     assert b.metrics.value("preempt.count") > 0
     assert b.metrics.value("cancel.count") == 1 and len(results) == 6
+    assert all(set(a) == {"pages_live", "pages_walked"}
+               for a in args["decode-segment"])
     assert all(a == {} for p in ("round", "chaos", "sweep", "admit",
-                                 "decode-segment", "collect")
+                                 "collect")
                for a in args[p])
+
+
+@pytest.fixture(scope="module")
+def one_page_blocks(setup, tmp_path_factory):
+    """The decode segments' span arguments in a run whose tuned decode
+    entry walks one page per block."""
+    cfg = setup[0]
+    geom = Geometry(hq=cfg.n_heads, hkv=cfg.kv_heads,
+                    d=cfg.resolved_head_dim, page_size=SERVE["page_size"])
+    path = tmp_path_factory.mktemp("tuned") / "tuned.json"
+    path.write_text(json.dumps({"schema": SCHEMA, "entries": {
+        entry_key(jax.default_backend(), "decode", geom):
+            {"config": {"pages_per_block": 1}}}}))
+    with decode_attn_policy(use_tuned=True, tuned_path=str(path)):
+        _, _, spans = _profiled(setup, tmp_path_factory.mktemp("xplane1"))
+    return [sp[3] for sp in spans if sp[0] == "decode-segment"]
+
+
+def test_decode_segment_span_counts_the_block_walk(traced, one_page_blocks):
+    """``pages_live`` pages hold the decoding slots' tokens; the kernel's
+    block walk spans ``pages_walked`` pages, whole blocks, so never
+    fewer, and exactly as many at one page per block."""
+    _, _, spans, _ = traced
+    walks = [(sp[3]["pages_live"], sp[3]["pages_walked"]) for sp in spans
+             if sp[0] == "decode-segment"]
+    assert walks and all(0 < live <= walked for live, walked in walks)
+    assert any(live < walked for live, walked in walks)
+    assert one_page_blocks and all(
+        a["pages_live"] == a["pages_walked"] > 0 for a in one_page_blocks)
 
 
 def test_tracer_records_the_same_phases(traced):

@@ -70,6 +70,19 @@ def test_paged_decode_compiles(one_chip, grid_order):
              ((B, MAX_LEN // PS), jnp.int32), ((B,), jnp.int32))
 
 
+@pytest.mark.parametrize("b,g,d,p_cap", [
+    (128, 7, 64, 96),       # qwen2-0.5b.offline_long_output's decode step
+    (32, 12, 128, 256),     # starcoder2-3b.code_completion's
+])
+def test_paged_decode_compiles_at_cell_geometry(one_chip, b, g, d, p_cap):
+    """The block walk at the benchmark cells' decode geometries, with
+    the default pages per block."""
+    pool = ((b * p_cap, HKV, PS, d), jnp.bfloat16)
+    _compile(functools.partial(paged_attn_kernel, interpret=False),
+             one_chip, ((b, HKV, g, d), jnp.bfloat16), pool, pool,
+             ((b, p_cap), jnp.int32), ((b,), jnp.int32))
+
+
 @pytest.mark.parametrize("lq", [16, 1024])
 def test_paged_prefill_compiles(one_chip, lq):
     """Lq 1024 is past what one row block fits in VMEM: the default row
