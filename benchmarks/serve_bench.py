@@ -1,20 +1,18 @@
 """Serving throughput + KV memory accounting: seed per-token host loop vs
-device-resident engine, dense vs paged KV cache, prefix cache on vs off.
+device-resident engine, paged-pool capacity, prefix cache on vs off.
 
 The seed ``Batcher`` ran decode as a per-token Python loop — eager
 dispatch, host argmax, a fresh padded batch per round, O(n^2) queue drain.
 The engine replaces that with slot-based continuous batching over a jitted
-``lax.scan`` (repro.serve.scheduler); the paged mode additionally replaces
-the per-slot ``max_len`` KV stripes with a block pool (repro.serve.kvpool)
-so admission is on free pages and retired slots return memory.  Every row
-therefore reports KV utilization (live tokens / allocated token capacity)
-next to tokens/sec — the dense layout's stranded-stripe waste is the
-number the paged pool exists to fix.  ``--prefix-cache`` runs a
+``lax.scan`` (repro.serve.scheduler) whose KV lives in a block pool
+(repro.serve.kvpool), so admission is on free pages and retired slots
+return memory.  Every row therefore reports KV utilization (live tokens /
+allocated token capacity) next to tokens/sec.  ``--prefix-cache`` runs a
 repeated-system-prompt workload through the shared-prefix radix cache
 (repro.serve.prefixcache) and reports the token hit rate plus prefill
 tokens computed vs skipped.
 
-  PYTHONPATH=src python benchmarks/serve_bench.py [--smoke] [--paged]
+  PYTHONPATH=src python benchmarks/serve_bench.py [--smoke]
                                                   [--prefix-cache]
                                                   [--arch A]
 
@@ -59,15 +57,14 @@ the overload counters (cancellations, sheds, deadline attainment,
 degradation time-in-state — all zero/HEALTHY with the controller off).
 
 ``--smoke`` is the CI sanity mode (~5 s): engine only, asserts a nonzero
-throughput (with ``--paged``: the paged engine, plus 100% page
-reclamation; with ``--prefix-cache``: additionally a nonzero prefix hit
-rate on the shared-prompt workload; with ``--prefill-chunk``: that chunk
-continuations actually ran).  The full mode asserts the engine beats the
-seed loop >= 3x, that at equal KV memory the paged pool either admits
-more concurrent requests than dense or matches dense throughput within
-10% while reclaiming every retired slot's pages, and that the prefix
-cache cuts prefill tokens computed by exactly its hit rate without
-losing concurrency.
+throughput and 100% page reclamation (with ``--prefix-cache``:
+additionally a nonzero prefix hit rate on the shared-prompt workload;
+with ``--prefill-chunk``: that chunk continuations actually ran).  The
+full mode asserts the engine beats the seed loop >= 3x, that the pool
+holds more concurrent requests than ``max_len`` stripes of the same KV
+memory could while reclaiming every retired slot's pages, and that the
+prefix cache cuts prefill tokens computed by exactly its hit rate
+without losing concurrency.
 
 Every invocation also appends its rows to ``BENCH_serve.json`` at the
 repo root — the machine-readable perf trajectory future PRs regress
@@ -135,11 +132,10 @@ def full_bench_rows(r: dict, capacity: dict, prefix: dict,
     """The full-mode trajectory rows, assembled once for both entry
     points (CLI main and the benchmarks.run table hook)."""
     rows = {
-        "full-dense": {k: r[k] for k in
-                       ("engine_tok_s", "seed_tok_s", "speedup",
-                        "kv_util_mean", "peak_live_slots")},
+        "full-engine": {k: r[k] for k in
+                        ("engine_tok_s", "seed_tok_s", "speedup",
+                         "kv_util_mean", "peak_live_slots")},
         "full-capacity-paged": capacity["paged"],
-        "full-capacity-dense": capacity["dense"],
         "full-prefix-on": prefix["cache-on"],
         "full-prefix-off": prefix["cache-off"],
     }
@@ -234,7 +230,7 @@ def seed_batcher_run(model, params, cfg: ServeConfig, requests, max_new):
 def engine_run(model, params, cfg: ServeConfig, requests, max_new,
                chaos=None, telemetry=None):
     """Returns (results, batcher) — the batcher carries the KV-utilization
-    samples and, in paged mode, the page pool.  ``telemetry`` is an
+    samples and the page pool.  ``telemetry`` is an
     optional :class:`repro.serve.telemetry.Tracer` the run records into
     (warmup runs pass none, so a trace holds only the measured drain)."""
     b = Batcher(model, params, cfg, chaos=chaos, telemetry=telemetry)
@@ -253,7 +249,7 @@ def _lat_row(batcher) -> dict:
 
 def bench(arch: str = "qwen2-0.5b", *, batch: int = 4, requests: int = 12,
           max_new: int = 24, max_len: int = 96, sync_every: int = 8,
-          smoke: bool = False, paged: bool = False, page_size: int = 16,
+          smoke: bool = False, page_size: int = 16,
           total_pages: int | None = None, prefix_cache: bool = False,
           shared_prefix: int = 0, prefill_chunk: int | None = None,
           speculate_k: int | None = None,
@@ -266,7 +262,7 @@ def bench(arch: str = "qwen2-0.5b", *, batch: int = 4, requests: int = 12,
     model = Model(cfg)
     params = pm.unwrap(model.init(jax.random.key(seed)))
     scfg = ServeConfig(max_len=max_len, batch=batch, sync_every=sync_every,
-                       paged=paged, page_size=page_size,
+                       page_size=page_size,
                        total_pages=total_pages, prefix_cache=prefix_cache,
                        prefill_chunk=prefill_chunk, speculate_k=speculate_k,
                        admission_mode=admission_mode,
@@ -315,7 +311,7 @@ def bench(arch: str = "qwen2-0.5b", *, batch: int = 4, requests: int = 12,
     kstats = batcher.preempt_stats()
     lat = batcher.latency_stats()
     slo = batcher.slo_stats()
-    out = {"arch": arch, "tokens": toks, "paged": paged,
+    out = {"arch": arch, "tokens": toks,
            "prefix_cache": prefix_cache,
            "engine_tok_s": toks / dt_engine, "engine_s": dt_engine,
            "kv_util_mean": util["mean_util"],
@@ -367,15 +363,14 @@ def bench(arch: str = "qwen2-0.5b", *, batch: int = 4, requests: int = 12,
                 json.dump(rep, f, indent=1)
             print(f"[serve_bench] wrote attribution report -> {attr_out} "
                   f"({rep['requests']} requests)")
-    if paged:
-        # a drained pool holds no mapped pages: everything is back on the
-        # free list except prefix pages parked evictable-cached (zero
-        # reserved cost — reclaimed on pressure) and pages a preempted-
-        # then-retired slot left parked dead (allocatable capacity)
-        out["pages_reclaimed"] = (
-            batcher.pool.free_pages + batcher.pool.cached_pages
-            + batcher.pool.preempted_pages == batcher.pool.n_pages
-            and int(batcher.pool.refcount.sum()) == 0)
+    # a drained pool holds no mapped pages: everything is back on the
+    # free list except prefix pages parked evictable-cached (zero
+    # reserved cost — reclaimed on pressure) and pages a preempted-
+    # then-retired slot left parked dead (allocatable capacity)
+    out["pages_reclaimed"] = (
+        batcher.pool.free_pages + batcher.pool.cached_pages
+        + batcher.pool.preempted_pages == batcher.pool.n_pages
+        and int(batcher.pool.refcount.sum()) == 0)
 
     if not smoke:
         t0 = time.perf_counter()
@@ -390,38 +385,33 @@ def bench(arch: str = "qwen2-0.5b", *, batch: int = 4, requests: int = 12,
 def capacity_compare(arch: str = "qwen2-0.5b", *, requests: int = 16,
                      max_new: int = 24, max_len: int = 96,
                      page_size: int = 16, seed: int = 0) -> dict:
-    """Equal-KV-memory comparison: the dense slot table spends
-    ``batch * max_len`` tokens of capacity on 4 slots; the paged pool
-    spends the same tokens on pages and admits into 8 slots, so short
-    requests run 2x as concurrently.  Returns both engines' peak live
-    slots, throughput and utilization."""
+    """Pool capacity at fixed KV memory: ``stripe_slots * max_len`` tokens
+    would hold only ``stripe_slots`` requests as per-slot ``max_len``
+    stripes; the paged pool spends the same tokens on pages and admits
+    into twice as many slots, so short requests run up to 2x as
+    concurrently.  Returns the pool's peak live slots, throughput and
+    utilization beside the stripe count."""
     cfg = get_config(arch).reduced()
     model = Model(cfg)
     params = pm.unwrap(model.init(jax.random.key(seed)))
     reqs = make_requests(cfg.vocab, requests, seed)
-    dense_batch = 4
-    kv_tokens = dense_batch * max_len                 # equal KV memory
-    dense_cfg = ServeConfig(max_len=max_len, batch=dense_batch)
-    paged_cfg = ServeConfig(max_len=max_len, batch=2 * dense_batch,
-                            paged=True, page_size=page_size,
-                            total_pages=kv_tokens // page_size)
-
-    res = {}
-    for name, scfg in (("dense", dense_cfg), ("paged", paged_cfg)):
-        engine_run(model, params, scfg, reqs, max_new)      # warmup
-        t0 = time.perf_counter()
-        got, b = engine_run(model, params, scfg, reqs, max_new)
-        dt = time.perf_counter() - t0
-        toks = sum(len(v) for v in got.values())
-        util = b.kv_utilization()
-        res[name] = {"tok_s": toks / dt, "s": dt,
-                     "kv_util_mean": util["mean_util"],
-                     "peak_live_slots": util["peak_live_slots"],
-                     **_lat_row(b)}
-        if name == "paged":
-            res[name]["pages_reclaimed"] = (b.pool.free_pages
-                                            == b.pool.n_pages)
-    return res
+    stripe_slots = 4
+    kv_tokens = stripe_slots * max_len                # fixed KV memory
+    scfg = ServeConfig(max_len=max_len, batch=2 * stripe_slots,
+                       page_size=page_size,
+                       total_pages=kv_tokens // page_size)
+    engine_run(model, params, scfg, reqs, max_new)          # warmup
+    t0 = time.perf_counter()
+    got, b = engine_run(model, params, scfg, reqs, max_new)
+    dt = time.perf_counter() - t0
+    toks = sum(len(v) for v in got.values())
+    util = b.kv_utilization()
+    return {"paged": {"tok_s": toks / dt, "s": dt,
+                      "kv_util_mean": util["mean_util"],
+                      "peak_live_slots": util["peak_live_slots"],
+                      "stripe_slots": stripe_slots,
+                      **_lat_row(b),
+                      "pages_reclaimed": b.pool.free_pages == b.pool.n_pages}}
 
 
 def prefix_compare(arch: str = "qwen2-0.5b", *, requests: int = 12,
@@ -442,7 +432,7 @@ def prefix_compare(arch: str = "qwen2-0.5b", *, requests: int = 12,
     # path fits several more (prefix pages counted once, not per request)
     pages_per_req = -(-(prefix_len + 8 + max_new) // page_size)
     pool_pages = 2 * pages_per_req + 2
-    base = dict(max_len=max_len, batch=8, sync_every=8, paged=True,
+    base = dict(max_len=max_len, batch=8, sync_every=8,
                 page_size=page_size, total_pages=pool_pages)
 
     res = {}
@@ -483,7 +473,7 @@ def chunked_compare(arch: str = "qwen2-0.5b", *, requests: int = 8,
         max_len = long_len + max_new + 2 * page_size
     reqs = make_long_mixed_requests(cfg.vocab, requests, long_len,
                                     seed=seed)
-    base = dict(max_len=max_len, batch=4, sync_every=8, paged=True,
+    base = dict(max_len=max_len, batch=4, sync_every=8,
                 page_size=page_size)
 
     res = {}
@@ -528,7 +518,7 @@ def spec_compare(arch: str = "qwen2-0.5b", *, requests: int = 8,
     model = Model(cfg)
     params = pm.unwrap(model.init(jax.random.key(seed)))
     reqs = make_repetitive_requests(cfg.vocab, requests, seed=seed)
-    base = dict(max_len=max_len, batch=batch, sync_every=8, paged=True,
+    base = dict(max_len=max_len, batch=batch, sync_every=8,
                 page_size=page_size)
     wave2 = 10 ** 6      # rid offset of the timed wave
 
@@ -590,7 +580,7 @@ def preempt_compare(arch: str = "qwen2-0.5b", *, requests: int = 9,
                                size=int(rng.integers(8, 14))).tolist())
             for rid in range(requests)]
     base = dict(max_len=max_len, batch=batch, sync_every=sync_every,
-                paged=True, page_size=page_size, total_pages=pool_pages)
+                page_size=page_size, total_pages=pool_pages)
 
     res = {}
     for name, mode in (("reserve", "reserve"), ("optimistic", "optimistic")):
@@ -675,7 +665,7 @@ def overload_compare(arch: str = "qwen2-0.5b", *, wave: int = 4,
     # calibration, both measured on a *warm* batcher (a fresh one would
     # time jit compilation, not serving)
     ref_cfg = ServeConfig(max_len=max_len, batch=batch,
-                          sync_every=sync_every, paged=True,
+                          sync_every=sync_every,
                           page_size=page_size)
     rb = Batcher(model, params, ref_cfg)
     for rid, p in reqs:
@@ -693,7 +683,7 @@ def overload_compare(arch: str = "qwen2-0.5b", *, wave: int = 4,
     deadline = 2.0 * t_wave
 
     base = dict(max_len=max_len, batch=batch, sync_every=sync_every,
-                paged=True, page_size=page_size, total_pages=pool_pages,
+                page_size=page_size, total_pages=pool_pages,
                 admission_mode="optimistic")
     res = {}
     for name, on in (("controller-off", False), ("controller-on", True)):
@@ -890,7 +880,7 @@ def print_roofline() -> None:
 
 
 def run(table) -> None:
-    """Hook for benchmarks.run: engine-vs-seed, dense-vs-paged and
+    """Hook for benchmarks.run: engine-vs-seed, pool-capacity and
     prefix-cache rows plus the paged-attention roofline; also refreshes
     BENCH_serve.json."""
     from repro.kernels.paged_attn import attn_telemetry
@@ -904,13 +894,12 @@ def run(table) -> None:
               f"{r['engine_tok_s']:.1f} tok/s ({r['speedup']:.1f}x, "
               f"KV util {r['kv_util_mean']:.0%})")
     c = capacity_compare(requests=12, max_new=16)
-    table.add("serve paged KV pool (equal KV mem)",
+    table.add("serve paged KV pool (fixed KV mem)",
               c["paged"]["s"] * 1e9,
               f"{c['paged']['tok_s']:.1f} tok/s, "
               f"{c['paged']['peak_live_slots']} live slots vs "
-              f"{c['dense']['peak_live_slots']} dense, "
-              f"KV util {c['paged']['kv_util_mean']:.0%} vs "
-              f"{c['dense']['kv_util_mean']:.0%}")
+              f"{c['paged']['stripe_slots']} max_len stripes, "
+              f"KV util {c['paged']['kv_util_mean']:.0%}")
     p = prefix_compare(requests=12, max_new=16)
     on, off = p["cache-on"], p["cache-off"]
     table.add("serve prefix cache (shared prompt)",
@@ -969,19 +958,17 @@ def main() -> None:
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--max-len", type=int, default=96)
     ap.add_argument("--sync-every", type=int, default=8)
-    ap.add_argument("--paged", action="store_true",
-                    help="serve through the paged KV-cache block pool")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="shared-prefix radix cache (needs --paged); runs "
+                    help="shared-prefix radix cache; runs "
                          "a repeated-system-prompt workload and reports "
                          "hit rate + prefill tokens computed vs skipped")
     ap.add_argument("--prefill-chunk", type=int, default=None,
-                    help="chunked prefill (needs --paged): admit prompts "
+                    help="chunked prefill: admit prompts "
                          "in page-aligned chunks of this many tokens, "
                          "interleaved with decode segments")
     ap.add_argument("--speculate", type=int, default=None,
-                    help="self-speculative decoding (needs --paged): "
+                    help="self-speculative decoding: "
                          "draft this many tokens per step from the "
                          "slot's own history and verify them in one "
                          "multi-token paged attention call (greedy, "
@@ -989,15 +976,15 @@ def main() -> None:
                          "continuation workload and reports the "
                          "acceptance rate")
     ap.add_argument("--optimistic", action="store_true",
-                    help="optimistic admission + page-level preemption "
-                         "(needs --paged): admit on prompt pages only, "
+                    help="optimistic admission + page-level preemption: "
+                         "admit on prompt pages only, "
                          "grow on demand, preempt the policy victim on "
                          "pool pressure with recompute-on-resume; the "
                          "smoke forces pool exhaustion via the chaos "
                          "injector and gates preemptions > 0 + bit-safe "
                          "recompute, the full mode runs preempt_compare")
     ap.add_argument("--overload", action="store_true",
-                    help="overload protection (needs --paged): serve "
+                    help="overload protection: serve "
                          "with the degradation controller on while the "
                          "chaos injector exhausts the pool and injects a "
                          "deadline-stamped low-priority queue burst; the "
@@ -1080,20 +1067,9 @@ def main() -> None:
     if args.attr_out and not args.trace_out:
         ap.error("--attr-out requires --trace-out (attribution walks "
                  "the recorded trace)")
-    if args.prefix_cache and not args.paged:
-        ap.error("--prefix-cache requires --paged")
-    if args.optimistic and not args.paged:
-        ap.error("--optimistic requires --paged")
-    if args.overload and not args.paged:
-        ap.error("--overload requires --paged")
-    if args.speculate is not None:
-        if not args.paged:
-            ap.error("--speculate requires --paged")
-        if args.speculate < 1:
-            ap.error("--speculate must be >= 1")
+    if args.speculate is not None and args.speculate < 1:
+        ap.error("--speculate must be >= 1")
     if args.prefill_chunk is not None:
-        if not args.paged:
-            ap.error("--prefill-chunk requires --paged")
         if args.prefill_chunk <= 0:
             ap.error("--prefill-chunk must be positive")
         if args.prefill_chunk % args.page_size:
@@ -1149,8 +1125,8 @@ def main() -> None:
                   max_len=2 * chunk + 32 if chunk else
                           48 if (args.speculate or args.optimistic
                                  or args.overload) else 32,
-                  sync_every=4, smoke=True, paged=args.paged,
-                  page_size=smoke_ps, prefix_cache=args.prefix_cache,
+                  sync_every=4, smoke=True, page_size=smoke_ps,
+                  prefix_cache=args.prefix_cache,
                   prefill_chunk=chunk, speculate_k=args.speculate,
                   # tight pool so slot growth actually contends while
                   # the chaos injector holds pages back
@@ -1174,8 +1150,7 @@ def main() -> None:
                   # bites (the shared prefix also feeds --prefix-cache)
                   shared_prefix=2 * chunk if chunk else 0)
         assert r["engine_tok_s"] > 0, r
-        if args.paged:
-            assert r["pages_reclaimed"], "retired pages were not reclaimed"
+        assert r["pages_reclaimed"], "retired pages were not reclaimed"
         if args.optimistic:
             assert r["preemptions"] > 0, \
                 "chaos-starved pool forced no preemptions"
@@ -1204,7 +1179,7 @@ def main() -> None:
                 else "spec" if args.speculate
                 else "chunked" if chunk
                 else "paged+prefix" if args.prefix_cache
-                else "paged" if args.paged else "dense")
+                else "paged")
         write_bench_json({f"smoke-{mode}": {
             "tok_s": r["engine_tok_s"], "tokens": r["tokens"],
             "kv_util_mean": r["kv_util_mean"],
@@ -1228,7 +1203,7 @@ def main() -> None:
             "time_healthy_s": r["time_healthy_s"],
             "time_degraded_s": r["time_degraded_s"],
             "time_shedding_s": r["time_shedding_s"],
-            "pages_reclaimed": bool(r.get("pages_reclaimed", False))}})
+            "pages_reclaimed": r["pages_reclaimed"]}})
         dom = (f", dominant TTFT {r['dominant_ttft_component']}"
                if "dominant_ttft_component" in r else "")
         ovl = (f", shed {r['shed_requests']}, cancelled "
@@ -1248,15 +1223,15 @@ def main() -> None:
     attn_telemetry().enable()      # roofline accounting over the full run
     r = bench(args.arch, batch=args.batch, requests=args.requests,
               max_new=args.max_new, max_len=args.max_len,
-              sync_every=args.sync_every, paged=args.paged,
-              page_size=args.page_size, prefix_cache=args.prefix_cache,
+              sync_every=args.sync_every, page_size=args.page_size,
+              prefix_cache=args.prefix_cache,
               prefill_chunk=args.prefill_chunk,
               speculate_k=args.speculate, trace_out=args.trace_out,
               attr_out=args.attr_out, ttft_slo=args.ttft_slo,
               tpot_slo=args.tpot_slo)
     mode = ("spec" if args.speculate
             else "paged+prefix" if args.prefix_cache
-            else "paged" if args.paged else "dense")
+            else "paged")
     print(f"[serve_bench] arch={r['arch']} mode={mode} "
           f"tokens={r['tokens']} backend={jax.default_backend()}")
     print(f"  seed per-token loop : {r['seed_tok_s']:8.1f} tok/s "
@@ -1277,17 +1252,14 @@ def main() -> None:
 
     c = capacity_compare(args.arch, max_new=args.max_new,
                          max_len=args.max_len, page_size=args.page_size)
-    d, p = c["dense"], c["paged"]
-    print(f"[capacity @ equal KV memory] dense: {d['tok_s']:.1f} tok/s, "
-          f"peak {d['peak_live_slots']} live slots, "
-          f"KV util {d['kv_util_mean']:.1%}")
-    print(f"                             paged: {p['tok_s']:.1f} tok/s, "
-          f"peak {p['peak_live_slots']} live slots, "
+    p = c["paged"]
+    print(f"[capacity @ fixed KV memory] paged: {p['tok_s']:.1f} tok/s, "
+          f"peak {p['peak_live_slots']} live slots "
+          f"(vs {p['stripe_slots']} max_len stripes), "
           f"KV util {p['kv_util_mean']:.1%}, "
           f"reclaimed={p['pages_reclaimed']}")
-    assert (p["peak_live_slots"] > d["peak_live_slots"]
-            or (p["tok_s"] >= 0.9 * d["tok_s"] and p["pages_reclaimed"])), \
-        "paged pool shows no capacity or throughput win over dense"
+    assert p["peak_live_slots"] > p["stripe_slots"] and p["pages_reclaimed"], \
+        "paged pool holds no more requests than max_len stripes would"
 
     pc = prefix_compare(args.arch, max_new=args.max_new,
                         max_len=args.max_len)

@@ -11,12 +11,13 @@ Phases, all in this one process:
     verify (Lq 5) kernels at qwen2-0.5b geometry against the ``ref.py``
     oracles;
 (c) ``repro.launch.serve.run`` at published widths with random weights
-    from ``--seed``, bf16, greedy: dense, paged, paged with
-    ``speculate_k=4``, and paged on ``attn_mode="xla"`` — 16 requests with
-    prompts of 32-1536 tokens, so that joins reach the 1024 and 2048
-    buckets.  The first wave's prefill and first-decode logits are
-    compared between the kernel and XLA routes for both KV layouts, and
-    the greedy-token agreement with the XLA run is printed.
+    from ``--seed``, bf16, greedy: paged, paged with ``speculate_k=4``,
+    and paged on ``attn_mode="xla"`` — 16 requests with prompts of
+    32-1536 tokens, so that joins reach the 1024 and 2048 buckets.  The
+    first wave's prefill and first-decode logits are compared between
+    the kernel and XLA routes over the model's dense caches and over the
+    paged pool, and the greedy-token agreement with the XLA run is
+    printed.
 
 Any failed check raises, so the script exits non-zero without printing
 its last line, ``{"ok": true, "device": {...}}``.  The tok/s it prints
@@ -265,7 +266,7 @@ def _agreement(a: dict, b: dict) -> tuple[int, int, int]:
 
 def serve_phase(plan: Plan, *, interpret: bool | None,
                 on_tpu: bool) -> dict:
-    """Phase (c): the four served runs, the route checks and the
+    """Phase (c): the three served runs, the route checks and the
     kernel-vs-XLA logits comparison of the first wave."""
     import jax
     import numpy as np
@@ -279,10 +280,10 @@ def serve_phase(plan: Plan, *, interpret: bool | None,
     lens = sorted(rng.integers(lo, hi + 1, size=plan.requests).tolist(),
                   reverse=True)
     _say(f"prompt lengths: {lens}")
-    runs = {"dense": dict(paged=False), "paged": dict(paged=True),
+    runs = {"paged": {},
             f"paged+spec{plan.speculate_k}": dict(
-                paged=True, speculate_k=plan.speculate_k),
-            "paged/xla": dict(paged=True, attn_mode="xla")}
+                speculate_k=plan.speculate_k),
+            "paged/xla": dict(attn_mode="xla")}
     outs = {}
     for name, kw in runs.items():
         t0 = time.perf_counter()
@@ -296,12 +297,11 @@ def serve_phase(plan: Plan, *, interpret: bool | None,
         route = "xla" if kw.get("attn_mode") == "xla" else "kernel"
         if kw.get("speculate_k") and not out["spec"]["steps"]:
             raise AssertionError(f"{name}: no verify step ran")
-        if kw["paged"]:
-            seen = {k.split(".")[0] for k in out["routes"]}
-            bad = [k for k in out["routes"] if not k.endswith("." + route)]
-            if not want <= seen or bad:
-                raise AssertionError(f"{name}: attention routes "
-                                     f"{out['routes']}, wanted {route}")
+        seen = {k.split(".")[0] for k in out["routes"]}
+        bad = [k for k in out["routes"] if not k.endswith("." + route)]
+        if not want <= seen or bad:
+            raise AssertionError(f"{name}: attention routes "
+                                 f"{out['routes']}, wanted {route}")
         widths = out["join"]["widths"]
         if not set(plan.widths) <= set(widths):
             raise AssertionError(f"{name}: join widths {widths} miss "

@@ -88,32 +88,30 @@ EOF
 }
 
 SMOKES=(
-  # dense baseline engine
-  "dense|--smoke"
   # paged KV-cache block pool
-  "paged KV cache|--paged --smoke"
+  "paged KV cache|--smoke"
   # repeated-system-prompt workload; asserts nonzero prefix hit rate
   # and that prefill tokens were actually skipped
-  "paged + shared-prefix radix cache|--paged --prefix-cache --smoke"
+  "paged + shared-prefix radix cache|--prefix-cache --smoke"
   # long-prompt workload; asserts chunk continuations actually ran
   # (PREFILLING slots resumed across join rounds)
-  "chunked prefill|--paged --prefill-chunk 16 --smoke"
+  "chunked prefill|--prefill-chunk 16 --smoke"
   # repetitive-continuation workload; asserts the n-gram drafter got
   # drafts accepted (acceptance_rate > 0) at bit-identical output
-  "self-speculative decoding|--paged --speculate 3 --smoke"
+  "self-speculative decoding|--speculate 3 --smoke"
   # tiny pool + chaos-forced exhaustion; asserts at least one slot was
   # preempted and every preempted request completed via
   # recompute-on-resume.  --trace-out records the richest lifecycle
   # trace (preempt/resume, chaos instants) as trace_smoke.json for the
   # gate below; --attr-out decomposes it into per-request TTFT/TPOT
   # bottleneck components (attribution_report.json rides as an artifact)
-  "optimistic admission + forced preemption|--paged --optimistic --smoke \
+  "optimistic admission + forced preemption|--optimistic --smoke \
 --trace-out trace_smoke.json --attr-out attribution_report.json"
   # chaos burst into a tight pool with the degradation controller on;
   # asserts requests were actually cancelled and shed (check_bench gates
   # cancellations/shed_requests nonzero + recovered_to_healthy + a sane
   # deadline_attainment on the smoke-overload row)
-  "overload protection|--paged --overload --smoke"
+  "overload protection|--overload --smoke"
   # bounded kernel-autotune sweep (<=4 measured candidates per op,
   # 2 reps, one geometry): winners land as autotune-* rows and persist
   # to tuned_shapes.json, gated + uploaded as the tuning-tier artifact

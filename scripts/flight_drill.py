@@ -71,7 +71,7 @@ def drill(out_path: str, fault_round: int = 3) -> list[str]:
     model = Model(cfg)
     params = pm.unwrap(model.init(jax.random.key(0)))
     scfg = ServeConfig(max_len=48, batch=2, dtype=jnp.float32,
-                       sync_every=4, paged=True, page_size=8,
+                       sync_every=4, page_size=8,
                        total_pages=10, flight_path=out_path)
     b = Batcher(model, params, scfg,
                 chaos=PoolFaultInjector(fault_round))

@@ -3,10 +3,10 @@
 ``decode_attn`` accepts either one shared length or per-slot lengths
 ([B] int32) so a continuous-batching scheduler can keep mixed-depth
 requests in one launch.  ``s_cap`` statically prunes the KV-block grid to
-``cdiv(s_cap, bs)`` — the serving engine passes a host-known bound on the
-deepest live slot between scan segments, so blocks past *every* slot's
-length are never launched (§5.1.2 command skipping at grid granularity);
-per-slot skipping inside the kernel handles the rest.
+``cdiv(s_cap, bs)`` — a caller that knows a bound on the deepest live
+slot passes it, so blocks past *every* slot's length are never launched
+(§5.1.2 command skipping at grid granularity); per-slot skipping inside
+the kernel handles the rest.
 
 ``DecodeAttnPolicy`` is how the model's attention layer decides, at trace
 time, whether decode attention routes through this kernel and whether the
@@ -56,7 +56,6 @@ class DecodeAttnPolicy:
     mode: str = "auto"              # "kernel" | "xla" | "auto"
     interpret: bool | None = None   # None -> auto (CPU interprets)
     block_size: int = BS
-    kv_cap: int | None = None       # static bound on live KV depth
     use_tuned: bool = True          # consult the autotuned-shape cache
     tuned_path: str | None = None   # None -> committed default (env wins)
 
@@ -123,13 +122,12 @@ def active_policy() -> DecodeAttnPolicy:
 @contextlib.contextmanager
 def decode_attn_policy(**kw):
     """Override the decode-attention routing policy for code traced inside
-    this context (jit caches must key on anything that varies, e.g. the
-    engine re-jits per kv_cap bucket)."""
+    this context (jit caches must key on anything that varies)."""
     global _ACTIVE
     prev = _ACTIVE
     _ACTIVE = dataclasses.replace(prev, **{k: v for k, v in kw.items()
-                                           if v is not None or k in
-                                           ("interpret", "kv_cap")})
+                                           if v is not None
+                                           or k == "interpret"})
     try:
         yield _ACTIVE
     finally:

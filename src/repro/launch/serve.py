@@ -24,7 +24,7 @@ def run(arch: str, *, reduced: bool = True, requests: int = 4,
         max_new: int = 8, batch: int = 4, max_len: int = 64,
         seed: int = 0, sync_every: int = 8, temperature: float = 0.0,
         eos_id: int | None = None, attn_mode: str = "auto",
-        paged: bool = False, page_size: int = 16,
+        page_size: int = 16,
         total_pages: int | None = None, prefix_cache: bool = False,
         shared_prefix: int = 0, admission: str = "fifo",
         prefill_chunk: int | None = None,
@@ -49,7 +49,7 @@ def run(arch: str, *, reduced: bool = True, requests: int = 4,
     params = pm.unwrap(jax.jit(model.init)(jax.random.key(seed)))
     scfg = ServeConfig(max_len=max_len, batch=batch, sync_every=sync_every,
                        temperature=temperature, attn_mode=attn_mode,
-                       paged=paged, page_size=page_size,
+                       page_size=page_size,
                        total_pages=total_pages, prefix_cache=prefix_cache,
                        admission=admission, prefill_chunk=prefill_chunk,
                        prefill_round_tokens=prefill_round_tokens,
@@ -80,8 +80,7 @@ def run(arch: str, *, reduced: bool = True, requests: int = 4,
     toks = sum(len(v) for v in results.values())
     util = b.kv_utilization()
     pstats = b.prefix_stats()
-    mode = (f"paged pool {b.pool.n_pages}x{b.pool.page_size}" if paged
-            else "dense")
+    mode = f"paged pool {b.pool.n_pages}x{b.pool.page_size}"
     if prefill_chunk:
         j = b.join_stats()
         mode += (f" + chunked prefill ({prefill_chunk} tok/chunk, "
@@ -162,14 +161,13 @@ def main() -> None:
     ap.add_argument("--eos-id", type=int, default=None)
     ap.add_argument("--attn-mode", default="auto",
                     choices=("auto", "kernel", "xla"))
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV cache: block pool + per-slot page tables")
     ap.add_argument("--page-size", type=int, default=16)
     ap.add_argument("--total-pages", type=int, default=None,
-                    help="pool size in pages (default: dense-equivalent)")
+                    help="pool size in pages (default: every slot can "
+                         "hold a full --max-len request)")
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="shared-prefix radix cache over the page pool "
-                         "(needs --paged): requests matching a cached "
+                    help="shared-prefix radix cache over the page pool: "
+                         "requests matching a cached "
                          "page-aligned prompt prefix share its pages and "
                          "prefill only their suffix; retired prefix pages "
                          "stay resident (evictable, LRU) at zero reserved "
@@ -179,12 +177,12 @@ def main() -> None:
                          "to every request (exercises --prefix-cache)")
     ap.add_argument("--admission", default="fifo",
                     choices=("fifo", "skip-ahead"),
-                    help="paged admission order: fifo blocks on the queue "
+                    help="admission order: fifo blocks on the queue "
                          "head; skip-ahead admits the first queued request "
                          "whose pages fit (bounded lookahead, aged so a "
                          "blocked head cannot starve)")
     ap.add_argument("--prefill-chunk", type=int, default=None,
-                    help="chunked prefill (needs --paged): prefill each "
+                    help="chunked prefill: prefill each "
                          "prompt's uncached suffix at most this many "
                          "tokens per join round (multiple of --page-size), "
                          "interleaving long-prompt admission with decode "
@@ -194,8 +192,8 @@ def main() -> None:
                          "tokens (chunks + admissions) one refill round "
                          "may take, deferring the rest to later rounds")
     ap.add_argument("--speculate", type=int, default=None,
-                    help="self-speculative decoding (needs --paged, "
-                         "greedy): draft this many tokens per step from "
+                    help="self-speculative decoding (greedy): draft this "
+                         "many tokens per step from "
                          "the slot's own history (n-gram lookup) and "
                          "verify them in one multi-token paged attention "
                          "call — bit-identical output, fewer steps on "
@@ -203,7 +201,7 @@ def main() -> None:
     ap.add_argument("--speculate-ngram", type=int, default=2,
                     help="history-match width of the draft lookup")
     ap.add_argument("--optimistic", action="store_true",
-                    help="optimistic admission (needs --paged): admit on "
+                    help="optimistic admission: admit on "
                          "the prompt's pages only and grow on demand, "
                          "preempting the lowest-priority / most-pages / "
                          "least-progress slot on pool pressure "
@@ -244,7 +242,7 @@ def main() -> None:
     run(args.arch, reduced=args.reduced, requests=args.requests,
         max_new=args.max_new, batch=args.batch, max_len=args.max_len,
         sync_every=args.sync_every, temperature=args.temperature,
-        eos_id=args.eos_id, attn_mode=args.attn_mode, paged=args.paged,
+        eos_id=args.eos_id, attn_mode=args.attn_mode,
         page_size=args.page_size, total_pages=args.total_pages,
         prefix_cache=args.prefix_cache, shared_prefix=args.shared_prefix,
         admission=args.admission, prefill_chunk=args.prefill_chunk,

@@ -227,8 +227,7 @@ def _paged_kernel_route(q, cache: "PagedKVCache", kv_len, dtype):
 
 def _decode_kernel_route(q, kc, vc, kv_len, dtype):
     """Route one-token GQA decode attention through the Pallas kernel when
-    the active policy asks for it.  q: [B,1,Hq,D] -> [B,1,Hq,D].  The
-    caller has already applied the policy's kv_cap slice to kc/vc."""
+    the active policy asks for it.  q: [B,1,Hq,D] -> [B,1,Hq,D]."""
     from ..kernels.decode_attn import decode_attn
     pol = _decode_policy()
     out = decode_attn(q[:, 0], kc.astype(dtype), vc.astype(dtype), kv_len,
@@ -314,10 +313,6 @@ def gqa_apply(params: dict, x: jnp.ndarray, cfg: ArchConfig, *,
         kv_len = cache.length + x.shape[1]
         new_cache = KVCache(kc, vc, kv_len)
         pol = _decode_policy()
-        if pol.kv_cap is not None and pol.kv_cap < kc.shape[1]:
-            # grid pruning: the engine bounds the deepest live slot between
-            # scan segments, so dead KV blocks never enter the attention op
-            kc, vc = kc[:, :pol.kv_cap], vc[:, :pol.kv_cap]
         if x.shape[1] == 1 and not ctx_shard and pol.kernel_wanted():
             out = _decode_kernel_route(q, kc, vc, kv_len, x.dtype)
         else:
@@ -407,9 +402,6 @@ def mla_apply(params: dict, x: jnp.ndarray, cfg: ArchConfig, *,
         ckv_c = _cache_insert(cache.k, c_kv, cache.length)
         kr_c = _cache_insert(cache.v, k_rope, cache.length)
         new_cache = KVCache(ckv_c, kr_c, cache.length + x.shape[1])
-        pol = _decode_policy()
-        if pol.kv_cap is not None and pol.kv_cap < ckv_c.shape[1]:
-            ckv_c, kr_c = ckv_c[:, :pol.kv_cap], kr_c[:, :pol.kv_cap]
         c_kv_all, k_rope_all = ckv_c.astype(x.dtype), kr_c.astype(x.dtype)
         kv_len = cache.length + x.shape[1]
         q_offset = cache.length

@@ -126,14 +126,14 @@ class ChaosInjector:
 
         def trace(kind, **attrs):
             if tr is not None:
-                tr.event(kind, None, round=r,
-                         pool_free=pool.free_pages if pool else 0, **attrs)
+                tr.event(kind, None, round=r, pool_free=pool.free_pages,
+                         **attrs)
 
-        if pool is not None and r in self.release_at:
+        if r in self.release_at:
             released = pool.release_held()
             self.events.append((r, "release_held", released))
             trace("CHAOS_RELEASE_HELD", pages=released)
-        if pool is not None and r in self.exhaust_at:
+        if r in self.exhaust_at:
             keep = self.exhaust_at[r]
             taken = pool.hold(max(0, pool.free_pages - keep))
             self.events.append((r, "hold", len(taken)))
@@ -167,8 +167,7 @@ class ChaosInjector:
             self.events.append((r, "burst", n))
             trace("CHAOS_BURST", requests=n)
         if self.check_invariants:
-            if pool is not None:
-                pool.check()
+            pool.check()
             if batcher.prefix is not None:
                 batcher.prefix.check()
 
@@ -197,12 +196,10 @@ class ChaosInjector:
         alongside the run's ``max_new`` budget + speculation window."""
         cfg = batcher.cfg
         budget = getattr(batcher, "_max_new", 16) + batcher.spec_k
-        cap = cfg.max_len - budget
-        if batcher.pool is not None:
-            pool = batcher.pool
-            cap = min(cap, min(pool.n_pages, pool.max_pages)
-                      * pool.page_size - budget)
-        plen = max(1, min(cfg.page_size if cfg.paged else 8, cap))
+        pool = batcher.pool
+        cap = min(cfg.max_len, min(pool.n_pages, pool.max_pages)
+                  * pool.page_size) - budget
+        plen = max(1, min(cfg.page_size, cap))
         return [1 + (rid * 7 + j) % 13 for j in range(plen)]
 
     @staticmethod

@@ -43,13 +43,15 @@ class ServeConfig:
     sync_every: int = 8          # decode steps per host sync (scan length)
     attn_mode: str = "auto"      # decode attention: "kernel"|"xla"|"auto"
     attn_interpret: bool | None = None   # None -> off on TPU, on elsewhere
-    # paged KV cache (repro.serve.kvpool): fixed-size pages in one pooled
-    # allocation, per-slot page tables, admission on free-page capacity
-    paged: bool = False
+    # the KV cache is paged (repro.serve.kvpool): fixed-size pages in one
+    # pooled allocation, per-slot page tables, admission on free-page
+    # capacity.  It is the only layout: ``paged`` must be True, and stays
+    # a field because configuration files name the layout
+    paged: bool = True
     page_size: int = 16          # KV rows per page
     total_pages: int | None = None   # pool size; None -> batch * max pages
-    #   (i.e. the same token capacity as the dense slot table)
-    # shared-prefix radix cache (repro.serve.prefixcache, needs paged):
+    #   (every slot can hold a full max_len request at once)
+    # shared-prefix radix cache (repro.serve.prefixcache):
     # full prompt pages are registered in a radix tree, later requests map
     # the matched pages via KVPool.share and prefill only their suffix
     prefix_cache: bool = False
@@ -58,7 +60,7 @@ class ServeConfig:
     # the first one whose pages fit when the head does not (higher slot
     # occupancy under mixed prompt sizes, bounded reorder window)
     admission: str = "fifo"
-    # admission sizing (needs paged): "reserve" (default) maps the whole
+    # admission sizing: "reserve" (default) maps the whole
     # worst case (prompt + max_new + speculation window) at admission, so
     # a slot can never run out of pages but the pool runs far under its
     # real capacity whenever outputs finish early.  "optimistic" maps only
@@ -78,7 +80,7 @@ class ServeConfig:
     # small-request load cannot starve a big prompt.  0 degenerates
     # skip-ahead to FIFO.
     admission_max_skips: int = 8
-    # chunked prefill (needs paged): a joining prompt's uncached suffix is
+    # chunked prefill: a joining prompt's uncached suffix is
     # prefilled at most ``prefill_chunk`` tokens per join round, the slot
     # parking in the PREFILLING state (device done-latch frozen) between
     # chunks so live slots' decode segments interleave with the remaining
@@ -95,7 +97,7 @@ class ServeConfig:
     # pieces ride the next round and are counted in ``join_stats()``.
     # None (default) keeps the one-chunk-per-slot-per-round behavior.
     prefill_round_tokens: int | None = None
-    # self-speculative decoding (needs paged; greedy/attention-only): each
+    # self-speculative decoding (greedy/attention-only): each
     # decode step drafts ``speculate_k`` candidate tokens from the slot's
     # own prompt+output history (on-device n-gram lookup, see
     # :func:`ngram_propose`) and verifies all k+1 tokens in ONE multi-token
@@ -166,6 +168,12 @@ class ServeConfig:
     # head instead of raising
     watchdog_rounds: int = 100_000
 
+    def __post_init__(self):
+        if not self.paged:
+            raise ValueError(
+                "paged=False: the dense per-slot KV layout was removed; the "
+                "serving stack keeps KV only in the paged pool")
+
     @property
     def max_pages(self) -> int:
         """Page-table width: pages needed for a full-length slot."""
@@ -214,12 +222,6 @@ def jit_decode_step(model: Model, cfg: ServeConfig, mesh: Mesh,
                       shd.replicated(mesh), extra_shard),
         out_shardings=(tok_shard, cache_shard),
         donate_argnums=(2,))
-
-
-def make_prefill(model: Model, cfg: ServeConfig):
-    def prefill(params, batch):
-        return model.prefill(params, batch, cfg.max_len, dtype=cfg.dtype)
-    return prefill
 
 
 # ---------------------------------------------------------------------------
@@ -282,29 +284,25 @@ def ngram_propose(history: jnp.ndarray, lengths: jnp.ndarray, *,
 # ---------------------------------------------------------------------------
 
 def make_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
-                     eos_id: int | None, kv_cap: int | None = None,
-                     paged: bool = False, speculate_k: int = 0):
+                     eos_id: int | None, speculate_k: int = 0):
     """Build the fused multi-token decode driver.
 
-    Returns ``loop(params, tok, caches, lengths, done, remaining, key
-    [, pages]) -> ((tok, caches, lengths, done, remaining, key), emitted)``
+    Returns ``loop(params, tok, caches, lengths, done, remaining, key,
+    pages) -> ((tok, caches, lengths, done, remaining, key), emitted)``
     where ``emitted`` is [steps, B] int32 with PAD_TOKEN in retired slots.
     All state stays on device across the scan; per-slot ``lengths`` drive
     the cache writes, RoPE positions and attention masks, ``done`` freezes
     retired slots (EOS or budget), and sampling happens on device.
 
-    With ``paged`` the loop additionally takes ``pages`` — the [B, P_cap]
-    slice of the device page table, held constant across the scan (the
-    scheduler reserves every slot's worst case at admission, so a segment
-    can never outgrow its pages).  ``P_cap`` then plays ``kv_cap``'s role,
-    but the pruning is shape-driven instead of policy-driven: the
-    scheduler buckets the deepest live slot's *page count* to a power of
-    two and slices the table before the call, so the paged-attention grid
-    (and the XLA gather width) is the bucket — dead pages are never
-    launched.  One executable is cached per (steps, P_cap) bucket, exactly
-    like the dense loop's (steps, kv_cap) keying.
+    ``pages`` is the [B, P_cap] slice of the device page table, held
+    constant across the scan (the scheduler maps every page a segment can
+    write before the call, so a segment can never outgrow its pages).
+    The scheduler buckets the deepest live slot's *page count* to a power
+    of two and slices the table before the call, so the paged-attention
+    grid (and the XLA gather width) is the bucket — dead pages are never
+    launched.  One executable is cached per (steps, P_cap) bucket.
 
-    With ``speculate_k`` = k > 0 (paged + greedy only) each scan step is a
+    With ``speculate_k`` = k > 0 (greedy only) each scan step is a
     draft-k **verify** step instead of a one-token decode: the carry grows
     a per-slot token ``history`` [B, max_len], :func:`ngram_propose`
     drafts k candidates from it, and one ``model.decode_step`` call with
@@ -327,13 +325,11 @@ def make_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
     temp = cfg.temperature
     spec_n = cfg.speculate_ngram
 
-    def loop(params, tok, caches, lengths, done, remaining, key,
-             pages=None):
+    def loop(params, tok, caches, lengths, done, remaining, key, pages):
         def body(carry, _):
             tok, caches, lengths, done, remaining, key = carry
             with decode_attn_policy(mode=cfg.attn_mode,
-                                    interpret=cfg.attn_interpret,
-                                    kv_cap=None if paged else kv_cap):
+                                    interpret=cfg.attn_interpret):
                 logits, caches = model.decode_step(
                     params, tok, caches, lengths, dtype=cfg.dtype,
                     pages=pages)
@@ -357,8 +353,6 @@ def make_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
 
     if not speculate_k:
         return loop
-    if not paged:
-        raise ValueError("speculate_k requires the paged loop")
     k = speculate_k
 
     def spec_loop(params, tok, caches, lengths, done, remaining, key,
@@ -423,81 +417,25 @@ def make_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
     return spec_loop
 
 
-def jit_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
-                    eos_id: int | None, kv_cap: int | None = None):
-    """Jitted decode segment: the caches argument is donated so the KV
-    cache is updated in place across the whole scan (the small carry
-    arrays — tokens, lengths, flags, key — are copied)."""
-    loop = make_decode_loop(model, cfg, steps=steps, eos_id=eos_id,
-                            kv_cap=kv_cap)
-    return jax.jit(loop, donate_argnums=(2,))
-
-
 def jit_paged_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
                           eos_id: int | None):
-    """Jitted paged decode segment — :func:`make_decode_loop` with
-    ``paged=True`` (the call site passes the sliced page table)."""
-    loop = make_decode_loop(model, cfg, steps=steps, eos_id=eos_id,
-                            paged=True)
+    """Jitted decode segment — :func:`make_decode_loop`; the caches
+    argument is donated, so the KV pool is updated in place across the
+    whole scan (the call site passes the sliced page table)."""
+    loop = make_decode_loop(model, cfg, steps=steps, eos_id=eos_id)
     return jax.jit(loop, donate_argnums=(2,))
 
 
 def jit_spec_decode_loop(model: Model, cfg: ServeConfig, *, steps: int,
                          eos_id: int | None):
-    """Jitted self-speculative verify segment — the paged loop with
+    """Jitted self-speculative verify segment — the decode loop with
     ``speculate_k`` drafts per step; takes ``(..., history, pages)`` and
     returns ``emitted`` [steps, B, k+1] (PAD past each step's committed
     count).  Caches are donated as usual; the history array is tiny
     ([B, max_len] int32) and returned in the carry."""
     loop = make_decode_loop(model, cfg, steps=steps, eos_id=eos_id,
-                            paged=True, speculate_k=cfg.speculate_k or 0)
+                            speculate_k=cfg.speculate_k or 0)
     return jax.jit(loop, donate_argnums=(2,))
-
-
-def make_join(model: Model, cfg: ServeConfig, *, eos_id: int | None):
-    """Build the slot-refill step: batch-prefill the joining prompts (padded
-    to one width) and select them into the live slot state.
-
-    ``join_mask`` [B] picks the slots being (re)filled; rows outside the
-    mask keep their caches, token, length and flags bit-for-bit (the
-    prefill computes for every row, but ``jnp.where`` on the batch axis
-    discards the non-joining rows).  Returns the refreshed state plus each
-    row's first sampled token.
-    """
-    temp = cfg.temperature
-
-    def join(params, caches, tok, lengths, done, remaining,
-             join_mask, prompts, plens, budgets, key):
-        with decode_attn_policy(mode=cfg.attn_mode,
-                                interpret=cfg.attn_interpret):
-            logits, new_caches = model.prefill(
-                params, {"tokens": prompts}, cfg.max_len, dtype=cfg.dtype,
-                last_pos=plens - 1)
-        key, sub = jax.random.split(key)
-        first = sample_tokens(logits[:, -1], sub, temp)
-        if eos_id is None:
-            is_eos = jnp.zeros_like(join_mask)
-        else:
-            is_eos = first == eos_id
-        rem_new = budgets - 1
-        tok = jnp.where(join_mask[:, None], first[:, None], tok)
-        lengths = jnp.where(join_mask, plens, lengths)
-        remaining = jnp.where(join_mask, rem_new, remaining)
-        done = jnp.where(join_mask, is_eos | (rem_new <= 0), done)
-
-        def select(new, old):
-            m = join_mask.reshape((1, join_mask.shape[0])
-                                  + (1,) * (new.ndim - 2))
-            return jnp.where(m, new.astype(old.dtype), old)
-
-        caches = jax.tree_util.tree_map(select, new_caches, caches)
-        return caches, tok, lengths, done, remaining, key, first
-    return join
-
-
-def jit_join(model: Model, cfg: ServeConfig, *, eos_id: int | None):
-    join = make_join(model, cfg, eos_id=eos_id)
-    return jax.jit(join, donate_argnums=(1, 2, 3, 4, 5))
 
 
 def paged_join_rows(joining: int) -> int:

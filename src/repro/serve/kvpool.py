@@ -1,8 +1,8 @@
 """Block-pool KV-cache memory manager (host side of the paged subsystem).
 
-The dense slot table (PR 1) gives every slot a ``[max_len]`` KV stripe, so
-memory is capped by ``slots x max_len`` whether or not those tokens exist —
-retired and short requests strand capacity.  The paper's co-design lesson
+A per-slot ``[max_len]`` KV stripe caps memory at ``slots x max_len``
+whether or not those tokens exist — retired and short requests strand
+capacity.  The paper's co-design lesson
 (§4.2 blocked placement, §5.1.2 command skipping) is to never spend
 commands or capacity on dead data, and PrIM-style studies put placement
 management, not compute, at the center of near-memory wins.  The paged
@@ -215,9 +215,9 @@ class KVPool:
         """Map pages for a ``tokens``-token request onto ``slot``.
 
         The whole worst case (prompt + budget) is reserved up front, so a
-        request can never run out of pages mid-segment; the win over dense
-        is that the reservation is ``ceil(tokens / page_size)`` pages, not
-        ``max_len``, and it is returned the moment the slot retires.
+        request can never run out of pages mid-segment; the reservation is
+        ``ceil(tokens / page_size)`` pages, not ``max_len``, and it is
+        returned the moment the slot retires.
         """
         if self._slot_pages[slot]:
             raise PageError(f"slot {slot} already holds pages"

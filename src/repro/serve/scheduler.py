@@ -3,20 +3,30 @@
 Serving architecture
 --------------------
 The scheduler owns a fixed-width **slot table**: ``cfg.batch`` decode slots
-that share one KV-cache allocation ([layers, B, max_len, ...]), one jitted
-prefill/join step and one jitted multi-token decode scan.  Host state per
-slot is just (request id, token budget, live length); device state is
-(next-token [B,1], per-slot cache_len [B], done flag [B], remaining budget
-[B], PRNG key, caches).
+whose KV lives in fixed-size pages of one pooled allocation
+(repro.serve.kvpool; [layers, pages, kv_heads, page_size, head_dim]), with
+one page-table row per slot, one jitted prefill/join step and one jitted
+multi-token decode scan.  Host state per slot is (request id, token
+budget, live length, mapped pages); device state is (next-token [B,1],
+per-slot cache_len [B], done flag [B], remaining budget [B], PRNG key,
+caches, page table).  SSM segments of a hybrid model keep their recurrent
+state per slot beside the pooled K/V (``Model.init_paged_caches``).
 
 Refill policy: requests queue in a ``deque``.  Between decode *segments*
 (``cfg.sync_every`` fused steps — the only host sync points), every retired
 slot is refilled from the queue head: the joining prompts are padded to one
-width, batch-prefilled in a single jitted call, and selected into the live
-state with a batch-axis ``where`` — occupied slots keep their caches
-bit-for-bit.  Mixed-length requests therefore share one jitted decode step
-at all times instead of padding to a fresh batch each round, and the same
-two compiled executables are reused across the whole drain (no retracing).
+width and prefilled in a single jitted call that computes only the joining
+rows (``make_paged_join``) and writes their K/V through the page table —
+occupied slots keep their state bit-for-bit.  Mixed-length requests
+therefore share one jitted decode step at all times instead of padding to a
+fresh batch each round, and the compiled executables are reused across the
+whole drain (one per join width and decode page-count bucket).
+
+Admission is on **free-page capacity**: a request joins when the pool can
+hold its prompt + budget (``ceil((plen + max_new) / page_size)`` pages),
+not merely when a slot index is free, and a retiring slot returns every
+page to the free list at the segment boundary, so short/early-EOS requests
+strand no capacity.
 
 Retirement: a slot retires when it emits EOS (the EOS token is kept) or
 exhausts its ``max_new`` budget.  Both conditions are evaluated *on device*
@@ -25,27 +35,15 @@ stops growing its cache and emits a PAD sentinel until the segment ends;
 the host mirrors the same rules when it drains the emitted block.
 
 Dead-block skipping (paper §5.1.2): commercial PIM kernels win by skipping
-commands for banks whose data is dead; the serving analogue is KV blocks
+commands for banks whose data is dead; the serving analogue is KV pages
 past a slot's live length.  Two levels: (1) per-slot lengths reach the
-decode-attention kernel, which skips every KV block past *that slot's*
-depth before any compute; (2) between segments the host knows the deepest
-live slot, so the engine re-jits the scan with a power-of-two ``kv_cap``
-and the attention op slices the cache to that bound — blocks past *every*
-slot's length are never launched at all.
+paged-attention kernel, which skips every page past *that slot's* depth;
+(2) between segments the host knows the deepest live slot's page count,
+so the device page table is sliced to a power-of-two bound on it
+(``_page_cap``, bucketed by ``_pow2_bucket`` so segments don't retrace) —
+pages past *every* slot's depth are never launched at all.
 
-Paged mode (``cfg.paged``, repro.serve.kvpool): the per-slot ``max_len``
-stripes are replaced by fixed-size pages in one pooled allocation.
-Admission is now on **free-page capacity** — a request joins when the pool
-can hold its prompt + budget (``ceil((plen + max_new) / page_size)``
-pages), not merely when a slot index is free — and a retiring slot returns
-every page to the free list at the segment boundary, so short/early-EOS
-requests stop stranding ``max_len``-sized stripes.  The dense ``kv_cap``
-bucketing becomes **page-count bucketing**: the device page table is
-sliced to a power-of-two bound on the deepest live slot's page count
-(same ``_pow2_bucket`` policy, so segments don't retrace), which prunes
-the paged-attention grid to live pages only.
-
-Prefix cache (``cfg.prefix_cache``, repro.serve.prefixcache, needs paged):
+Prefix cache (``cfg.prefix_cache``, repro.serve.prefixcache):
 admission first matches the prompt against a radix tree of page-aligned
 cached chunks; the matched pages are mapped into the joining slot via
 ``KVPool.share`` (refcounts go above 1) and only the **uncached suffix**
@@ -70,7 +68,7 @@ barrier — the lookahead scan stops at it, so sustained small-request
 load cannot starve a big prompt indefinitely (``max_skips=0``
 degenerates skip-ahead to FIFO).
 
-Chunked prefill (``cfg.prefill_chunk``, needs paged): a long prompt's
+Chunked prefill (``cfg.prefill_chunk``): a long prompt's
 uncached suffix no longer monopolizes one join — it is prefilled in
 page-aligned chunks of at most ``prefill_chunk`` tokens, one chunk per
 refill round, the slot sitting in the **PREFILLING** state in between:
@@ -105,7 +103,7 @@ further continuations are deferred to the next round (counted in
 piece of a round is always taken, so prefill always progresses — the
 budget trades prefill throughput for decode latency explicitly.
 
-Self-speculative decoding (``cfg.speculate_k``, needs paged; greedy and
+Self-speculative decoding (``cfg.speculate_k``; greedy and
 attention-only): decode segments run the draft-k verify loop from
 :func:`repro.serve.engine.make_decode_loop` — per step, k candidate
 tokens are drafted from the slot's own prompt+output ``history`` (the
@@ -128,7 +126,7 @@ scheduler's part of the contract:
   ``spec_stats()`` (acceptance rate = accepted drafts / proposed).
 
 Optimistic admission + page-level preemption
-(``cfg.admission_mode="optimistic"``, needs paged; attention-only):
+(``cfg.admission_mode="optimistic"``; attention-only):
 reservation admission maps the full worst case (prompt + max_new + k) at
 join time, so the pool runs far under its true capacity whenever outputs
 finish early — ``kv_util_mean`` is the gap.  Optimistic admission maps
@@ -204,9 +202,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .engine import (PAD_TOKEN, ServeConfig, jit_decode_loop, jit_join,
-                     jit_paged_decode_loop, jit_paged_join,
-                     jit_spec_decode_loop, paged_join_rows)
+from .engine import (PAD_TOKEN, ServeConfig, jit_paged_decode_loop,
+                     jit_paged_join, jit_spec_decode_loop, paged_join_rows)
 from .kvpool import KVPool, PageError
 from .overload import (CANCEL_REASONS, HEALTHY, RETRY_AFTER, STATES,
                        DegradationController, Watchdog, WatchdogStall,
@@ -215,6 +212,7 @@ from .prefixcache import PrefixCache
 # _pct moved to telemetry (the registry owns percentile math) but stays
 # importable from here — it has always been this module's public helper
 from .telemetry import MetricsRegistry, Tracer, _pct, phase  # noqa: F401
+from ..configs.base import BlockKind
 from ..kernels.paged_attn.ops import decode_launch
 from ..models.model_zoo import Model
 
@@ -267,28 +265,15 @@ class ContinuousBatcher:
             raise ValueError(
                 f"unknown admission mode {cfg.admission_mode!r} "
                 "(expected 'reserve' or 'optimistic')")
-        if cfg.admission_mode == "optimistic":
-            from ..configs.base import BlockKind
-            if not cfg.paged:
-                raise ValueError(
-                    "admission_mode='optimistic' requires paged=True "
-                    "(on-demand growth and preemption move pages through "
-                    "the pool)")
-            if any(s.kind is BlockKind.SSM
-                   for s in model.cfg.resolved_segments()):
-                raise ValueError(
-                    "optimistic admission is attention-only: preempting a "
-                    "hybrid SSM slot would discard a recurrent state that "
-                    "recompute-on-resume cannot rebuild from paged KV")
+        has_ssm = any(s.kind is BlockKind.SSM
+                      for s in model.cfg.resolved_segments())
+        if cfg.admission_mode == "optimistic" and has_ssm:
+            raise ValueError(
+                "optimistic admission is attention-only: preempting a "
+                "hybrid SSM slot would discard a recurrent state that "
+                "recompute-on-resume cannot rebuild from paged KV")
         self.chaos = chaos
-        if chaos is not None and not cfg.paged:
-            raise ValueError("chaos injection requires paged=True (its "
-                             "faults move pages through the pool)")
         if cfg.prefill_chunk is not None:
-            from ..configs.base import BlockKind
-            if not cfg.paged:
-                raise ValueError("prefill_chunk requires paged=True "
-                                 "(chunks resume through the page table)")
             if cfg.prefill_chunk <= 0:
                 raise ValueError("prefill_chunk must be positive")
             if cfg.prefill_chunk % cfg.page_size:
@@ -296,8 +281,7 @@ class ContinuousBatcher:
                     f"prefill_chunk {cfg.prefill_chunk} must be a multiple "
                     f"of page_size {cfg.page_size} (chunk boundaries must "
                     "never land inside a shared prefix page)")
-            if any(s.kind is BlockKind.SSM
-                   for s in model.cfg.resolved_segments()):
+            if has_ssm:
                 raise ValueError(
                     "prefill_chunk is attention-only: a hybrid SSM "
                     "model's recurrent state cannot resume mid-prompt "
@@ -307,11 +291,6 @@ class ContinuousBatcher:
             raise ValueError("prefill_round_tokens must be positive")
         self.spec_k = cfg.speculate_k or 0
         if cfg.speculate_k is not None:
-            from ..configs.base import BlockKind
-            if not cfg.paged:
-                raise ValueError(
-                    "speculate_k requires paged=True (the verify step "
-                    "writes and rolls back through the page table)")
             if cfg.speculate_k < 1:
                 raise ValueError("speculate_k must be >= 1")
             if cfg.speculate_ngram < 1:
@@ -320,36 +299,25 @@ class ContinuousBatcher:
                 raise ValueError(
                     "speculate_k is greedy-only for now: acceptance is "
                     "defined by exact argmax agreement (temperature 0)")
-            if any(s.kind is BlockKind.SSM
-                   for s in model.cfg.resolved_segments()):
+            if has_ssm:
                 raise ValueError(
                     "speculate_k is attention-only: a hybrid SSM model's "
                     "recurrent state advances k+1 tokens per verify and "
                     "cannot roll back past the acceptance point")
         b = cfg.batch
-        if cfg.paged:
-            self.pool = KVPool(cfg.pool_pages, cfg.page_size, b,
-                               max_pages=cfg.max_pages)
-            self.caches = model.init_paged_caches(
-                b, cfg.pool_pages, cfg.page_size, cfg.dtype)
-            self._join = jit_paged_join(model, cfg, eos_id=eos_id)
-        else:
-            self.pool = None
-            self.caches = model.init_caches(b, cfg.max_len, cfg.dtype)
-            self._join = jit_join(model, cfg, eos_id=eos_id)
+        self.pool = KVPool(cfg.pool_pages, cfg.page_size, b,
+                           max_pages=cfg.max_pages)
+        self.caches = model.init_paged_caches(
+            b, cfg.pool_pages, cfg.page_size, cfg.dtype)
+        self._join = jit_paged_join(model, cfg, eos_id=eos_id)
         self.prefix: PrefixCache | None = None
         if cfg.prefix_cache:
-            from ..configs.base import BlockKind
-            if not cfg.paged:
-                raise ValueError("prefix_cache requires paged=True "
-                                 "(shared pages live in the block pool)")
-            if any(s.kind is BlockKind.SSM
-                   for s in model.cfg.resolved_segments()):
+            if has_ssm:
                 raise ValueError(
                     "prefix_cache is attention-only: hybrid SSM models "
                     "cannot resume a recurrent state from cached pages")
             self.prefix = PrefixCache(self.pool)
-        if self.telemetry is not None and self.pool is not None:
+        if self.telemetry is not None:
             # pool-partition gauge: every allocator mutation lands one
             # counter sample in the trace (and the current-state gauges)
             self.pool.gauge_cb = self._on_pool_gauge
@@ -520,11 +488,9 @@ class ContinuousBatcher:
         tr, fl = self.telemetry, self.flight
         if tr is None and fl is None:
             return
-        pages = (len(self.pool.slot_pages(slot))
-                 if self.pool is not None and slot is not None else 0)
-        free = self.pool.free_pages if self.pool is not None else 0
+        pages = len(self.pool.slot_pages(slot)) if slot is not None else 0
         pages_held = attrs.pop("pages_held", pages)
-        pool_free = attrs.pop("pool_free", free)
+        pool_free = attrs.pop("pool_free", self.pool.free_pages)
         if tr is not None:
             tr.event(kind, rid, round=self.round, slot=slot,
                      pages_held=pages_held, pool_free=pool_free, **attrs)
@@ -615,31 +581,17 @@ class ContinuousBatcher:
         # the wrong executable
         keyid = (steps, cap, bool(self._spec_live()))
         if keyid not in self._loops:
-            if self._spec_live():
-                self._loops[keyid] = jit_spec_decode_loop(
-                    self.model, self.cfg, steps=steps, eos_id=self.eos)
-            elif self.cfg.paged:
-                # cap shapes the page-table slice; the jit keys on it
-                self._loops[keyid] = jit_paged_decode_loop(
-                    self.model, self.cfg, steps=steps, eos_id=self.eos)
-            else:
-                self._loops[keyid] = jit_decode_loop(
-                    self.model, self.cfg, steps=steps, eos_id=self.eos,
-                    kv_cap=cap)
+            # cap shapes the page-table slice; the jit keys on it
+            jit_loop = (jit_spec_decode_loop if self._spec_live()
+                        else jit_paged_decode_loop)
+            self._loops[keyid] = jit_loop(
+                self.model, self.cfg, steps=steps, eos_id=self.eos)
         return self._loops[keyid]
-
-    def _kv_cap(self, steps: int) -> int | None:
-        live = [self.slot_len[i] for i, r in enumerate(self.slot_rid)
-                if r is not None]
-        if not live:
-            return None
-        cap = _pow2_bucket(max(live) + steps, hi=self.cfg.max_len)
-        return None if cap >= self.cfg.max_len else cap
 
     def _page_cap(self) -> int:
         """Power-of-two bound on the deepest live slot's *allocated* page
-        count (allocation covers prompt + budget, so a segment can never
-        outgrow it) — the paged analogue of ``_kv_cap``."""
+        count (allocation covers every token a segment can write, so a
+        segment can never outgrow it)."""
         live = [len(self.pool.slot_pages(i))
                 for i, r in enumerate(self.slot_rid) if r is not None]
         if not live:
@@ -669,12 +621,6 @@ class ContinuousBatcher:
         """
         if not self.queue:
             return None
-        if self.pool is None:
-            rid, p = self.queue.popleft()
-            self.admit_order.append(rid)
-            self._note_admitted(rid)
-            self._trace("ADMIT", rid, slot=slot, prompt_tokens=len(p))
-            return rid, p, 0
         # SHEDDING freezes optimistic slot growth at the source: new
         # admissions revert to worst-case reservation, so they can never
         # demand on-demand pages (and thus preemptions) later — already
@@ -765,8 +711,6 @@ class ContinuousBatcher:
         """Return ``slot``'s pages; registered prefix pages whose refcount
         hits zero park in the evictable cached state, everything else goes
         straight back to the free list."""
-        if self.pool is None:
-            return
         cacheable = frozenset()
         if self.prefix is not None:
             cacheable = self.prefix.registered_pages(
@@ -879,7 +823,7 @@ class ContinuousBatcher:
         Highest-priority slots grow first, so pressure evicts in policy
         order; a slot picked as its own victim simply stops (its demand
         left with it)."""
-        if self.pool is None or self.cfg.admission_mode != "optimistic":
+        if self.cfg.admission_mode != "optimistic":
             return
         adv = steps * (self._spec_live() + 1)
         order = sorted(
@@ -938,8 +882,7 @@ class ContinuousBatcher:
         rid = self.slot_rid[slot]
         if rid is None:
             raise RuntimeError(f"cancel of empty slot {slot}")
-        pages = (len(self.pool.slot_pages(slot))
-                 if self.pool is not None else 0)
+        pages = len(self.pool.slot_pages(slot))
         if self.prefix is not None and not self.slot_pending[slot]:
             # like a preemption: full pages of committed KV are real and
             # immutable — register them so the cache keeps the benefit
@@ -1061,7 +1004,7 @@ class ContinuousBatcher:
         ctl = self.overload
         slo = self.slo_stats()
         burn = max(slo["burn_rate_ttft"], slo["burn_rate_tpot"])
-        pressure = self.pool.pressure() if self.pool is not None else 0.0
+        pressure = self.pool.pressure()
         prev = ctl.state
         state = ctl.observe(burn=burn, pressure=pressure,
                             queue_depth=len(self.queue),
@@ -1156,8 +1099,7 @@ class ContinuousBatcher:
             width = _pow2_bucket(
                 max(len(piece) for _, _, piece, _, _ in take),
                 lo=8, hi=self.cfg.max_len)
-            rows = (paged_join_rows(len(take)) if self.pool is not None
-                    else self.cfg.batch)
+            rows = paged_join_rows(len(take))
             self.metrics.inc("join.rows_computed", rows)
             span.set_metadata(
                 rows_computed=rows, width=width,
@@ -1228,7 +1170,7 @@ class ContinuousBatcher:
 
     def _join_pieces(self, take: list, width: int, max_new: int) -> None:
         """Prefill ``take`` at ``width`` in one join call (full-batch
-        arrays; the paged join computes only the rows in ``take``) and
+        arrays; the join computes only the rows in ``take``) and
         commit each completed prompt's first token."""
         t0 = time.perf_counter()
         b = self.cfg.batch
@@ -1259,16 +1201,13 @@ class ContinuousBatcher:
                 prior = len(self.outputs.get(rid, ()))
                 if prior:
                     budgets[slot] = max(1, max_new - prior)
-        join_args = (self.params, self.caches, self.tok, self.lengths,
-                     self.done, self.remaining, jnp.asarray(join_mask),
-                     jnp.asarray(prompts), jnp.asarray(plens),
-                     jnp.asarray(budgets), self.key)
-        if self.pool is not None:
-            join_args += (jnp.asarray(self.pool.table),
-                          jnp.asarray(prefix_lens),
-                          jnp.asarray(commit_mask))
         (self.caches, self.tok, self.lengths, self.done, self.remaining,
-         self.key, first) = self._join(*join_args)
+         self.key, first) = self._join(
+            self.params, self.caches, self.tok, self.lengths, self.done,
+            self.remaining, jnp.asarray(join_mask), jnp.asarray(prompts),
+            jnp.asarray(plens), jnp.asarray(budgets), self.key,
+            jnp.asarray(self.pool.table), jnp.asarray(prefix_lens),
+            jnp.asarray(commit_mask))
         first = np.asarray(first)
         now = time.perf_counter()
         for slot, rid, piece, depth, commit in take:
@@ -1449,8 +1388,7 @@ class ContinuousBatcher:
                     f"{max_new}"
                     + (f" + speculation window {window}" if window else "")
                     + f" exceeds max_len {self.cfg.max_len}")
-            if (self.pool is not None
-                    and self.pool.pages_for(len(prompt) + max_new + window)
+            if (self.pool.pages_for(len(prompt) + max_new + window)
                     > min(self.pool.n_pages, self.pool.max_pages)):
                 raise ValueError(
                     f"request {rid}: needs "
@@ -1473,6 +1411,10 @@ class ContinuousBatcher:
             # debugging bundle
             self._dump_flight(err)
             raise
+        # drained: pages a chaos injector still holds go back to the free
+        # list, so a finished run leaves the whole pool to the next one
+        if self.pool.held_pages > 0:
+            self.pool.release_held()
         return self.results
 
     def _round(self, max_new: int, steps: int) -> bool:
@@ -1514,12 +1456,9 @@ class ContinuousBatcher:
             if rows:
                 live, mapped, _ = self._sample_kv()
                 span.set_metadata(live_tokens=live, mapped_tokens=mapped)
-                if self.pool is not None:
-                    cap = self._page_cap()
-                    walk = self._decode_pages(cap)
-                    pages = jnp.asarray(self.pool.table[:, :cap])
-                else:
-                    cap = self._kv_cap(steps)
+                cap = self._page_cap()
+                walk = self._decode_pages(cap)
+                pages = jnp.asarray(self.pool.table[:, :cap])
         if not rows:
             return True
         with phase("decode-segment", tr, self.round, **walk):
@@ -1534,16 +1473,11 @@ class ContinuousBatcher:
                 # read-only and the next join writes prompts into this
                 # mirror
                 self.history = np.array(hist)
-            elif self.pool is not None:
-                ((self.tok, self.caches, self.lengths, self.done,
-                  self.remaining, self.key), emitted) = loop(
-                    self.params, self.tok, self.caches, self.lengths,
-                    self.done, self.remaining, self.key, pages)
             else:
                 ((self.tok, self.caches, self.lengths, self.done,
                   self.remaining, self.key), emitted) = loop(
                     self.params, self.tok, self.caches, self.lengths,
-                    self.done, self.remaining, self.key)
+                    self.done, self.remaining, self.key, pages)
             emitted = np.asarray(emitted)
         with phase("collect", tr, self.round):
             self._collect(emitted)
@@ -1603,7 +1537,7 @@ class ContinuousBatcher:
                 "slot_prior": list(self.slot_prior),
                 "slot_max_tokens": list(self.slot_max_tokens),
                 "pending_tokens": [len(p) for p in self.slot_pending]},
-            "pool": self.pool.snapshot() if self.pool is not None else None,
+            "pool": self.pool.snapshot(),
             "queue": [[rid, len(p)] for rid, p in self.queue],
             "preempt_events": list(self.preempt_events),
             "metrics": self.metrics.snapshot(),
@@ -1621,15 +1555,10 @@ class ContinuousBatcher:
     # ------------------------------------------------------------------
     def _sample_kv(self) -> tuple[int, int, int]:
         """Record and return (live tokens, allocated token capacity, live
-        slots) at a segment boundary.  Dense allocates ``batch * max_len``
-        whether or not slots are live; paged allocates only the mapped
-        pages."""
+        slots) at a segment boundary; the capacity is the mapped pages'."""
         live = [i for i, r in enumerate(self.slot_rid) if r is not None]
         live_tokens = sum(self.slot_len[i] for i in live)
-        if self.pool is not None:
-            alloc = self.pool.used_pages * self.pool.page_size
-        else:
-            alloc = self.cfg.batch * self.cfg.max_len
+        alloc = self.pool.used_pages * self.pool.page_size
         sample = (live_tokens, alloc, len(live))
         self.kv_samples.append(sample)
         return sample
@@ -1690,7 +1619,7 @@ class ContinuousBatcher:
         # live pool, so a gauge from a previous pool geometry can never
         # survive into the next wave's snapshot()
         self.metrics.clear_gauges("pool.")
-        if self.pool is not None and self.pool.gauge_cb is not None:
+        if self.pool.gauge_cb is not None:
             self.pool._notify()
         self._slo_classes.clear()
         self.kv_samples = []
@@ -1880,8 +1809,7 @@ class ContinuousBatcher:
                 "hit_rate": self.prefill_skipped / total if total else 0.0,
                 "admits": self.prefix_admits,
                 "hits": self.prefix_hits,
-                "cached_pages": (self.pool.cached_pages
-                                 if self.pool is not None else 0),
+                "cached_pages": self.pool.cached_pages,
                 "radix_entries": (self.prefix.n_entries
                                   if self.prefix is not None else 0),
                 "evicted_pages": (self.prefix.evicted_pages

@@ -300,8 +300,7 @@ class Tracer:
               pool_free: int = 0, t: float | None = None, **attrs) -> None:
         """One typed lifecycle/fault event.  ``rid=None`` marks a
         scheduler-global event (chaos faults); ``slot=None`` marks a
-        queue-side event (SUBMIT, or ADMIT in dense mode where there is
-        no pool)."""
+        queue-side event (SUBMIT)."""
         e = {"t": self._clock() if t is None else t, "kind": kind,
              "rid": rid, "round": round, "slot": slot,
              "pages_held": pages_held, "pool_free": pool_free}

@@ -38,7 +38,7 @@ def setup():
 
 
 BASE = dict(max_len=96, batch=6, dtype=jnp.float32, sync_every=4,
-            paged=True, page_size=8, total_pages=10,
+            page_size=8, total_pages=10,
             admission_mode="optimistic")
 
 
@@ -116,7 +116,7 @@ def test_explain_spec_run_carves_verify_overhead(setup):
     cfg, model, params = setup
     b = Batcher(model, params,
                 ServeConfig(max_len=96, batch=4, dtype=jnp.float32,
-                            sync_every=4, paged=True, page_size=8,
+                            sync_every=4, page_size=8,
                             speculate_k=3, telemetry=True))
     tok = int(np.random.default_rng(0).integers(0, cfg.vocab))
     for rid in range(3):

@@ -42,7 +42,7 @@ def test_kernel_phase_on_cpu(cs, plan):
 
 def test_serve_phase_on_cpu(cs, plan, capsys):
     outs = cs.serve_phase(plan, interpret=True, on_tpu=False)
-    assert set(outs) == {"dense", "paged", "paged+spec2", "paged/xla"}
+    assert set(outs) == {"paged", "paged+spec2", "paged/xla"}
     assert outs["paged"]["routes"].keys() == {"decode.kernel",
                                               "prefill.kernel"}
     assert outs["paged/xla"]["routes"].keys() == {"decode.xla",

@@ -42,7 +42,7 @@ def _setup(arch):
     model = Model(cfg)
     params = pm.unwrap(model.init(jax.random.key(0)))
     scfg = ServeConfig(max_len=MAX_LEN, batch=B, dtype=jnp.float32,
-                       paged=True, page_size=PS,
+                       page_size=PS,
                        total_pages=B * MAX_LEN // PS + SPARE)
     one_row = jax.jit(lambda toks, caches, table, last, depth:
                       model.prefill_paged(

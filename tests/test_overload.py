@@ -41,7 +41,7 @@ def setup():
 
 
 BASE = dict(max_len=96, batch=6, dtype=jnp.float32, sync_every=4,
-            paged=True, page_size=8, total_pages=24,
+            page_size=8, total_pages=24,
             admission_mode="optimistic")
 
 

@@ -32,7 +32,7 @@ def setup():
 
 
 BASE = dict(max_len=96, batch=6, dtype=jnp.float32, sync_every=4,
-            paged=True, page_size=8, admission_mode="optimistic")
+            page_size=8, admission_mode="optimistic")
 
 
 def test_stress_overload_traffic_invariants(setup):

@@ -34,7 +34,7 @@ from repro.serve.telemetry import Tracer
 PHASES = ("chaos", "sweep", "admit", "join", "pages", "decode-segment",
           "collect")
 SERVE = dict(max_len=96, batch=6, dtype=jnp.float32, sync_every=4,
-             paged=True, page_size=8, total_pages=10, prefill_chunk=8,
+             page_size=8, total_pages=10, prefill_chunk=8,
              admission_mode="optimistic")
 MAX_NEW = 10
 

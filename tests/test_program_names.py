@@ -49,7 +49,7 @@ def batcher():
         params = pm.unwrap(model.init(jax.random.key(0)))
         return Batcher(model, params, ServeConfig(
             max_len=64, batch=4, dtype=jnp.float32, sync_every=2,
-            paged=True, page_size=8, **kw))
+            page_size=8, **kw))
     return make
 
 

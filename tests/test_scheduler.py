@@ -82,7 +82,7 @@ def test_skip_ahead_improves_occupancy_mixed_sizes(setup):
     # small first so the pool is part-full when the big head blocks
     requests = [(0, smalls[0]), (1, big), (2, smalls[1]), (3, smalls[2])]
     base = dict(max_len=64, batch=3, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8, total_pages=6)
+                page_size=8, total_pages=6)
 
     def run(admission):
         b = Batcher(model, params,
@@ -114,7 +114,7 @@ def test_skip_ahead_lookahead_is_bounded(setup):
     big = rng.integers(0, cfg.vocab, size=30).tolist()
     small = rng.integers(0, cfg.vocab, size=4).tolist()
     base = dict(max_len=64, batch=2, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8, total_pages=6)
+                page_size=8, total_pages=6)
 
     def peak(lookahead):
         b = Batcher(model, params,
@@ -140,7 +140,7 @@ def _aging_batcher(model, params, max_skips):
     """Pool staged so a big head blocks while smalls fit: 14 pages, an
     occupier slot pinning 8, the big request needing 7 > 6 free."""
     scfg = ServeConfig(max_len=64, batch=5, dtype=jnp.float32,
-                      paged=True, page_size=8, total_pages=14,
+                      page_size=8, total_pages=14,
                       admission="skip-ahead", admission_max_skips=max_skips)
     b = Batcher(model, params, scfg)
     b.pool.reserve(4, 64)          # occupier: 8 pages off the free list
@@ -191,7 +191,7 @@ def test_skip_ahead_aging_full_drain_parity(setup):
     smalls = [rng.integers(0, cfg.vocab, size=4).tolist() for _ in range(3)]
     requests = [(0, smalls[0]), (1, big), (2, smalls[1]), (3, smalls[2])]
     base = dict(max_len=64, batch=3, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8, total_pages=6,
+                page_size=8, total_pages=6,
                 admission="skip-ahead")
 
     def run(max_skips):

@@ -26,7 +26,7 @@ def setup():
 
 
 BASE = dict(max_len=96, batch=3, dtype=jnp.float32, sync_every=4,
-            paged=True, page_size=8, total_pages=36)
+            page_size=8, total_pages=36)
 
 
 def _run(model, params, requests, max_new=10, eos_id=None, **kw):
@@ -141,14 +141,11 @@ def test_chunked_kernel_route_matches_xla(setup):
 
 
 def test_prefill_chunk_validation(setup):
-    """Misconfigured chunking is rejected up front: non-paged engines,
-    non-positive sizes, and chunks that straddle page boundaries."""
+    """Misconfigured chunking is rejected up front: non-positive sizes
+    and chunks that straddle page boundaries."""
     cfg, model, params = setup
-    with pytest.raises(ValueError, match="paged"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2,
-                                           prefill_chunk=16))
     for bad in (0, -8, 12):     # 12 % page_size(8) != 0
         with pytest.raises(ValueError, match="prefill_chunk"):
             Batcher(model, params,
-                    ServeConfig(max_len=32, batch=2, paged=True,
+                    ServeConfig(max_len=32, batch=2,
                                 page_size=8, prefill_chunk=bad))

@@ -1,7 +1,7 @@
-"""Decode-loop parity: the fused device-resident scan (slot scheduler,
-per-slot lengths, device sampling) must produce token-for-token identical
-output to the step-by-step reference loop — greedy, mixed prompt lengths,
-EOS mid-batch, and across continuous-batching refills."""
+"""Decode loop: on-device sampling in the fused device-resident scan, and
+the decode_attn kernel's per-slot lengths and grid pruning.  The scan's
+greedy parity with the step-by-step reference loop (mixed prompt lengths,
+EOS mid-batch, refills, kernel route) is in test_serve_paged.py."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -11,7 +11,6 @@ from repro.configs import get_config
 from repro.models import param as pm
 from repro.models.model_zoo import Model
 from repro.serve.engine import ServeConfig
-from repro.serve.reference import reference_decode
 from repro.serve.scheduler import Batcher
 
 
@@ -31,65 +30,6 @@ def _engine_run(model, params, scfg, requests, max_new, eos_id=None):
     for rid, p in requests:
         b.submit(rid, p)
     return b.run(max_new=max_new)
-
-
-def test_scan_parity_greedy_mixed_lengths(setup):
-    """Fused scan == per-token reference, bit-exact token ids."""
-    cfg, model, params, requests = setup
-    scfg = ServeConfig(max_len=64, batch=4, dtype=jnp.float32, sync_every=4)
-    ref = reference_decode(model, params, scfg, requests, max_new=12)
-    got = _engine_run(model, params, scfg, requests, max_new=12)
-    for rid, _ in requests:
-        assert got[rid] == ref[rid], (rid, got[rid], ref[rid])
-        assert len(got[rid]) == 12
-
-
-def test_scan_parity_across_refills(setup):
-    """More requests than slots: per-request outputs are independent of the
-    slot schedule (per-slot lengths isolate the rows)."""
-    cfg, model, params, _ = setup
-    rng = np.random.default_rng(7)
-    requests = [(i, rng.integers(0, cfg.vocab,
-                                 size=int(rng.integers(3, 12))).tolist())
-                for i in range(7)]
-    scfg = ServeConfig(max_len=64, batch=3, dtype=jnp.float32, sync_every=4)
-    ref = reference_decode(model, params, scfg, requests, max_new=10)
-    got = _engine_run(model, params, scfg, requests, max_new=10)
-    assert set(got) == {rid for rid, _ in requests}
-    for rid, _ in requests:
-        assert got[rid] == ref[rid], (rid, got[rid], ref[rid])
-
-
-def test_eos_mid_batch_retires_slot(setup):
-    """Pick a token one request emits mid-stream as EOS: that slot retires
-    early (EOS kept), the others run to budget — identical to reference."""
-    cfg, model, params, requests = setup
-    scfg = ServeConfig(max_len=64, batch=4, dtype=jnp.float32, sync_every=4)
-    free = reference_decode(model, params, scfg, requests, max_new=12)
-    eos = free[requests[0][0]][4]     # token request 0 emits at step 4
-    ref = reference_decode(model, params, scfg, requests, max_new=12,
-                           eos_id=eos)
-    got = _engine_run(model, params, scfg, requests, max_new=12, eos_id=eos)
-    assert any(len(v) < 12 for v in ref.values())          # actually mid-batch
-    for rid, _ in requests:
-        assert got[rid] == ref[rid], (rid, got[rid], ref[rid])
-        if ref[rid][-1] == eos or len(ref[rid]) < 12:
-            assert got[rid][-1] == eos                     # EOS is kept
-
-
-def test_kernel_route_matches_xla(setup):
-    """Routing decode attention through the Pallas kernel (interpret on
-    CPU) changes nothing about the sampled ids."""
-    cfg, model, params, requests = setup
-    base = dict(max_len=64, batch=4, dtype=jnp.float32, sync_every=4)
-    got_x = _engine_run(model, params,
-                        ServeConfig(**base, attn_mode="xla"),
-                        requests, max_new=8)
-    got_k = _engine_run(model, params,
-                        ServeConfig(**base, attn_mode="kernel"),
-                        requests, max_new=8)
-    for rid, _ in requests:
-        assert got_x[rid] == got_k[rid], (rid, got_x[rid], got_k[rid])
 
 
 def test_temperature_sampling_runs(setup):

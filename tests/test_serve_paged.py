@@ -2,8 +2,9 @@
 pages, page-count bucketing, free-page admission) must produce
 token-for-token identical greedy output to the dense step-by-step
 reference — mixed prompt lengths, EOS mid-batch, refills, and a pool
-smaller than the dense slot table — while reclaiming every retired
-slot's pages."""
+smaller than batch * max_len — while reclaiming every retired slot's
+pages.  The parity tests run at two page sizes: 8 (several pages per
+request) and 16, the serving default."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -35,12 +36,17 @@ def _run(model, params, scfg, requests, max_new, eos_id=None):
     return b.run(max_new=max_new), b
 
 
-def test_paged_parity_greedy_mixed_lengths(setup):
+PAGE_SIZES = pytest.mark.parametrize("page_size", [8, 16],
+                                     ids=["ps8", "ps16"])
+
+
+@PAGE_SIZES
+def test_paged_parity_greedy_mixed_lengths(setup, page_size):
     """Paged engine == dense per-token reference, bit-exact token ids,
     and the drained pool is fully free again."""
     cfg, model, params, requests = setup
     scfg = ServeConfig(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
-                       paged=True, page_size=8)
+                       page_size=page_size)
     ref = reference_decode(model, params, scfg, requests, max_new=12)
     got, b = _run(model, params, scfg, requests, max_new=12)
     for rid, _ in requests:
@@ -51,17 +57,18 @@ def test_paged_parity_greedy_mixed_lengths(setup):
     b.pool.check()
 
 
-def test_paged_parity_across_refills(setup):
+@PAGE_SIZES
+def test_paged_parity_across_refills(setup, page_size):
     """More requests than slots: retirements free pages between segments
     and the refills join through the page table — outputs independent of
-    the slot schedule."""
+    the slot schedule (per-slot lengths isolate the rows)."""
     cfg, model, params, _ = setup
     rng = np.random.default_rng(7)
     requests = [(i, rng.integers(0, cfg.vocab,
                                  size=int(rng.integers(3, 12))).tolist())
                 for i in range(7)]
     scfg = ServeConfig(max_len=64, batch=3, dtype=jnp.float32, sync_every=4,
-                       paged=True, page_size=8)
+                       page_size=page_size)
     ref = reference_decode(model, params, scfg, requests, max_new=10)
     got, b = _run(model, params, scfg, requests, max_new=10)
     assert set(got) == {rid for rid, _ in requests}
@@ -73,7 +80,7 @@ def test_paged_parity_across_refills(setup):
 def test_paged_pool_smaller_than_dense(setup):
     """A pool with fewer tokens than batch * max_len still drains with
     identical outputs: admission blocks on free pages, retirements
-    re-admit.  This is the capacity decoupling the dense layout can't do."""
+    re-admit: the pool's capacity is decoupled from the slot count."""
     cfg, model, params, _ = setup
     rng = np.random.default_rng(3)
     requests = [(i, rng.integers(0, cfg.vocab,
@@ -82,8 +89,8 @@ def test_paged_pool_smaller_than_dense(setup):
     base = dict(max_len=64, batch=3, dtype=jnp.float32, sync_every=4)
     ref = reference_decode(model, params, ServeConfig(**base), requests,
                            max_new=8)
-    # 6 pages x 8 tokens = 48 token capacity vs dense 3 x 64 = 192
-    scfg = ServeConfig(**base, paged=True, page_size=8, total_pages=6)
+    # 6 pages x 8 tokens = 48 token capacity vs 3 slots x 64 = 192
+    scfg = ServeConfig(**base, page_size=8, total_pages=6)
     got, b = _run(model, params, scfg, requests, max_new=8)
     for rid, _ in requests:
         assert got[rid] == ref[rid], (rid, got[rid], ref[rid])
@@ -92,12 +99,14 @@ def test_paged_pool_smaller_than_dense(setup):
     assert util["samples"] > 0 and util["peak_util"] > 0.5
 
 
-def test_paged_eos_mid_batch_frees_pages(setup):
-    """EOS retirement mid-batch returns the slot's pages at the segment
-    boundary and keeps parity with the reference."""
+@PAGE_SIZES
+def test_paged_eos_mid_batch_frees_pages(setup, page_size):
+    """EOS retirement mid-batch (EOS kept, the other slots run to budget)
+    returns the slot's pages at the segment boundary and keeps parity
+    with the reference."""
     cfg, model, params, requests = setup
     scfg = ServeConfig(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
-                       paged=True, page_size=8)
+                       page_size=page_size)
     free = reference_decode(model, params, scfg, requests, max_new=12)
     eos = free[requests[0][0]][4]
     ref = reference_decode(model, params, scfg, requests, max_new=12,
@@ -111,12 +120,13 @@ def test_paged_eos_mid_batch_frees_pages(setup):
     assert b.pool.free_pages == b.pool.n_pages
 
 
-def test_paged_kernel_route_matches_xla(setup):
+@PAGE_SIZES
+def test_paged_kernel_route_matches_xla(setup, page_size):
     """Routing paged decode attention through the Pallas page-table
     kernel (interpret on CPU) changes no sampled ids vs the XLA gather."""
     cfg, model, params, requests = setup
     base = dict(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8)
+                page_size=page_size)
     got_x, _ = _run(model, params, ServeConfig(**base, attn_mode="xla"),
                     requests, max_new=8)
     got_k, _ = _run(model, params, ServeConfig(**base, attn_mode="kernel"),
@@ -126,14 +136,14 @@ def test_paged_kernel_route_matches_xla(setup):
 
 
 def test_paged_matches_dense_engine(setup):
-    """Dense engine and paged engine agree with each other too (same
-    scheduler, different memory layout)."""
+    """The paged engine agrees with the model's dense caches, driven step
+    by step by the reference loop (same model, different memory
+    layout)."""
     cfg, model, params, requests = setup
-    base = dict(max_len=64, batch=4, dtype=jnp.float32, sync_every=4)
-    dense, _ = _run(model, params, ServeConfig(**base), requests, max_new=10)
-    paged, _ = _run(model, params,
-                    ServeConfig(**base, paged=True, page_size=16),
-                    requests, max_new=10)
+    scfg = ServeConfig(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
+                       page_size=16)
+    dense = reference_decode(model, params, scfg, requests, max_new=10)
+    paged, _ = _run(model, params, scfg, requests, max_new=10)
     for rid, _ in requests:
         assert dense[rid] == paged[rid]
 
@@ -142,7 +152,9 @@ def test_paged_ssm_hybrid_across_refills():
     """Hybrid SSM model (mamba2): the paged join must not clobber
     non-joining slots' recurrent SSM state when a retirement triggers a
     refill while other slots are mid-decode — SSM caches are per-slot
-    (not paged), so the join's batch-axis select protects them."""
+    (not paged), and the join scatters back only the joining rows.  The
+    oracle is the reference loop over the model's dense caches, SSM
+    state included."""
     cfg = get_config("mamba2-370m").reduced()
     model = Model(cfg)
     params = pm.unwrap(model.init(jax.random.key(0)))
@@ -157,11 +169,13 @@ def test_paged_ssm_hybrid_across_refills():
             b.submit(rid, p)
         return b.run(max_new=8)
 
-    base = dict(max_len=64, batch=2, dtype=jnp.float32, sync_every=4)
-    free = run(ServeConfig(**base))
+    scfg = ServeConfig(max_len=64, batch=2, dtype=jnp.float32, sync_every=4,
+                       page_size=8)
+    free = reference_decode(model, params, scfg, requests, max_new=8)
     eos = free[0][2]                   # retires slot 0 mid-stream
-    dense = run(ServeConfig(**base), eos=eos)
-    paged = run(ServeConfig(**base, paged=True, page_size=8), eos=eos)
+    dense = reference_decode(model, params, scfg, requests, max_new=8,
+                             eos_id=eos)
+    paged = run(scfg, eos=eos)
     assert any(len(v) < 8 for v in dense.values())       # refill happened
     for rid, _ in requests:
         assert dense[rid] == paged[rid], (rid, dense[rid], paged[rid])
@@ -203,7 +217,7 @@ def test_paged_rejects_oversized_request(setup):
     deadlocking admission."""
     cfg, model, params, _ = setup
     scfg = ServeConfig(max_len=64, batch=2, dtype=jnp.float32,
-                       paged=True, page_size=8, total_pages=4)   # 32 tokens
+                       page_size=8, total_pages=4)   # 32 tokens
     b = Batcher(model, params, scfg)
     b.submit(0, list(range(1, 30)))
     with pytest.raises(ValueError, match="pages"):

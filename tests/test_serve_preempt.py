@@ -36,7 +36,7 @@ def setup():
 
 
 BASE = dict(max_len=96, batch=6, dtype=jnp.float32, sync_every=4,
-            paged=True, page_size=8, total_pages=10,
+            page_size=8, total_pages=10,
             admission_mode="optimistic")
 
 
@@ -85,19 +85,13 @@ def test_unknown_admission_mode_rejected(setup):
                 ServeConfig(max_len=32, batch=2, admission_mode="eager"))
 
 
-def test_optimistic_requires_paged(setup):
-    cfg, model, params = setup
-    with pytest.raises(ValueError, match="paged"):
-        Batcher(model, params,
-                ServeConfig(max_len=32, batch=2,
-                            admission_mode="optimistic"))
-
-
-def test_chaos_requires_paged(setup):
-    cfg, model, params = setup
-    with pytest.raises(ValueError, match="chaos"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2),
-                chaos=ChaosInjector())
+@pytest.mark.parametrize("kw", [{}, {"admission_mode": "optimistic"}],
+                         ids=["plain", "optimistic"])
+def test_dense_layout_rejected(kw):
+    """The dense per-slot KV layout is gone: asking for it fails at
+    configuration time, whatever else the configuration asks for."""
+    with pytest.raises(ValueError, match="dense per-slot KV layout"):
+        ServeConfig(max_len=32, batch=2, paged=False, **kw)
 
 
 def test_optimistic_rejects_hybrid_ssm():
@@ -107,7 +101,7 @@ def test_optimistic_rejects_hybrid_ssm():
     model = Model(get_config("mamba2-370m").reduced())
     with pytest.raises(ValueError, match="attention-only"):
         Batcher(model, None,
-                ServeConfig(max_len=32, batch=2, paged=True,
+                ServeConfig(max_len=32, batch=2,
                             admission_mode="optimistic"))
 
 

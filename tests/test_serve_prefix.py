@@ -52,7 +52,7 @@ def test_prefix_parity_and_skipped_prefill(setup):
     (the shared pages mean most prompts prefill only their suffix)."""
     cfg, model, params, requests = setup
     base = dict(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8)
+                page_size=8)
     off, _ = _run(model, params, ServeConfig(**base), requests, max_new=12)
     on, b = _run(model, params, ServeConfig(**base, prefix_cache=True),
                  requests, max_new=12)
@@ -71,7 +71,7 @@ def test_prefix_parity_vs_dense_reference(setup):
     sharing composes with the paged engine, not just mirrors it."""
     cfg, model, params, requests = setup
     scfg = ServeConfig(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
-                       paged=True, page_size=8, prefix_cache=True)
+                       page_size=8, prefix_cache=True)
     ref = reference_decode(model, params, scfg, requests, max_new=10)
     got, b = _run(model, params, scfg, requests, max_new=10)
     for rid, _ in requests:
@@ -90,7 +90,7 @@ def test_prefix_refills_rehit_cache(setup):
         0, cfg.vocab, size=int(rng.integers(1, 6))).tolist())
         for i in range(7)]
     base = dict(max_len=64, batch=2, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8)
+                page_size=8)
     off, _ = _run(model, params, ServeConfig(**base), requests, max_new=8)
     on, b = _run(model, params, ServeConfig(**base, prefix_cache=True),
                  requests, max_new=8)
@@ -108,7 +108,7 @@ def test_prefix_eos_mid_batch(setup):
     with the cache-off engine is unchanged."""
     cfg, model, params, requests = setup
     base = dict(max_len=64, batch=4, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8)
+                page_size=8)
     free, _ = _run(model, params, ServeConfig(**base), requests, max_new=12)
     eos = free[requests[0][0]][4]
     off, _ = _run(model, params, ServeConfig(**base), requests, max_new=12,
@@ -133,7 +133,7 @@ def test_prefix_eviction_under_constrained_pool(setup):
         0, cfg.vocab, size=int(rng.integers(1, 5))).tolist())
         for i in range(6)]
     base = dict(max_len=64, batch=1, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8, total_pages=4)
+                page_size=8, total_pages=4)
     off, _ = _run(model, params, ServeConfig(**base), requests, max_new=6)
     on, b = _run(model, params, ServeConfig(**base, prefix_cache=True),
                  requests, max_new=6)
@@ -155,7 +155,7 @@ def test_prefix_same_round_hit(setup):
         0, cfg.vocab, size=int(rng.integers(1, 5))).tolist())
         for i in range(3)]
     base = dict(max_len=64, batch=3, dtype=jnp.float32, sync_every=4,
-                paged=True, page_size=8)
+                page_size=8)
     off, _ = _run(model, params, ServeConfig(**base), requests, max_new=8)
     on, b = _run(model, params, ServeConfig(**base, prefix_cache=True),
                  requests, max_new=8)
@@ -194,13 +194,10 @@ def test_prefix_mla_suffix_prefill():
 
 def test_prefix_requires_paged_and_rejects_ssm(setup):
     cfg, model, params, _ = setup
-    with pytest.raises(ValueError, match="paged"):
-        Batcher(model, params,
-                ServeConfig(max_len=64, batch=2, prefix_cache=True))
     mcfg = get_config("mamba2-370m").reduced()
     mmodel = Model(mcfg)
     mparams = pm.unwrap(mmodel.init(jax.random.key(0)))
     with pytest.raises(ValueError, match="SSM"):
         Batcher(mmodel, mparams,
-                ServeConfig(max_len=64, batch=2, paged=True, page_size=8,
+                ServeConfig(max_len=64, batch=2, page_size=8,
                             prefix_cache=True))

@@ -34,7 +34,7 @@ def setup():
 
 
 BASE = dict(max_len=96, batch=3, dtype=jnp.float32, sync_every=4,
-            paged=True, page_size=8, total_pages=36)
+            page_size=8, total_pages=36)
 
 
 def _run(model, params, requests, max_new=10, eos_id=None, **kw):
@@ -268,21 +268,18 @@ def test_reset_stats_isolates_measurement_waves(setup):
 
 def test_spec_validation(setup):
     cfg, model, params = setup
-    with pytest.raises(ValueError, match="paged"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2,
-                                           speculate_k=3))
     with pytest.raises(ValueError, match="speculate_k"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2, paged=True,
+        Batcher(model, params, ServeConfig(max_len=32, batch=2,
                                            speculate_k=0))
     with pytest.raises(ValueError, match="greedy"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2, paged=True,
+        Batcher(model, params, ServeConfig(max_len=32, batch=2,
                                            speculate_k=3, temperature=0.7))
     with pytest.raises(ValueError, match="speculate_ngram"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2, paged=True,
+        Batcher(model, params, ServeConfig(max_len=32, batch=2,
                                            speculate_k=3,
                                            speculate_ngram=0))
     with pytest.raises(ValueError, match="prefill_round_tokens"):
-        Batcher(model, params, ServeConfig(max_len=32, batch=2, paged=True,
+        Batcher(model, params, ServeConfig(max_len=32, batch=2,
                                            prefill_round_tokens=0))
 
 
@@ -292,7 +289,7 @@ def test_spec_rejects_hybrid_ssm():
     allocated, so no params are needed)."""
     model = Model(get_config("mamba2-370m").reduced())
     with pytest.raises(ValueError, match="attention-only"):
-        Batcher(model, None, ServeConfig(max_len=32, batch=2, paged=True,
+        Batcher(model, None, ServeConfig(max_len=32, batch=2,
                                          speculate_k=3))
 
 
