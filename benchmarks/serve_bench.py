@@ -741,17 +741,18 @@ def prefill_kernel_timing(arch: str = "qwen2-0.5b", *, b: int = 4,
     ref on one suffix-prefill shape — reported for trajectory only (the
     interpreter is expected to lose off-TPU; the kernel path is routed in
     on real backends)."""
-    from repro.kernels.paged_attn import (paged_prefill_attn_pallas,
+    from repro.kernels.paged_attn import (pack_pages,
+                                          paged_prefill_attn_pallas,
                                           paged_prefill_attn_ref)
     cfg = get_config(arch).reduced()
     hq, hkv = cfg.n_heads, cfg.kv_heads
     d = cfg.resolved_head_dim
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.standard_normal((b, lq, hq, d)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
-                    jnp.float32)
-    v = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
-                    jnp.float32)
+    k = pack_pages(jnp.asarray(
+        rng.standard_normal((1, pages, hkv, page_size, d)), jnp.float32))
+    v = pack_pages(jnp.asarray(
+        rng.standard_normal((1, pages, hkv, page_size, d)), jnp.float32))
     p_max = pages // b
     tbl = jnp.asarray(rng.permutation(pages)[:b * p_max]
                       .reshape(b, p_max).astype(np.int32))
@@ -826,17 +827,17 @@ def roofline_probe(arch: str = "qwen2-0.5b", *, b: int = 2, lq: int = 8,
     without an eager probe.  Returns the three ``op.kernel`` snapshot
     rows."""
     from repro.kernels.decode_attn import decode_attn_policy
-    from repro.kernels.paged_attn import (attn_telemetry, paged_attn,
-                                          paged_prefill_attn,
+    from repro.kernels.paged_attn import (attn_telemetry, pack_pages,
+                                          paged_attn, paged_prefill_attn,
                                           paged_verify_attn)
     cfg = get_config(arch).reduced()
     hq, hkv = cfg.n_heads, cfg.kv_heads
     d = cfg.resolved_head_dim
     rng = np.random.default_rng(0)
-    kp = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
-                     jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pages, hkv, page_size, d)),
-                     jnp.float32)
+    kp = pack_pages(jnp.asarray(
+        rng.standard_normal((1, pages, hkv, page_size, d)), jnp.float32))
+    vp = pack_pages(jnp.asarray(
+        rng.standard_normal((1, pages, hkv, page_size, d)), jnp.float32))
     p_max = pages // b
     tbl = jnp.asarray(rng.permutation(pages)[:b * p_max]
                       .reshape(b, p_max).astype(np.int32))

@@ -106,7 +106,8 @@ def check_kernels(cfg, plan: Plan, *, interpret: bool) -> None:
     table."""
     import jax.numpy as jnp
     import numpy as np
-    from repro.kernels.paged_attn import (paged_attn, paged_attn_ref,
+    from repro.kernels.paged_attn import (pack_pages, paged_attn,
+                                          paged_attn_ref,
                                           paged_prefill_attn_pallas,
                                           paged_prefill_attn_ref)
     from repro.kernels.paged_attn.kernel import GRID_ORDERS
@@ -119,14 +120,15 @@ def check_kernels(cfg, plan: Plan, *, interpret: bool) -> None:
     def normal(shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
 
-    k, v = normal((n, hkv, ps, d)), normal((n, hkv, ps, d))
+    # two layers' pools as stored; the ops read the second
+    k, v = (pack_pages(normal((2, n, hkv, ps, d))) for _ in range(2))
     table = jnp.asarray(rng.permutation(n).reshape(b, p_max)
                         .astype(np.int32))
     q = normal((b, hq, d))
     ln = jnp.asarray(rng.integers(1, plan.max_len + 1, size=b), jnp.int32)
-    ref = paged_attn_ref(q, k, v, table, ln)
+    ref = paged_attn_ref(q, k, v, table, ln, 1)
     for order in GRID_ORDERS:
-        out = paged_attn(q, k, v, table, ln, interpret=interpret,
+        out = paged_attn(q, k, v, table, ln, 1, interpret=interpret,
                          grid_order=order)
         err = _assert_close(f"decode/{order}", out, ref, KERNEL_TOL)
         _say(f"kernel decode grid_order={order}: max |err| {err:.3g}")
@@ -136,9 +138,9 @@ def check_kernels(cfg, plan: Plan, *, interpret: bool) -> None:
         q = normal((b, lq, hq, d))
         off = jnp.asarray(rng.integers(0, plan.max_len - lq + 1, size=b),
                           jnp.int32)
-        out = paged_prefill_attn_pallas(q, k, v, table, off, off + lq,
+        out = paged_prefill_attn_pallas(q, k, v, table, off, off + lq, 1,
                                         interpret=interpret)
-        ref = paged_prefill_attn_ref(q, k, v, table, off, off + lq)
+        ref = paged_prefill_attn_ref(q, k, v, table, off, off + lq, 1)
         err = _assert_close(f"{op}/Lq={lq}", out, ref, KERNEL_TOL)
         _say(f"kernel {op} Lq={lq}: max |err| {err:.3g}")
 

@@ -15,8 +15,9 @@ call's query depends on the last output, so no call is hoisted) and
 reports the mean time per call, the HBM bytes of the live K/V pages it
 needs, and that traffic's share of the v5e's 819 GB/s.
 ``--baseline-src DIR`` also times the ``paged_attn_kernel`` of another
-checkout's ``src`` (a kernel without ``pages_per_block``) on the same
-inputs.  Prints one JSON line per point and exits non-zero off a TPU.
+checkout's ``src`` (a kernel without ``pages_per_block``, reading one
+layer's ``[N, Hkv, page_size, D]`` pool and no layer operand) on the
+same pages.  Prints one JSON line per point and exits non-zero off a TPU.
 
     python scripts/decode_block_sweep.py [--ppb 1,4,8,16,32] [--reps 200]
         [--baseline-src DIR] [--seed 0]
@@ -38,7 +39,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "src"))
 
 from repro.kernels.paged_attn.kernel import (  # noqa: E402
-    default_pages_per_block, paged_attn_kernel)
+    default_pages_per_block, pack_pages, paged_attn_kernel)
 
 HBM_GBPS = 819.0             # TPU v5e, Google Cloud "TPU v5e" documentation
 PS = 16
@@ -72,8 +73,9 @@ def inputs(name: str, seed: int):
     key = jax.random.key(seed)
     kq, kk, kv = jax.random.split(key, 3)
     q = jax.random.normal(kq, (b, hkv, g, d), jnp.bfloat16)
-    k = jax.random.normal(kk, (n, hkv, PS, d), jnp.bfloat16)
-    v = jax.random.normal(kv, (n, hkv, PS, d), jnp.bfloat16)
+    # one layer's pool, stored as the kernel reads it
+    k = pack_pages(jax.random.normal(kk, (1, n, hkv, PS, d), jnp.bfloat16))
+    v = pack_pages(jax.random.normal(kv, (1, n, hkv, PS, d), jnp.bfloat16))
     live_pages = int(np.sum(-(-ln // PS)))
     need = live_pages * PS * hkv * d * 2 * 2 + 2 * b * hkv * g * d * 2
     return (q, k, v, jnp.asarray(tbl), jnp.asarray(ln, jnp.int32)), need
@@ -125,11 +127,16 @@ def main(argv=None) -> int:
             points.append(("baseline", None))
         for label, ppb in points:
             if label == "baseline":
-                def kern(*xs):
-                    return base(*xs, interpret=False)
+                def kern(q, k, v, tbl, ln):
+                    # the older kernel's unstacked, unpacked pool
+                    n, hkv = k.shape[1:3]
+                    return base(q, k[0].reshape(n, hkv, PS, -1),
+                                v[0].reshape(n, hkv, PS, -1), tbl, ln,
+                                interpret=False)
             else:
                 def kern(*xs, ppb=ppb):
-                    return paged_attn_kernel(*xs, interpret=False,
+                    return paged_attn_kernel(*xs, jnp.zeros((1,), jnp.int32),
+                                             interpret=False,
                                              pages_per_block=ppb)
             s = timed(kern, args, a.reps)
             print(json.dumps({

@@ -5,6 +5,7 @@ from .autotune import (Candidate, Geometry,  # noqa: F401
                        enumerate_candidates, entry_key, load_entries,
                        make_workload, prune, resolve_cache_path,
                        save_entries)
+from .kernel import pack_pages, page_shape  # noqa: F401
 from .ops import (PagedAttnTelemetry, amenability_reports,  # noqa: F401
                   attn_telemetry,
                   paged_attn, paged_attn_xla,

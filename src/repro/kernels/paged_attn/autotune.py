@@ -64,7 +64,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ...core.hwspec import DEFAULT_TPU, TpuSpec
-from .kernel import GRID_ORDERS, default_pages_per_block
+from .kernel import GRID_ORDERS, default_pages_per_block, pack_pages
 from .prefill_kernel import default_block_rows, vmem_bytes
 
 SCHEMA = 1
@@ -166,8 +166,11 @@ def make_workload(op: str, geom: Geometry, *, b: int = 2, lq: int = 8,
                          f"page_size > page_size + lq "
                          f"(pages={pages}, b={b}, ps={ps}, lq={lq})")
     rng = np.random.default_rng(seed)
-    kp = jnp.asarray(rng.standard_normal((pages, hkv, ps, d)), jnp.float32)
-    vp = jnp.asarray(rng.standard_normal((pages, hkv, ps, d)), jnp.float32)
+    # one layer's pool, stored as the kernels read it
+    kp = pack_pages(jnp.asarray(rng.standard_normal((1, pages, hkv, ps, d)),
+                                jnp.float32))
+    vp = pack_pages(jnp.asarray(rng.standard_normal((1, pages, hkv, ps, d)),
+                                jnp.float32))
     tbl = jnp.asarray(rng.permutation(pages)[:b * p_max]
                       .reshape(b, p_max).astype(np.int32))
     if op == "decode":

@@ -3,8 +3,11 @@
 Same regime as :mod:`repro.kernels.decode_attn` — one new token against a
 deep KV cache, memory-bound, accumulator staged in VMEM across the KV walk
 — but the cache is no longer a per-slot stripe: K/V pages live in one
-pooled head-major ``[n_pages, Hkv, page_size, D]`` allocation and each
-slot names its pages through a ``[B, max_pages]`` table.
+pooled head-major allocation stacked over the model's layers, ``[L,
+n_pages, Hkv, page_size / f, f * D]`` (:func:`page_shape`), and each slot
+names its pages through a ``[B, max_pages]`` table.  The call reads layer
+``layer`` of the stack, a scalar-prefetch operand, so the serving layer
+loop never slices a layer out of the pool.
 
 Block walk.  The grid is ``(B, Hkv, cdiv(P, pages_per_block))``: each
 step attends over a *block* of ``pages_per_block`` consecutive table
@@ -15,7 +18,7 @@ and one 16-token page fills 16 of a vreg's 128 lanes, so walking pages
 one per step made the step count, not the bytes, set the kernel's time.
 
 The K/V pools stay in HBM (``memory_space=pl.ANY``).  A live step gathers
-its block with one async copy per page, ``pool[table[b, p], h]`` →
+its block with one async copy per page, ``pool[layer, table[b, p], h]`` →
 ``buf[slot, p - j*ppb]``, into a double-buffered ``[2, ppb, rows, lanes]``
 VMEM buffer (a page's ``[page_size, D]`` tile, or its lane-packed view:
 below), and it starts the copies of the *next live* block —
@@ -40,11 +43,13 @@ Command skipping (§5.1.2) lands at three levels:
   the engine's page-count bucketing) — pages past *every* slot's length
   are never launched, and no table column at or past that width is read.
 
-Pages are stored head-major so that one page copy, ``pool[page, h]``, is
-a ``[page_size, D]`` tile with the page's token rows on sublanes and D on
-lanes.  Mosaic copies only whole lane rows of a lane-tiled pool, so where
-``D`` divides 128 the kernel reads the pool as ``[N, Hkv, page_size / f,
-f * D]`` with ``f = 128 / D`` tokens to a row (:func:`lane_pack`).  Query
+Pages are stored head-major so that one page copy, ``pool[layer, page,
+h]``, is a ``[page_size, D]`` tile with the page's token rows on sublanes
+and D on lanes.  Mosaic copies only whole lane rows of a lane-tiled pool,
+so where ``D`` divides 128 the pool is stored as ``[.., page_size / f, f *
+D]`` with ``f = 128 / D`` tokens to a row (:func:`lane_pack`): row ``i``
+of a page holds its tokens ``f i .. f i + f - 1``, which is the row-major
+``[page_size, D]`` page reshaped, so the kernel copies it as stored.  Query
 row ``c * Gp + g`` (``Gp``: G rounded up to 8) then holds ``q[g]`` in lane
 group ``c`` and zeros elsewhere, scores only that group's keys, and keeps
 its own flash accumulator; the last step merges the ``f`` partial
@@ -99,6 +104,31 @@ def lane_pack(ps: int, d: int) -> int:
     return f if ps % f == 0 else 1
 
 
+def page_shape(ps: int, d: int) -> tuple[int, int]:
+    """(rows, lanes) of one stored (page, head) tile of ``ps`` tokens of
+    width ``d``: ``f = lane_pack(ps, d)`` tokens to a row."""
+    f = lane_pack(ps, d)
+    return ps // f, f * d
+
+
+def pack_pages(pages: jnp.ndarray) -> jnp.ndarray:
+    """Token-row pages ``[..., ps, D]`` as the pool stores them, ``[...,
+    ps / f, f * D]``: a row-major reshape, so the contents do not move."""
+    *lead, ps, d = pages.shape
+    return pages.reshape(*lead, *page_shape(ps, d))
+
+
+def page_geometry(pool: jnp.ndarray, d: int) -> tuple[int, int]:
+    """``(page_size, f)`` of a stored GQA pool ``[..., ps / f, f * D]`` at
+    head_dim ``d``; a pool not stored as :func:`page_shape` says raises."""
+    rows, lanes = int(pool.shape[-2]), int(pool.shape[-1])
+    f = lanes // d
+    if lanes % d or page_shape(rows * f, d) != (rows, lanes):
+        raise ValueError(f"pool pages {(rows, lanes)} are not a stored "
+                         f"page of head_dim {d} (see page_shape)")
+    return rows * f, f
+
+
 def _axes(grid_order: str) -> tuple[int, int]:
     """(batch_axis, head_axis) grid positions for ``grid_order``."""
     if grid_order not in GRID_ORDERS:
@@ -112,7 +142,7 @@ def _make_kernel(*, ps: int, ppb: int, p_cap: int, f: int, gp: int, d: int,
     bk = ppb * ps                    # keys per block
     br = bk // f                     # buffer rows per block
 
-    def kernel(tbl_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+    def kernel(tbl_ref, len_ref, lay_ref, q_ref, k_hbm, v_hbm, o_ref,
                k_buf, v_buf, k_sem, v_sem, state, m_ref, l_ref, acc_ref):
         i0, i1, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
         n1 = pl.num_programs(1)
@@ -129,12 +159,13 @@ def _make_kernel(*, ps: int, ppb: int, p_cap: int, f: int, gp: int, d: int,
             (slot ``b``, head ``hh``) into buffer half ``slot``: one per
             live page of the block, none past the slot's length."""
             cnt = jnp.minimum((n_live(b) + ps - 1) // ps - jj * ppb, ppb)
+            layer = lay_ref[0]
 
             def body(i, carry):
                 page = tbl_ref[b, jj * ppb + i]
-                fn(pltpu.make_async_copy(k_hbm.at[page, hh],
+                fn(pltpu.make_async_copy(k_hbm.at[layer, page, hh],
                                          k_buf.at[slot, i], k_sem.at[slot]))
-                fn(pltpu.make_async_copy(v_hbm.at[page, hh],
+                fn(pltpu.make_async_copy(v_hbm.at[layer, page, hh],
                                          v_buf.at[slot, i], v_sem.at[slot]))
                 return carry
             jax.lax.fori_loop(0, cnt, body, 0)
@@ -190,7 +221,7 @@ def _make_kernel(*, ps: int, ppb: int, p_cap: int, f: int, gp: int, d: int,
             copies(bi, h, j, cur, lambda c: c.wait())
             # buffer row r, lane group c holds key base + r * f + c; score
             # row c * gp + g is query g against lane group c only
-            k = k_buf[cur].reshape(br, f * d)
+            k = k_buf[cur].reshape(br, f * d).astype(q_ref.dtype)
             v = v_buf[cur].reshape(br, f * d)
             scores = jax.lax.dot_general(
                 q_ref[0, 0], k, (((1,), (1,)), ((), ())),
@@ -232,18 +263,19 @@ def _make_kernel(*, ps: int, ppb: int, p_cap: int, f: int, gp: int, d: int,
 
 def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
                       v_pages: jnp.ndarray, table: jnp.ndarray,
-                      lengths: jnp.ndarray, *,
+                      lengths: jnp.ndarray, layer: jnp.ndarray, *,
                       interpret: bool = True,
                       grid_order: str = "bh",
                       pages_per_block: int | None = None) -> jnp.ndarray:
-    """q: [B, Hkv, G, D]; k_pages/v_pages: [N, Hkv, ps, D] pooled pages;
-    table: [B, P] int32 physical page per (slot, logical page) — every
-    entry must be < N (callers clamp sentinels); lengths: [B] int32.
-    ``grid_order`` picks the outer grid majorness and ``pages_per_block``
-    the pages one grid step walks (None: :func:`default_pages_per_block`;
-    see the module docstring)."""
+    """q: [B, Hkv, G, D]; k_pages/v_pages: the stacked pools as stored,
+    [L, N, Hkv, ps / f, f * D] (:func:`page_shape`); table: [B, P] int32
+    physical page per (slot, logical page) — every entry must be < N
+    (callers clamp sentinels); lengths: [B] int32; layer: [1] int32, the
+    layer of the stack to read.  ``grid_order`` picks the outer grid
+    majorness and ``pages_per_block`` the pages one grid step walks
+    (None: :func:`default_pages_per_block`; see the module docstring)."""
     b, hkv, g, d = q.shape
-    n, _, ps, _ = k_pages.shape
+    ps, f = page_geometry(k_pages, d)
     p_max = table.shape[1]
     if pages_per_block is None:
         pages_per_block = default_pages_per_block(ps, p_max)
@@ -251,7 +283,6 @@ def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         raise ValueError(f"pages_per_block must be positive, "
                          f"got {pages_per_block}")
     ppb = min(pages_per_block, p_max)
-    f = lane_pack(ps, d)
     gp = g if f == 1 else -(-g // SUBLANES) * SUBLANES
     if f > 1:
         # lane group c of query row c * gp + g holds q[g]; the rest is 0
@@ -259,20 +290,18 @@ def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         q = jnp.concatenate(
             [jnp.pad(q, ((0, 0),) * 3 + ((c * d, (f - 1 - c) * d),))
              for c in range(f)], axis=2)
-        k_pages = k_pages.reshape(n, hkv, ps // f, f * d)
-        v_pages = v_pages.reshape(n, hkv, ps // f, f * d)
     b_axis, h_axis = _axes(grid_order)
     grid = [0, 0, pl.cdiv(p_max, ppb)]
     grid[b_axis], grid[h_axis] = b, hkv
     grid = tuple(grid)
 
-    def qo_map(i0, i1, j, tbl, ln):
+    def qo_map(i0, i1, j, tbl, ln, lay):
         return ((i0, i1)[b_axis], (i0, i1)[h_axis], 0, 0)
 
     rows = f * gp
     buf = (2, ppb, ps // f, f * d)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, 1, rows, f * d), qo_map),
@@ -296,5 +325,5 @@ def paged_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((b, hkv, gp, d), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",) * 3),
-        interpret=interpret)(table, lengths, q, k_pages, v_pages)
+        interpret=interpret)(table, lengths, layer, q, k_pages, v_pages)
     return out[:, :, :g]

@@ -7,6 +7,11 @@ table to a host-known bound on the deepest live slot's page count (the
 serving engine's page-count bucketing), so the kernel grid *is* the pruned
 page count; per-slot skipping inside the kernel handles the rest.
 
+Pools are the model's stacked ``[L, N, Hkv, page_size / f, f * D]``
+arrays as stored (:func:`kernel.page_shape`); every op takes the layer
+to read as its ``layer`` argument (default 0), so a caller inside the
+layer loop passes the whole stack and slices nothing.
+
 Routing (kernel vs XLA gather, interpret on/off) reuses the
 ``DecodeAttnPolicy`` from :mod:`repro.kernels.decode_attn` — the decision
 is about the backend, not about which cache layout is in play.
@@ -24,7 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .kernel import default_pages_per_block, paged_attn_kernel
+from .kernel import default_pages_per_block, page_geometry, paged_attn_kernel
 from .prefill_kernel import paged_prefill_attn_kernel
 from .ref import gather_pages
 
@@ -209,7 +214,7 @@ def _traffic(q, k_pages, table, lengths, q_offset=None) -> tuple:
     lq = int(q.shape[1]) if q.ndim == 4 else 1
     hq = int(q.shape[-2])
     d = int(q.shape[-1])
-    hkv, ps = int(k_pages.shape[1]), int(k_pages.shape[2])
+    hkv, (ps, _) = int(k_pages.shape[2]), page_geometry(k_pages, d)
     p = int(table.shape[-1])
     item = jnp.dtype(k_pages.dtype).itemsize
     qitem = jnp.dtype(q.dtype).itemsize
@@ -275,9 +280,10 @@ def _tuned_launch(op: str, q, k_pages, *, lg: int) -> dict:
     (``{}`` on a miss / tuned loading disabled).  Shapes are static even
     under trace, so resolution works at trace time."""
     from ..decode_attn import active_policy
+    d = int(q.shape[-1])
     return active_policy().tuned_config(
-        op, hq=int(q.shape[-2]), hkv=int(k_pages.shape[1]),
-        d=int(q.shape[-1]), page_size=int(k_pages.shape[2]), lg=lg) or {}
+        op, hq=int(q.shape[-2]), hkv=int(k_pages.shape[2]), d=d,
+        page_size=page_geometry(k_pages, d)[0], lg=lg) or {}
 
 
 def decode_launch(*, hq: int, hkv: int, d: int, page_size: int, p_cap: int,
@@ -308,89 +314,99 @@ def decode_launch(*, hq: int, hkv: int, d: int, page_size: int, p_cap: int,
                                              "pages_per_block"))
 def _paged_attn_jit(q: jnp.ndarray, k_pages: jnp.ndarray,
                     v_pages: jnp.ndarray, table: jnp.ndarray,
-                    lengths: jnp.ndarray, *,
+                    lengths: jnp.ndarray, layer: jnp.ndarray, *,
                     interpret: bool = True,
                     grid_order: str = "bh",
                     pages_per_block: int | None = None) -> jnp.ndarray:
     b, hq, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[2]
     g = hq // hkv
     qg = q.reshape(b, hkv, g, d)
-    tbl = _clamp_table(table, k_pages.shape[0])
+    tbl = _clamp_table(table, k_pages.shape[1])
     ln = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32).reshape(-1), (b,))
-    out = paged_attn_kernel(qg, k_pages, v_pages, tbl, ln,
+    out = paged_attn_kernel(qg, k_pages, v_pages, tbl, ln, _layer(layer),
                             interpret=interpret, grid_order=grid_order,
                             pages_per_block=pages_per_block)
     return out.reshape(b, hq, d)
 
 
+def _layer(layer) -> jnp.ndarray:
+    """The layer index as the kernels' [1] int32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def paged_attn(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
-               table: jnp.ndarray, lengths: jnp.ndarray, *,
+               table: jnp.ndarray, lengths: jnp.ndarray, layer=0, *,
                interpret: bool = True,
                grid_order: str | None = None,
                pages_per_block: int | None = None) -> jnp.ndarray:
-    """q: [B, Hq, D] one-token queries; k_pages/v_pages: [N, Hkv, ps, D]
-    pooled pages; table: [B, P] int32; slot b attends over the first
-    ``lengths[b]`` tokens of its pages in table order.  ``grid_order`` /
-    ``pages_per_block`` left None resolve by :func:`decode_launch`."""
+    """q: [B, Hq, D] one-token queries; k_pages/v_pages: stacked pools as
+    stored, [L, N, Hkv, ps / f, f * D]; table: [B, P] int32; slot b
+    attends over the first ``lengths[b]`` tokens of its pages of layer
+    ``layer`` in table order.  ``grid_order`` / ``pages_per_block`` left
+    None resolve by :func:`decode_launch`."""
+    d = int(q.shape[-1])
     grid_order, pages_per_block = decode_launch(
-        hq=int(q.shape[-2]), hkv=int(k_pages.shape[1]), d=int(q.shape[-1]),
-        page_size=int(k_pages.shape[2]), p_cap=int(table.shape[-1]),
+        hq=int(q.shape[-2]), hkv=int(k_pages.shape[2]), d=d,
+        page_size=page_geometry(k_pages, d)[0], p_cap=int(table.shape[-1]),
         grid_order=grid_order, pages_per_block=pages_per_block)
     kw = dict(interpret=interpret, grid_order=grid_order,
               pages_per_block=pages_per_block)
     if not _TELEMETRY.enabled:
-        return _paged_attn_jit(q, k_pages, v_pages, table, lengths, **kw)
+        return _paged_attn_jit(q, k_pages, v_pages, table, lengths, layer,
+                               **kw)
     return _recorded("decode", "kernel", q, _paged_attn_jit,
-                     q, k_pages, v_pages, table, lengths,
+                     q, k_pages, v_pages, table, lengths, layer,
                      traffic=_traffic(q, k_pages, table, lengths), **kw)
 
 
 def paged_attn_xla(q: jnp.ndarray, k_pages: jnp.ndarray,
                    v_pages: jnp.ndarray, table: jnp.ndarray,
-                   lengths: jnp.ndarray) -> jnp.ndarray:
+                   lengths: jnp.ndarray, layer=0) -> jnp.ndarray:
     """Gather-then-attend fallback: identical math on the XLA path (used
     off-TPU where the Pallas interpreter would sit in the hot loop)."""
     if _TELEMETRY.enabled:
         return _recorded("decode", "xla", q, _paged_attn_xla_impl,
-                         q, k_pages, v_pages, table, lengths,
+                         q, k_pages, v_pages, table, lengths, layer,
                          traffic=_traffic(q, k_pages, table, lengths))
-    return _paged_attn_xla_impl(q, k_pages, v_pages, table, lengths)
+    return _paged_attn_xla_impl(q, k_pages, v_pages, table, lengths, layer)
 
 
-def _paged_attn_xla_impl(q, k_pages, v_pages, table, lengths):
+def _paged_attn_xla_impl(q, k_pages, v_pages, table, lengths, layer):
     from ..decode_attn.ref import decode_attn_ref
-    k = gather_pages(k_pages, table)
-    v = gather_pages(v_pages, table)
+    d = q.shape[-1]
+    k = gather_pages(k_pages, table, layer, d)
+    v = gather_pages(v_pages, table, layer, d)
     return decode_attn_ref(q, k, v, lengths).astype(q.dtype)
 
 
 def paged_prefill_attn_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
                               v_pages: jnp.ndarray, table: jnp.ndarray,
-                              q_offset: jnp.ndarray, kv_len: jnp.ndarray, *,
+                              q_offset: jnp.ndarray, kv_len: jnp.ndarray,
+                              layer=0, *,
                               interpret: bool = True,
                               block_rows: int | None = None,
                               grid_order: str = "bh") -> jnp.ndarray:
     """The Pallas flash-prefill path (see :mod:`prefill_kernel`): q
     [B, L, Hq, D] causal suffix queries at per-slot depths ``q_offset``
-    [B], over pooled pages masked to ``kv_len``.  Queries are folded to
-    [B, Hkv, L * G, D] so the kernel's block rows fuse (token, group) and
-    D stays on the lane axis; K/V are cast to the query dtype (the pool
-    may hold a narrower storage dtype).  ``block_rows`` / ``grid_order``
-    pass straight to the kernel's launch geometry — tuned-shape
-    resolution happens in :func:`paged_prefill_attn`, not here."""
+    [B], over layer ``layer`` of the stacked pools, masked to ``kv_len``.
+    Queries are folded to [B, Hkv, L * G, D] so the kernel's block rows
+    fuse (token, group) and D stays on the lane axis; the kernel casts
+    each K/V page to the query dtype (the pool may hold a narrower
+    storage dtype).  ``block_rows`` / ``grid_order`` pass straight to the
+    kernel's launch geometry — tuned-shape resolution happens in
+    :func:`paged_prefill_attn`, not here."""
     b, lq, hq, d = q.shape
-    hkv = k_pages.shape[1]
+    hkv = k_pages.shape[2]
     g = hq // hkv
     qf = q.reshape(b, lq, hkv, g, d).transpose(0, 2, 1, 3, 4)
     qf = qf.reshape(b, hkv, lq * g, d)
-    tbl = _clamp_table(table, k_pages.shape[0])
+    tbl = _clamp_table(table, k_pages.shape[1])
     off = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32).reshape(-1),
                            (b,))
     ln = jnp.broadcast_to(jnp.asarray(kv_len, jnp.int32).reshape(-1), (b,))
-    out = paged_prefill_attn_kernel(qf, k_pages.astype(q.dtype),
-                                    v_pages.astype(q.dtype), tbl, off, ln,
-                                    g=g, interpret=interpret,
+    out = paged_prefill_attn_kernel(qf, k_pages, v_pages, tbl, off, ln,
+                                    _layer(layer), g=g, interpret=interpret,
                                     block_rows=block_rows,
                                     grid_order=grid_order)
     return out.reshape(b, hkv, lq, g, d).transpose(0, 2, 1, 3, 4) \
@@ -400,13 +416,14 @@ def paged_prefill_attn_pallas(q: jnp.ndarray, k_pages: jnp.ndarray,
 def paged_prefill_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
                        v_pages: jnp.ndarray, table: jnp.ndarray,
                        q_offset: jnp.ndarray,
-                       kv_len: jnp.ndarray, *,
+                       kv_len: jnp.ndarray, layer=0, *,
                        grid_order: str | None = None,
                        block_rows: int | None = None,
                        _op: str | None = None) -> jnp.ndarray:
     """Prefill-attention through the page table: multi-token causal GQA
     queries ``q`` [B, L, Hq, D] at per-slot depths ``q_offset`` [B] over
-    pooled pages, masked to each slot's ``kv_len``.
+    layer ``layer`` of the stacked pools, masked to each slot's
+    ``kv_len``.
 
     This is the suffix-only prefill path: a joining slot whose prompt
     prefix is already resident (shared prefix pages mapped by the radix
@@ -433,7 +450,7 @@ def paged_prefill_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
     op = _op or ("decode" if q.shape[1] == 1 else "prefill")
     if pol.kernel_wanted():
         if grid_order is None or block_rows is None:
-            g = int(q.shape[2]) // int(k_pages.shape[1])
+            g = int(q.shape[2]) // int(k_pages.shape[2])
             cfg = _tuned_launch(op, q, k_pages, lg=int(q.shape[1]) * g)
             if grid_order is None:
                 grid_order = cfg.get("grid_order", "bh")
@@ -442,29 +459,30 @@ def paged_prefill_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
         if _TELEMETRY.enabled:
             return _recorded(op, "kernel", q, paged_prefill_attn_pallas,
                              q, k_pages, v_pages, table, q_offset, kv_len,
+                             layer,
                              traffic=_traffic(q, k_pages, table, kv_len,
                                               q_offset=q_offset),
                              interpret=pol.resolve_interpret(),
                              block_rows=block_rows, grid_order=grid_order)
         return paged_prefill_attn_pallas(q, k_pages, v_pages, table,
-                                         q_offset, kv_len,
+                                         q_offset, kv_len, layer,
                                          interpret=pol.resolve_interpret(),
                                          block_rows=block_rows,
                                          grid_order=grid_order)
     from .ref import paged_prefill_attn_ref
     if _TELEMETRY.enabled:
         return _recorded(op, "xla", q, paged_prefill_attn_ref,
-                         q, k_pages, v_pages, table, q_offset, kv_len,
+                         q, k_pages, v_pages, table, q_offset, kv_len, layer,
                          traffic=_traffic(q, k_pages, table, kv_len,
                                           q_offset=q_offset))
     return paged_prefill_attn_ref(q, k_pages, v_pages, table,
-                                  q_offset, kv_len)
+                                  q_offset, kv_len, layer)
 
 
 def paged_verify_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
                       v_pages: jnp.ndarray, table: jnp.ndarray,
                       q_offset: jnp.ndarray,
-                      kv_len: jnp.ndarray, *,
+                      kv_len: jnp.ndarray, layer=0, *,
                       grid_order: str | None = None,
                       block_rows: int | None = None) -> jnp.ndarray:
     """Speculative-decode **verify** attention: score a slot's current
@@ -493,5 +511,5 @@ def paged_verify_attn(q: jnp.ndarray, k_pages: jnp.ndarray,
       any draft that fits the reserved window.
     """
     return paged_prefill_attn(q, k_pages, v_pages, table, q_offset, kv_len,
-                              grid_order=grid_order, block_rows=block_rows,
-                              _op="verify")
+                              layer, grid_order=grid_order,
+                              block_rows=block_rows, _op="verify")

@@ -5,20 +5,31 @@ top of it): a block of ``Lq`` new prompt tokens per slot, sitting at a
 per-slot absolute depth ``q_offset[b]`` (its resident cached-prefix /
 already-prefilled length), attends causally over everything below it —
 shared prefix pages, earlier chunks and the block's own K/V, all resident
-in the pooled head-major ``[n_pages, Hkv, page_size, D]`` allocation and named by the
-``[B, max_pages]`` table.  Unlike :mod:`kernel` (one query row, pure
-memory-bound), the query block here re-uses every fetched page across
-``Lq * G`` rows, so the kernel is the compute-bound sibling: same page
-walk, fatter matmuls.
+in the pooled head-major allocation stacked over layers, ``[L, n_pages,
+Hkv, page_size / f, f * D]`` (:func:`kernel.page_shape`), and named by the
+``[B, max_pages]`` table.  The call reads layer ``layer`` of the stack.
+Unlike :mod:`kernel` (one query row, pure memory-bound), the query block
+here re-uses every fetched page across ``Lq * G`` rows, so the kernel is
+the compute-bound sibling: same page walk, fatter matmuls.
 
-Layout mirrors the decode kernel.  The page table, per-slot query offsets
-and per-slot live lengths are scalar-prefetched, so the BlockSpec index
-map for grid step ``(b, h, r, p)`` redirects the K/V DMA to physical page
-``table[b, p]`` — the gather costs nothing extra.  Queries are pre-folded
-to ``[B, Hkv, Lq * G, D]`` (row ``r`` is query token ``r // G``, group
-member ``r % G``) so the block keeps D on the 128-lane axis and the fused
-(query, group) rows on sublanes; the flash accumulator (m, l, acc) is
-staged in VMEM across the page walk.
+Layout mirrors the decode kernel.  The page table, per-slot query offsets,
+per-slot live lengths and the layer are scalar-prefetched, so the
+BlockSpec index map for grid step ``(b, h, r, p)`` redirects the K/V DMA
+to physical page ``(layer, table[b, p])`` — the gather costs nothing
+extra.  Queries are pre-folded to ``[B, Hkv, Lq * G, D]`` (row ``r`` is
+query token ``r // G``, group member ``r % G``) so the block keeps D on
+the 128-lane axis and the fused (query, group) rows on sublanes; the flash
+accumulator (m, l, acc) is staged in VMEM across the page walk.
+
+Packed pages.  Where ``f = 128 / D`` tokens share a stored row (D 64:
+row ``i`` holds tokens ``2i`` and ``2i + 1``), the kernel reads the page
+as stored, ``[page_size / f, f * D]``, and never relays it out.  The
+query block is repeated in each of the ``f`` lane groups; lane group
+``c`` of the page, the rest zeroed, scores tokens ``f i + c`` as one
+``[rows, page_size / f]`` tile with its own causal positions, and the
+``f`` tiles share one online-softmax update.  Each tile's PV product
+lands in its own lane group of an ``[rows, f * D]`` accumulator, whose
+lane groups the last page step adds.
 
 Command skipping (§5.1.2) at page granularity, same two levels as decode:
 
@@ -71,6 +82,7 @@ from __future__ import annotations
 
 import functools
 import math
+import operator
 
 import jax
 import jax.numpy as jnp
@@ -78,7 +90,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ...core.hwspec import DEFAULT_TPU
-from .kernel import GRID_ORDERS, _axes
+from .kernel import GRID_ORDERS, _axes, page_geometry
 
 # default row blocks past one block are multiples of the bf16 sublane tile
 # (16 rows; the f32 tile of 8 divides it), as Mosaic requires of a block
@@ -133,9 +145,10 @@ def default_block_rows(lg: int, *, d: int, ps: int, q_itemsize: int,
     return -(-lg // (n_blocks * ROW_TILE)) * ROW_TILE
 
 
-def _make_kernel(ps: int, g: int, scale: float, b_axis: int):
-    def kernel(tbl_ref, off_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-               m_ref, l_ref, acc_ref):
+def _make_kernel(ps: int, f: int, d: int, g: int, scale: float,
+                 b_axis: int):
+    def kernel(tbl_ref, off_ref, len_ref, lay_ref, q_ref, k_ref, v_ref,
+               o_ref, m_ref, l_ref, acc_ref):
         bi = pl.program_id(b_axis)
         r = pl.program_id(2)
         p = pl.program_id(3)
@@ -156,7 +169,9 @@ def _make_kernel(ps: int, g: int, scale: float, b_axis: int):
         row0 = r * br
         rows = row0 + jax.lax.broadcasted_iota(jnp.int32, (br, 1), 0)
         qpos = off + rows // g                                # [br, 1]
-        kpos = base + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
+        # stored row i of the page holds keys base + f * i + c, c < f
+        kpos = base + f * jax.lax.broadcasted_iota(jnp.int32,
+                                                   (1, ps // f), 1)
 
         # page-granular command skipping, both ends of the causal window:
         # pages past the slot's live depth AND pages strictly above every
@@ -165,29 +180,41 @@ def _make_kernel(ps: int, g: int, scale: float, b_axis: int):
         # pulled either)
         @pl.when((base < ln) & (base <= off + (row0 + br - 1) // g))
         def _():
-            q = q_ref[0, 0]                  # [br, D]
-            k = k_ref[0, 0]                  # [ps, D]
-            v = v_ref[0, 0]
-            scores = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32) * scale   # [br, ps]
-            live = (kpos <= qpos) & (kpos < ln)               # [br, ps]
-            scores = jnp.where(live, scores, -1e30)
+            q = q_ref[0, 0]                  # [br, f * D]
+            k = k_ref[0, 0].astype(q.dtype)  # [ps / f, f * D]
+            v = v_ref[0, 0].astype(jnp.float32)
+            if f > 1:
+                group = jax.lax.broadcasted_iota(jnp.int32, k.shape, 1) // d
+            scores = []
+            for c in range(f):
+                kc = k if f == 1 else jnp.where(group == c, k, 0)
+                s = jax.lax.dot_general(
+                    q, kc, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                live = (kpos + c <= qpos) & (kpos + c < ln)   # [br, ps/f]
+                scores.append(jnp.where(live, s, -1e30))
             m_prev = m_ref[...]              # [br, 1]
-            m_new = jnp.maximum(m_prev, scores.max(axis=-1, keepdims=True))
-            pexp = jnp.exp(scores - m_new)
+            m_new = functools.reduce(
+                jnp.maximum, [s.max(axis=-1, keepdims=True) for s in scores],
+                m_prev)
+            pexp = [jnp.exp(s - m_new) for s in scores]
             corr = jnp.exp(m_prev - m_new)
-            l_ref[...] = l_ref[...] * corr + pexp.sum(axis=-1, keepdims=True)
-            acc_ref[...] = acc_ref[...] * corr + jax.lax.dot_general(
-                pexp.astype(jnp.float32), v.astype(jnp.float32),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+            l_ref[...] = l_ref[...] * corr + functools.reduce(
+                operator.add, [pc.sum(axis=-1, keepdims=True) for pc in pexp])
+            acc_ref[...] = acc_ref[...] * corr + functools.reduce(
+                operator.add, [jax.lax.dot_general(
+                    pc.astype(jnp.float32),
+                    v if f == 1 else jnp.where(group == c, v, 0.0),
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                    for c, pc in enumerate(pexp)])
             m_ref[...] = m_new
 
         @pl.when(p == np_ - 1)
         def _():
-            o_ref[0, 0] = (acc_ref[...]
-                           / jnp.maximum(l_ref[...], 1e-30)
+            acc = functools.reduce(
+                operator.add, [acc_ref[:, pl.ds(c * d, d)] for c in range(f)])
+            o_ref[0, 0] = (acc / jnp.maximum(l_ref[...], 1e-30)
                            ).astype(o_ref.dtype)
     return kernel
 
@@ -197,20 +224,23 @@ def _make_kernel(ps: int, g: int, scale: float, b_axis: int):
 def paged_prefill_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
                               v_pages: jnp.ndarray, table: jnp.ndarray,
                               q_offset: jnp.ndarray, kv_len: jnp.ndarray,
+                              layer: jnp.ndarray,
                               *, g: int, interpret: bool = True,
                               block_rows: int | None = None,
                               grid_order: str = "bh") -> jnp.ndarray:
     """q: [B, Hkv, Lq * G, D] fused query rows (row ``r`` = token ``r // g``
-    of group member ``r % g``); k_pages/v_pages: [N, Hkv, ps, D] pooled
-    pages; table: [B, P] int32, every entry < N (callers clamp sentinels);
-    q_offset/kv_len: [B] int32 per-slot depth of the query block and total
-    live KV length (``q_offset + Lq`` for a suffix prefill).
+    of group member ``r % g``); k_pages/v_pages: the stacked pools as
+    stored, [L, N, Hkv, ps / f, f * D]; table: [B, P] int32, every entry
+    < N (callers clamp sentinels); q_offset/kv_len: [B] int32 per-slot
+    depth of the query block and total live KV length (``q_offset + Lq``
+    for a suffix prefill); layer: [1] int32, the layer of the stack.
     ``block_rows`` (must divide ``Lq * G``; default:
     :func:`default_block_rows`, whose tail block may overhang) and ``grid_order`` tune the launch geometry — outputs are
     numerically equivalent across valid settings; bit-exactness per
     backend is verified by the autotuner (see module docstring)."""
     b, hkv, lg, d = q.shape
-    ps = k_pages.shape[2]
+    ps, f = page_geometry(k_pages, d)
+    rows, lanes = k_pages.shape[-2:]
     p_max = table.shape[1]
     if block_rows is None:
         br = default_block_rows(
@@ -226,34 +256,38 @@ def paged_prefill_attn_kernel(q: jnp.ndarray, k_pages: jnp.ndarray,
     grid[b_axis], grid[h_axis] = b, hkv
     grid = tuple(grid)
 
-    def kv_map(i0, i1, r, p, tbl, off, ln):
+    def kv_map(i0, i1, r, p, tbl, off, ln, lay):
         bi, h = (i0, i1)[b_axis], (i0, i1)[h_axis]
         # dead pages (past the live depth, or above the whole row block)
         # re-fetch the slot's first page instead of pulling a fresh line
         base = p * ps
         dead = (base >= ln[bi]) | (base > off[bi] + (r * br + br - 1) // g)
         pg = jnp.where(dead, tbl[bi, 0], tbl[bi, p])
-        return (pg, h, 0, 0)
+        return (lay[0], pg, h, 0, 0)
 
-    def q_map(i0, i1, r, p, tbl, off, ln):
+    def q_map(i0, i1, r, p, tbl, off, ln, lay):
         bi, h = (i0, i1)[b_axis], (i0, i1)[h_axis]
         return (bi, h, r, 0)
 
+    if f > 1:
+        # each lane group of a stored row scores against its own copy
+        q = jnp.tile(q, (1, 1, 1, f))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, 1, br, d), q_map),
-            pl.BlockSpec((1, 1, ps, d), kv_map),
-            pl.BlockSpec((1, 1, ps, d), kv_map),
+            pl.BlockSpec((1, 1, br, f * d), q_map),
+            pl.BlockSpec((None, 1, 1, rows, lanes), kv_map),
+            pl.BlockSpec((None, 1, 1, rows, lanes), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, br, d), q_map),
         scratch_shapes=[pltpu.VMEM((br, 1), jnp.float32),
                         pltpu.VMEM((br, 1), jnp.float32),
-                        pltpu.VMEM((br, d), jnp.float32)],
+                        pltpu.VMEM((br, f * d), jnp.float32)],
     )
     return pl.pallas_call(
-        _make_kernel(ps, g, 1.0 / math.sqrt(d), b_axis),
+        _make_kernel(ps, f, d, g, 1.0 / math.sqrt(d), b_axis),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, lg, d), q.dtype),
-        interpret=interpret)(table, q_offset, kv_len, q, k_pages, v_pages)
+        interpret=interpret)(table, q_offset, kv_len, layer, q, k_pages,
+                             v_pages)

@@ -36,15 +36,26 @@ class KVCache(NamedTuple):
     length: jnp.ndarray      # [] int32: tokens filled
 
 
+class PoolLayer(NamedTuple):
+    """Layer ``layer`` of a pool stacked over a segment's layers.  Reads and
+    writes address ``pool[layer, ...]`` in place: the layer is never
+    sliced out of the stack, so the layer loop carries one buffer that
+    every layer's scatter updates and every kernel call reads."""
+    pool: jnp.ndarray        # [L, N, Hkv, ps/f, f*D] (MLA: [L, N, ps, r])
+    layer: jnp.ndarray       # [] int32
+
+
 class PagedKVCache(NamedTuple):
     """Paged layout: K/V pages live in one pooled allocation shared by all
     slots; ``table`` names each slot's pages in order (entries >= n_pages
     are unallocated — scatters through them drop, reads clamp + mask).
-    GQA pages are head-major, so a (page, head) block is a contiguous
-    ``[page_size, D]`` tile for the paged kernels; MLA's latent pages have
-    no head axis.  Either way the token axis is the second-minor one."""
-    k: jnp.ndarray           # [n_pages, Hkv, page_size, D] (MLA: [n, ps, r])
-    v: jnp.ndarray           # [n_pages, Hkv, page_size, D] (MLA: rope key)
+    GQA pages are head-major and stored as the kernels read them
+    (``kernels.paged_attn.page_shape``): a (page, head) tile is ``[ps / f,
+    f * D]``, ``f`` tokens to a 128-lane row where D divides 128, which
+    is the row-major ``[ps, D]`` page reshaped.  MLA's latent pages have
+    no head axis and no packing."""
+    k: PoolLayer             # GQA keys (MLA: latent c_kv)
+    v: PoolLayer             # GQA values (MLA: rope key)
     table: jnp.ndarray       # [B, P] int32 page ids
     length: jnp.ndarray      # [B] int32: tokens filled per slot
 
@@ -154,15 +165,19 @@ def _cache_insert(buf: jnp.ndarray, vals: jnp.ndarray, length) -> jnp.ndarray:
     return buf.at[bidx, pos].set(vals, mode="drop")
 
 
-def _paged_insert(pool: jnp.ndarray, vals: jnp.ndarray,
-                  table: jnp.ndarray, length: jnp.ndarray) -> jnp.ndarray:
-    """Scatter ``vals`` [B, L, Hkv, D] into the head-major page pool
-    [N, Hkv, ps, D] (or latent ``vals`` [B, L, R] into [N, ps, R]): row
-    b's token at sequence position ``length[b] + t`` lands in page
-    ``table[b, (length[b] + t) // ps]`` at offset ``% ps``.  Positions
-    whose logical page is unallocated (sentinel id >= N) or beyond the
-    table width drop — exactly the dense path's out-of-range semantics,
-    and how a join masks non-joining rows out of a shared prefill.
+def _paged_insert(pool: PoolLayer, vals: jnp.ndarray,
+                  table: jnp.ndarray, length: jnp.ndarray) -> PoolLayer:
+    """Scatter ``vals`` [B, L, Hkv, D] into layer ``pool.layer`` of the
+    stacked head-major pool [L, N, Hkv, ps / f, f * D] (or latent
+    ``vals`` [B, L, R] into [L, N, ps, R]): row b's token at sequence
+    position ``length[b] + t`` lands in page ``table[b, (length[b] + t)
+    // ps]`` at offset ``o = (length[b] + t) % ps``, which a packed page
+    stores in row ``o // f``, lane group ``o % f``.  One scatter per call
+    writes into the stack itself, so inside the layer loop it updates
+    the carried pool in place.  Positions whose logical page is
+    unallocated (sentinel id >= N) or beyond the table width drop —
+    exactly the dense path's out-of-range semantics, and how a join
+    masks non-joining rows out of a shared prefill.
 
     Because the write address is purely position-indexed, the insert is
     **rollback-safe** for speculative decoding: a verify writes k+1 rows
@@ -173,27 +188,63 @@ def _paged_insert(pool: jnp.ndarray, vals: jnp.ndarray,
     the new length, overwrites them.  The scheduler reserves the k-row
     overhang at admission so these writes never land past the slot's
     pages (a dropped write would make a *accepted* draft read garbage)."""
-    vals = vals.astype(pool.dtype)
-    n, ps = pool.shape[0], pool.shape[-2]
+    stack, layer = pool
+    vals = vals.astype(stack.dtype)
+    n, rows_per_page = stack.shape[1], stack.shape[-2]
     b, l = vals.shape[:2]
+    f = stack.shape[-1] // vals.shape[-1] if stack.ndim == 5 else 1
+    length = jnp.asarray(length, jnp.int32)
     p_max = table.shape[1]
-    pos = jnp.asarray(length, jnp.int32)[:, None] + jnp.arange(l)[None, :]
-    logical = pos // ps                                        # [B, L]
-    bidx = jnp.arange(b)[:, None]
+    # the stored rows the call touches: row q0 + j of each slot's sequence
+    nrow = (l + f - 2) // f + 1
+    q = length[:, None] // f + jnp.arange(nrow)[None, :]        # [B, J]
+    logical = q // rows_per_page
     page = jnp.where(logical < p_max,
-                     table[bidx, jnp.minimum(logical, p_max - 1)], n)
-    flat_vals = vals.reshape((b * l,) + vals.shape[2:])
-    page, off = page.reshape(-1), (pos % ps).reshape(-1)
-    if pool.ndim == 4:
-        return pool.at[page, :, off].set(flat_vals, mode="drop")
-    return pool.at[page, off].set(flat_vals, mode="drop")
+                     table[jnp.arange(b)[:, None],
+                           jnp.minimum(logical, p_max - 1)], n)
+    row = q % rows_per_page
+    rows = vals                            # f == 1: a token is a row
+    if f > 1:
+        # a packed row also holds tokens this call does not write: merge
+        # the new tokens into the row as stored
+        hkv, d = vals.shape[2:]
+        t = q[..., None] * f + jnp.arange(f) - length[:, None, None]
+        fresh = (t >= 0) & (t < l)                              # [B, J, f]
+        page = jnp.where(fresh.any(axis=-1), page, n)
+        old = stack[layer, jnp.minimum(page, n - 1)[..., None],
+                    jnp.arange(hkv), row[..., None]]         # [B, J, H, fD]
+        new = jnp.take_along_axis(
+            vals, jnp.clip(t, 0, l - 1).reshape(b, -1, 1, 1), axis=1)
+        new = new.reshape(b, nrow, f, hkv, d).swapaxes(2, 3)
+        rows = jnp.where(fresh[:, :, None, :, None], new,
+                         old.reshape(b, nrow, hkv, f, d))
+        rows = rows.reshape(b, nrow, hkv, f * d)
+    # one whole stored row per (token row, head): a window that spans the
+    # minor axis, which the chip scatters natively and in place
+    coords = [page, row]
+    if stack.ndim == 5:
+        hkv = rows.shape[2]
+        coords = [jnp.broadcast_to(c[..., None], rows.shape[:3])
+                  for c in coords]
+        coords.insert(1, jnp.broadcast_to(jnp.arange(hkv), rows.shape[:3]))
+    coords = [c.reshape(-1) for c in coords]
+    lay = jnp.broadcast_to(jnp.asarray(layer, jnp.int32), coords[0].shape)
+    dims = jax.lax.ScatterDimensionNumbers(
+        update_window_dims=(1,),
+        inserted_window_dims=tuple(range(stack.ndim - 1)),
+        scatter_dims_to_operand_dims=tuple(range(stack.ndim - 1)))
+    stack = jax.lax.scatter(stack, jnp.stack([lay, *coords], axis=1),
+                            rows.reshape(-1, rows.shape[-1]), dims,
+                            indices_are_sorted=False, unique_indices=False,
+                            mode=jax.lax.GatherScatterMode.FILL_OR_DROP)
+    return PoolLayer(stack, layer)
 
 
-def _paged_gather(pool: jnp.ndarray, table: jnp.ndarray) -> jnp.ndarray:
-    """Pages -> contiguous [B, P * ps, ...] view for the XLA attention
-    path (sentinels clamp; callers mask by per-slot length)."""
+def _paged_gather(pool: PoolLayer, table: jnp.ndarray) -> jnp.ndarray:
+    """MLA latent pages -> contiguous [B, P * ps, R] view for the XLA
+    attention path (sentinels clamp; callers mask by per-slot length)."""
     from ..kernels.paged_attn import gather_pages
-    return gather_pages(pool, table)
+    return gather_pages(pool.pool, table, pool.layer)
 
 
 def _paged_prefill_route(q, cache: "PagedKVCache", q_offset, kv_len):
@@ -209,18 +260,20 @@ def _paged_prefill_route(q, cache: "PagedKVCache", q_offset, kv_len):
     Pallas flash-prefill kernel on real TPU backends, the gather ref
     elsewhere."""
     from ..kernels.paged_attn import paged_prefill_attn
-    return paged_prefill_attn(q, cache.k, cache.v, cache.table,
-                              q_offset, kv_len)
+    return paged_prefill_attn(q, cache.k.pool, cache.v.pool, cache.table,
+                              q_offset, kv_len, cache.k.layer)
 
 
-def _paged_kernel_route(q, cache: "PagedKVCache", kv_len, dtype):
+def _paged_kernel_route(q, cache: "PagedKVCache", kv_len):
     """Route one-token GQA decode through the paged Pallas kernel.  The
     grid is the table width — the engine slices the table to its
-    page-count bucket, so dead pages are never launched."""
+    page-count bucket, so dead pages are never launched.  The kernel
+    reads the layer's pages out of the stacked pools and casts them to
+    the query dtype itself."""
     from ..kernels.paged_attn import paged_attn
     pol = _decode_policy()
-    out = paged_attn(q[:, 0], cache.k.astype(dtype), cache.v.astype(dtype),
-                     cache.table, kv_len,
+    out = paged_attn(q[:, 0], cache.k.pool, cache.v.pool, cache.table,
+                     kv_len, cache.k.layer,
                      interpret=pol.resolve_interpret())
     return out[:, None]
 
@@ -304,7 +357,7 @@ def gqa_apply(params: dict, x: jnp.ndarray, cfg: ArchConfig, *,
         new_cache = PagedKVCache(kp, vp, cache.table, kv_len)
         pol = _decode_policy()
         if x.shape[1] == 1 and not ctx_shard and pol.kernel_wanted():
-            out = _paged_kernel_route(q, new_cache, kv_len, x.dtype)
+            out = _paged_kernel_route(q, new_cache, kv_len)
         else:
             out = _paged_prefill_route(q, new_cache, cache.length, kv_len)
     elif cache is not None and kv_override is None:

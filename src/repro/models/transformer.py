@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from . import param as pm
-from .attention import (KVCache, PagedKVCache, attention_apply,
+from .attention import (KVCache, PagedKVCache, PoolLayer, attention_apply,
                         init_attention)
 from .layers import (dense, embed, init_dense, init_embedding, init_layernorm,
                      init_mlp, init_rmsnorm, layernorm, mlp, rmsnorm, unembed)
@@ -137,18 +137,21 @@ def init_caches(cfg: ArchConfig, batch: int, max_len: int,
 def init_paged_caches(cfg: ArchConfig, batch: int, n_pages: int,
                       page_size: int, dtype=jnp.bfloat16) -> list:
     """Paged variant of :func:`init_caches`: attention segments hold one
-    pooled ``[layers, n_pages, kv_heads, page_size, head_dim]`` allocation
-    (head-major, see PagedKVCache; MLA latents ``[layers, n_pages,
-    page_size, r]``) shared by every slot through the page table (see
-    repro.serve.kvpool); SSM segments keep their per-slot recurrent state
-    — it is O(1) in sequence length, there is nothing to page (which is
-    also why prefix sharing is attention-only: a recurrent state cannot
-    resume from a cached page)."""
+    pooled ``[layers, n_pages, kv_heads, page_size / f, f * head_dim]``
+    allocation, stored as the paged kernels read it (head-major, ``f``
+    tokens to a 128-lane row where head_dim divides 128; see PagedKVCache
+    and ``kernels.paged_attn.page_shape``; MLA latents ``[layers,
+    n_pages, page_size, r]``), shared by every slot through the page
+    table (see repro.serve.kvpool); SSM segments keep their per-slot
+    recurrent state — it is O(1) in sequence length, there is nothing to
+    page (which is also why prefix sharing is attention-only: a recurrent
+    state cannot resume from a cached page)."""
+    from ..kernels.paged_attn import page_shape
     caches = []
     kshape, vshape = _attn_cache_shape(cfg, n_pages, page_size)
     if cfg.attn is not AttnKind.MLA:
-        kshape = vshape = (n_pages, cfg.kv_heads, page_size,
-                           cfg.resolved_head_dim)
+        kshape = vshape = (n_pages, cfg.kv_heads) + page_shape(
+            page_size, cfg.resolved_head_dim)
     for seg in cfg.resolved_segments():
         n = seg.count
         if seg.kind is BlockKind.SSM:
@@ -211,11 +214,10 @@ def init_lm(key: jax.Array, cfg: ArchConfig) -> dict:
 # ---------------------------------------------------------------------------
 
 def _scan_segment(stacked, x, cfg, kind, *, positions, offset, cache,
-                  cross_kv, causal, remat, pages=None):
-    """cache: None | {"k","v"} stacked | SsmCache of stacked arrays.
-    ``pages`` ([B, P] int32 page table) switches attention caches to the
-    paged layout — the table is shared by every layer (same logical page
-    geometry), only the pooled pages differ per layer."""
+                  cross_kv, causal, remat):
+    """cache: None | {"k","v"} dense, stacked | SsmCache of stacked arrays
+    (the paged pools have a layer loop of their own,
+    :func:`_paged_layer_loop`)."""
     is_ssm = kind is BlockKind.SSM
 
     def call(p, h, c):
@@ -239,8 +241,6 @@ def _scan_segment(stacked, x, cfg, kind, *, positions, offset, cache,
         p, craw = xs
         if is_ssm:
             c = craw
-        elif pages is not None:
-            c = PagedKVCache(craw["k"], craw["v"], pages, offset)
         else:
             c = KVCache(craw["k"], craw["v"], offset)
         y, new_c, a = call(p, h, c)
@@ -250,6 +250,32 @@ def _scan_segment(stacked, x, cfg, kind, *, positions, offset, cache,
     (x, aux), new_cache = jax.lax.scan(
         body, (x, jnp.zeros((), jnp.float32)), (stacked, cache))
     return x, new_cache, aux
+
+
+def _paged_layer_loop(stacked, x, cfg, kind, *, positions, offset, cache,
+                      cross_kv, pages):
+    """The layer loop of an attention segment over the paged pools
+    ``cache`` ({"k","v"}, stacked ``[L, N, ...]``), addressed through the
+    page table ``pages`` ([B, P] int32, shared by every layer).  The
+    carry holds the pools next to the activations; the layer's weights
+    and its index are the scan inputs.  Each layer's attention scatters
+    its new rows into ``pool[layer]`` and its kernel reads ``pool[layer]``
+    through that index, so the pools stay one buffer that the loop
+    updates in place — no layer is sliced out, relaid out or stacked
+    back."""
+    def body(carry, xs):
+        h, aux, kpool, vpool = carry
+        p, layer = xs
+        c = PagedKVCache(PoolLayer(kpool, layer), PoolLayer(vpool, layer),
+                         pages, offset)
+        y, new_c, a = block_apply(p, h, cfg, kind, positions=positions,
+                                  cache=c, cross_kv=cross_kv)
+        return (y, aux + a, new_c.k.pool, new_c.v.pool), None
+    layers = jnp.arange(cache["k"].shape[0], dtype=jnp.int32)
+    (x, aux, k, v), _ = jax.lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), cache["k"], cache["v"]),
+        (stacked, layers))
+    return x, {"k": k, "v": v}, aux
 
 
 def encode(params: dict, frames: jnp.ndarray, cfg: ArchConfig):
@@ -316,21 +342,33 @@ def forward(params: dict, batch: dict, cfg: ArchConfig, *,
         if seg.kind is BlockKind.SHARED_ATTN:
             c = None
             if cache_i is not None and pages is not None:
-                c = PagedKVCache(cache_i["k"][0], cache_i["v"][0], pages,
+                # the shared block's pools, addressed in place at layer 0
+                layer = jnp.zeros((), jnp.int32)
+                c = PagedKVCache(PoolLayer(cache_i["k"], layer),
+                                 PoolLayer(cache_i["v"], layer), pages,
                                  offset)
             elif cache_i is not None:
                 c = KVCache(cache_i["k"][0], cache_i["v"][0], offset)
             y, nc, aux = block_apply(params["shared_block"], x, cfg,
                                      BlockKind.DENSE, positions=positions,
                                      cache=c, cross_kv=cross_kv)
-            if cache_i is not None:
+            if isinstance(nc, PagedKVCache):
+                nc = {"k": nc.k.pool, "v": nc.v.pool}
+            elif cache_i is not None:
                 nc = {"k": nc.k[None], "v": nc.v[None]}
+            new_caches.append(nc)
+        elif (pages is not None and cache_i is not None
+              and seg.kind is not BlockKind.SSM):
+            y, nc, aux = _paged_layer_loop(
+                params["segments"][i], x, cfg, seg.kind,
+                positions=positions, offset=offset, cache=cache_i,
+                cross_kv=cross_kv, pages=pages)
             new_caches.append(nc)
         else:
             y, nc, aux = _scan_segment(
                 params["segments"][i], x, cfg, seg.kind,
                 positions=positions, offset=offset, cache=cache_i,
-                cross_kv=cross_kv, causal=True, remat=remat, pages=pages)
+                cross_kv=cross_kv, causal=True, remat=remat)
             new_caches.append(nc)
         x = y
         aux_total = aux_total + aux

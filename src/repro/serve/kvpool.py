@@ -7,7 +7,8 @@ capacity.  The paper's co-design lesson
 commands or capacity on dead data, and PrIM-style studies put placement
 management, not compute, at the center of near-memory wins.  The paged
 analogue: KV lives in fixed-size **pages** inside one pooled allocation
-(``[layers, n_pages, kv_heads, page_size, head_dim]`` per segment, see
+(``[layers, n_pages, kv_heads, page_size / f, f * head_dim]`` per
+segment, ``f`` tokens to a 128-lane row, see
 :func:`repro.models.transformer.init_paged_caches`); each slot holds an
 ordered list of page ids (its **page table**), pages come from a free list,
 and retirement returns every page exactly once.

@@ -4,7 +4,8 @@ Serving architecture
 --------------------
 The scheduler owns a fixed-width **slot table**: ``cfg.batch`` decode slots
 whose KV lives in fixed-size pages of one pooled allocation
-(repro.serve.kvpool; [layers, pages, kv_heads, page_size, head_dim]), with
+(repro.serve.kvpool; [layers, pages, kv_heads, page_size / f, f *
+head_dim], updated in place across the layer loop), with
 one page-table row per slot, one jitted prefill/join step and one jitted
 multi-token decode scan.  Host state per slot is (request id, token
 budget, live length, mapped pages); device state is (next-token [B,1],
